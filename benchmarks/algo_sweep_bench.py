@@ -10,7 +10,7 @@ non-default cell bit-for-bit against the lax baseline on integer payloads
 (the acceptance row: tuned path bit-identical to baseline for sum
 allreduce).
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/algo_sweep_bench.py [--smoke] [--quant] \\
               [--profile-out PATH]
 
@@ -43,10 +43,6 @@ def main():
                     help="write the profile here (default: a temp file)")
     ap.add_argument("--iters", type=int, default=0)
     args = ap.parse_args()
-
-    from mlsl_tpu import sysinfo
-
-    sysinfo.apply_platform_override()
 
     import numpy as np
     import jax

@@ -22,7 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 from benchmarks._common import device_sync, setup_chip
 
-jax = setup_chip("bn_probe")
+jax = setup_chip()
 
 import jax.numpy as jnp
 from jax import lax

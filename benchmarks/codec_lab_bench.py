@@ -19,12 +19,11 @@ calibration autotuner (tuner/calibrate.py):
    uniform wire comfortably meets (matched averaged-tail convergence, by
    construction of the budget constraint).
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/codec_lab_bench.py [--smoke]
 --smoke trims the size grid and scales the stream (~1/16 elements, same 161
 tensors) — the tier-1 wiring (tests/test_codec_lab.py, the ``bench_smoke``
-marker) runs this mode. Full grid runs via benchmarks/capture.py. Prints
-one JSON row per measurement (the standard capture-row shape).
+marker) runs this mode. Prints one JSON row per measurement.
 """
 
 import argparse
@@ -49,10 +48,6 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="fast tier-1 mode: trimmed sizes, scaled stream")
     args = ap.parse_args()
-
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
 
     import mlsl_tpu as mlsl
     from mlsl_tpu import codecs

@@ -6,7 +6,7 @@ BASELINE.json configs[1]) with the isolation methodology (best-of-blocks,
 d2h-synced). On one real chip the groups degenerate to the dispatch floor;
 on a mesh (virtual CPU or a real slice) the rows are group-wise algbw.
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
        python benchmarks/grid_collectives.py
 Prints one JSON line per (collective, group).
 """
@@ -19,10 +19,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 
 def main():
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     import numpy as np
 
     import mlsl_tpu as mlsl

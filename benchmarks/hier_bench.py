@@ -23,7 +23,7 @@ Usage: python benchmarks/hier_bench.py [--smoke] [--tiers 2x4]
        [--dcn-gbps 6.25] [--dcn-lat-us 50] [--no-dcn-sim]
 
 --smoke trims sizes/iters for the tier-1 wiring (tests/test_hier.py, the
-``bench_smoke`` marker); the full grid belongs to capture.py.
+``bench_smoke`` marker).
 """
 
 import argparse
@@ -73,10 +73,6 @@ def main():
 
     if not os.environ.get("MLSL_MESH_TIERS"):
         os.environ["MLSL_MESH_TIERS"] = args.tiers
-
-    from mlsl_tpu import sysinfo
-
-    sysinfo.apply_platform_override()
 
     import numpy as np
     import jax

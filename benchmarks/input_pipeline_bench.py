@@ -17,7 +17,7 @@ The closing ``input_pipeline_best`` row names the winning cell — its
 tuner.KNOB_RANGES) would carry as ``MLSL_FEED_DEPTH`` on this machine
 (docs/TUNING.md §12).
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/input_pipeline_bench.py [--smoke]
 --smoke trims the grid and shapes for the tier-1 wiring
 (tests/test_feed.py, ``bench_smoke`` marker). Prints one JSON row per cell
@@ -38,10 +38,6 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="fast tier-1 mode: tiny shapes, trimmed grid")
     args = ap.parse_args()
-
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
 
     import numpy as np
     import jax

@@ -17,10 +17,9 @@ halving/doubling allreduce) and the ``pallas_a2a`` fused MoE exchange:
 Off-TPU the kernels run under the Pallas interpreter (armed here when no
 TPU is attached): parity rows are real, timing rows are tagged ``backend:
 interpret`` and are NOT a performance signal — interpreter DMAs are
-simulated with world gathers. The measured curve belongs to the next
-on-chip capture (BENCH r06, benchmarks/capture.py).
+simulated with world gathers. Not measured on the chip (ROADMAP S8).
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/latency_bench.py [--smoke]
 
 --smoke trims sizes/iters for the tier-1 wiring (tests/test_pallas_rhd.py,
@@ -65,8 +64,6 @@ def main():
     args = ap.parse_args()
 
     from mlsl_tpu import sysinfo
-
-    sysinfo.apply_platform_override()
 
     import numpy as np
     import jax

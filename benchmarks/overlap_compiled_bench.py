@@ -22,7 +22,7 @@ Layer count stays ~54 (the real bench.py per-layer trainer's count): the CPU
 proof backend deadlocks past a few dozen concurrent in-flight collectives
 (the PR 2 hazard), and the host twin keeps all layers in flight per step.
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/overlap_compiled_bench.py [--smoke]
 --smoke scales tensor sizes down (~1/16, same layer count — the per-layer
 dispatch floor being beaten is per layer) and trims iters; the tier-1 wiring
@@ -71,10 +71,6 @@ def main():
     ap.add_argument("--stages", type=int, default=None,
                     help="overlap staging depth (default: config)")
     args = ap.parse_args()
-
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
 
     import numpy as np
     import jax

@@ -11,10 +11,10 @@ hop engines' arithmetic is exactly representable).
 Off-TPU the kernel runs under the Pallas interpreter (armed here via
 MLSL_PALLAS_INTERPRET=1 when no TPU is attached): the parity rows are real,
 the timing rows are tagged ``backend: interpret`` and are NOT a performance
-signal — the interpreter simulates every DMA with gathers. The measured
-curve belongs to the next on-chip capture (BENCH r06, benchmarks/capture.py).
+signal — the interpreter simulates every DMA with gathers. Not measured on
+the chip (ROADMAP S8).
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/pallas_ring_bench.py [--smoke]
 
 --smoke trims sizes/iters for the tier-1 wiring (tests/test_pallas_ring.py,
@@ -60,8 +60,6 @@ def main():
     args = ap.parse_args()
 
     from mlsl_tpu import sysinfo
-
-    sysinfo.apply_platform_override()
 
     import numpy as np
     import jax

@@ -20,7 +20,7 @@ Tensor counts are rounded UP to a small size palette so the per-layer path
 compiles a handful of distinct ring programs instead of ~50 (the coalesced
 path is insensitive; the palette preserves the size distribution).
 
-Usage: MLSL_TPU_PLATFORM=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/quant_bucket_bench.py [--smoke]
 --smoke scales the tensor list down (~1/16 the elements, same 161 tensors)
 and trims sizes/iters — the tier-1 wiring (tests/test_quant_bucket.py, the
@@ -80,10 +80,6 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="fast tier-1 mode: scaled-down tensors, fewer iters")
     args = ap.parse_args()
-
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
 
     import numpy as np
 

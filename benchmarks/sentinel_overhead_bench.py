@@ -43,10 +43,6 @@ def main():
                     help="fast tier-1 mode: fewer iters")
     args = ap.parse_args()
 
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     import numpy as np
     import jax
     import jax.numpy as jnp

@@ -17,8 +17,8 @@ The acceptance instrument for the serving engine (mlsl_tpu/serve/):
   (the exit code; timing never gates).
 
 Off-TPU the numbers are CPU-mesh proof numbers, tagged ``backend: cpu`` —
-scheduling behaviour and parity are real, absolute tokens/s belongs to the
-on-chip capture (benchmarks/capture.py).
+scheduling behaviour and parity are real; absolute tokens/s is not measured
+on the chip yet (ROADMAP S1/S7).
 
 Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/serving_bench.py [--smoke]
@@ -83,8 +83,6 @@ def main():
     args = ap.parse_args()
 
     from mlsl_tpu import sysinfo
-
-    sysinfo.apply_platform_override()
 
     import numpy as np
     import jax

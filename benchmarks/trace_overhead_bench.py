@@ -33,10 +33,6 @@ def main():
                     help="fast tier-1 mode: fewer layers/iters")
     args = ap.parse_args()
 
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     import numpy as np
 
     import mlsl_tpu as mlsl

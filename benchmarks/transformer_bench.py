@@ -22,7 +22,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 from benchmarks._common import model_flops, setup_chip, timed
 
-jax = setup_chip("transformer_bench")
+jax = setup_chip()
 
 import jax.numpy as jnp  # noqa: E402
 
@@ -56,7 +56,10 @@ def run_config(env, name, cfg, batch):
         "step_ms": round(ms, 3),
         "tok_s": round(tokens / (ms / 1e3)),
     }
-    peak = peak_tflops(jax.devices()[0].device_kind)
+    # an unlisted TPU kind raises (bench._peak_tflops); the CPU (--quick) has
+    # no peak and reports no MFU
+    peak = (peak_tflops(jax.devices()[0].device_kind)
+            if jax.default_backend() == "tpu" else None)
     # mfu_model = canonical model-FLOPs MFU (analytic, remat-comparable) —
     # needs nothing from the XLA cost model
     mf = model_flops(cfg, batch)

@@ -10,7 +10,7 @@
  * Build & run on the 8-device CPU mesh (the Makefile computes the portable
  * embed-Python link flags via python3-config):
  *   make -C native compat_example
- *   PYTHONPATH=. MLSL_TPU_PLATFORM=cpu \
+ *   PYTHONPATH=. JAX_PLATFORMS=cpu \
  *       XLA_FLAGS=--xla_force_host_platform_device_count=8 \
  *       ./native/compat_example
  */

@@ -12,7 +12,7 @@ callbacks. Geometry is calibrated at registration: a declared block_size the
 codec does not honor fails loudly instead of corrupting memory.
 
 Run on the 8-device CPU mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 MLSL_TPU_PLATFORM=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/custom_codec.py
 """
 
@@ -41,9 +41,6 @@ def quantized_allreduce(env, dist, n, vals):
 
 
 def main():
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
     import jax.numpy as jnp
 
     env = mlsl.Environment.get_env().init()

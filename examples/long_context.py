@@ -13,7 +13,7 @@ training:
    for head sharding when heads are plentiful.
 
 Run on the 8-device CPU mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 MLSL_TPU_PLATFORM=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/long_context.py
 """
 
@@ -29,10 +29,6 @@ import mlsl_tpu as mlsl
 
 
 def main():
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     from mlsl_tpu.models import transformer as tfm
 
     env = mlsl.Environment.get_env().init()

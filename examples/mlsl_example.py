@@ -4,7 +4,7 @@ data x model grid, register a small operation graph, and run training-loop phase
 with asynchronous gradient synchronization.
 
 Run on the 8-device CPU mesh (simulating a TPU slice):
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 MLSL_TPU_PLATFORM=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/mlsl_example.py
 or on real TPU hardware with no extra flags.
 """
@@ -21,10 +21,6 @@ from mlsl_tpu.types import DataType, GroupType, OpType, ReductionType
 
 
 def main():
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     # 1. Bootstrap (reference: Environment::GetEnv().Init(&argc, &argv))
     env = mlsl.Environment.get_env().init()
     world = env.get_process_count()

@@ -2,7 +2,7 @@
 gradient sync, async data loading and checkpointing.
 
 Run on the 8-device CPU mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 MLSL_TPU_PLATFORM=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/train_transformer.py
 """
 
@@ -17,10 +17,6 @@ import mlsl_tpu as mlsl
 
 
 def main():
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     from mlsl_tpu.checkpoint import CheckpointManager, restore_trainer, save_trainer
     from mlsl_tpu.data import AsyncLoader
     from mlsl_tpu.models import transformer as tfm

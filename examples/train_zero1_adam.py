@@ -10,7 +10,7 @@ Demonstrates the trainer-side framework features on top of the MLSL graph:
   the Adam trajectory instead of restarting from zero moments.
 
 Run on the 8-device CPU mesh:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 MLSL_TPU_PLATFORM=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/train_zero1_adam.py
 """
 
@@ -31,10 +31,6 @@ from mlsl_tpu.models.train import DataParallelTrainer
 
 
 def main():
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
-
     env = mlsl.Environment.get_env().init()
     n = len(env.devices)
     dist = env.create_distribution(n, 1)

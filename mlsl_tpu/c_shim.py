@@ -44,9 +44,6 @@ def _release(hid: int) -> int:
 # ---- environment ----
 
 def env_init() -> int:
-    from mlsl_tpu.sysinfo import apply_platform_override
-
-    apply_platform_override()
     Environment.get_env().init()
     return 0
 

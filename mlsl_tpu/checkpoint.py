@@ -35,18 +35,12 @@ import time
 from typing import Any, List, Optional
 
 import jax
+import orbax.checkpoint as ocp
 
 from mlsl_tpu import chaos
 from mlsl_tpu.config import _env_float, _env_int
 from mlsl_tpu.log import MLSLError, log_info, log_warning
 from mlsl_tpu.obs import tracer as obs
-
-try:
-    import orbax.checkpoint as ocp
-
-    _HAVE_ORBAX = True
-except ImportError:  # pragma: no cover
-    _HAVE_ORBAX = False
 
 
 class CheckpointManager:
@@ -59,8 +53,6 @@ class CheckpointManager:
         save_retries: Optional[int] = None,
         retry_backoff_s: Optional[float] = None,
     ):
-        if not _HAVE_ORBAX:
-            raise RuntimeError("orbax-checkpoint is not available")
         self.directory = os.path.abspath(directory)
         os.makedirs(self.directory, exist_ok=True)
         self.save_retries = (

@@ -32,12 +32,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # JAX >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from mlsl_tpu.comm.mesh import GRID_AXES, NUM_GRID_AXES, ProcessGroup
 from mlsl_tpu.log import mlsl_assert
@@ -48,17 +44,11 @@ _BUF_SPEC = P(*GRID_AXES, None)
 
 
 def smap(f, mesh, in_specs, out_specs, check: bool = True):
-    """shard_map with a version-compatible way to disable VMA/replication checking
-    (needed when out_specs claim replication the compiler can't prove, or when the
-    body contains pallas_call, whose outputs carry no vma annotation)."""
-    if check:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    for kw in ({"check_vma": False}, {"check_rep": False}):
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """shard_map; ``check=False`` disables VMA checking (needed when out_specs
+    claim replication the compiler can't prove, or when the body contains
+    pallas_call, whose outputs carry no vma annotation)."""
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=check)
 
 
 def _axis_sizes(mesh) -> dict:

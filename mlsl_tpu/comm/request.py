@@ -1062,7 +1062,7 @@ class CommRequest:
         # ~10 µs over plain block_until_ready, a genuine hang converges to
         # 1 ms polls until the deadline trips
         delay = 1e-5
-        while not _array_is_ready(out):
+        while not out.is_ready():
             if time.monotonic() > deadline:
                 self._watchdog_trip("wait")
             time.sleep(delay)
@@ -1194,7 +1194,7 @@ class CommRequest:
         # check in-flight FIRST — once it clears, _results is fully built.
         if self.dispatcher.is_in_flight(self.uid) or not self._results:
             return False, None
-        ready = all(_array_is_ready(r) for r in self._results)
+        ready = all(r.is_ready() for r in self._results)
         if ready:
             out = self._assemble()
             jax.block_until_ready(out)
@@ -1389,14 +1389,6 @@ def _normalize_alltoallv_per_rank(d: CommDesc, s: np.ndarray) -> dict:
                 recv_len=max(recv_len, 1))
 
 
-def _array_is_ready(arr: jax.Array) -> bool:
-    try:
-        return bool(arr.is_ready())
-    except AttributeError:  # pragma: no cover - very old jax
-        jax.block_until_ready(arr)
-        return True
-
-
 class Dispatcher:
     """Host-side dispatch policy: immediate async launch, or newest-first deferral.
 
@@ -1437,14 +1429,17 @@ class Dispatcher:
             and self._native.pending() == 0  # never strand deferred entries
         ):
             self._native_tried = True
-            try:
-                from mlsl_tpu.native import NativeScheduler
+            from mlsl_tpu import native
 
-                self._native = NativeScheduler(
+            # load() raising (a failed build beside a stale library) must
+            # propagate; only "no library and no toolchain" selects the
+            # pure-Python queue
+            self._native = (
+                native.NativeScheduler(
                     cfg.msg_priority_threshold, cfg.msg_priority_mode
                 )
-            except (RuntimeError, ImportError):
-                self._native = None
+                if native.load() is not None else None
+            )
         return self._native
 
     def submit(self, req: CommRequest, buf: jax.Array) -> None:

@@ -483,14 +483,9 @@ class Config:
     # warm-execute every collective program the committed graph can dispatch —
     # plain, bucketed, and quant-ring — on zero buffers at Commit, so step 0
     # of the training loop contains no collective compilation. Composes with
-    # compile_cache_dir below (the warm run itself reloads from disk).
+    # JAX's persistent compilation cache (sysinfo.resolve_compile_cache): the
+    # warm run itself reloads from disk.
     precompile: bool = False        # MLSL_PRECOMPILE
-
-    # Persistent XLA compilation cache (TPU-native: Session::Commit pre-lowers
-    # every per-edge collective, and on real chips each first compile costs
-    # tens of seconds — a warm cache makes restarts near-instant; the
-    # reference has no analog because MPI has no compile step). Empty = off.
-    compile_cache_dir: str = ""     # MLSL_COMPILE_CACHE_DIR
 
     def validate(self) -> None:
         """Reject contradictory or unserviceable settings with a clear
@@ -966,7 +961,4 @@ class Config:
         c.heap_size_gb = _env_int("MLSL_HEAP_SIZE_GB", c.heap_size_gb)
         c.alltoall_split = _env_int("MLSL_ALLTOALL_SPLIT", c.alltoall_split)
         c.thp_threshold_mb = _env_int("MLSL_THP_THRESHOLD_MB", c.thp_threshold_mb)
-        c.compile_cache_dir = os.environ.get(
-            "MLSL_COMPILE_CACHE_DIR", c.compile_cache_dir
-        )
         return c

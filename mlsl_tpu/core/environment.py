@@ -39,6 +39,7 @@ class Environment:
         self.request_storage = RequestStorage()
         self.devices: Sequence[jax.Device] = ()
         self.quant_params: Optional[QuantParams] = None
+        self.compile_cache_dir = ""  # set by init (sysinfo.resolve_compile_cache)
         self._distributions: list = []
         self._sessions: list = []
         self._global_colors: Optional[tuple] = None
@@ -77,6 +78,8 @@ class Environment:
                 coordinator_address, num_processes, process_id
             )
             Environment._jax_distributed_up = True
+        # a TPU runtime that failed to start must not become a CPU run
+        sysinfo.require_chosen_backend()
         self.config = Config.from_env()
         set_log_level(self.config.log_level)
         sysinfo.auto_config(self.config)
@@ -107,7 +110,7 @@ class Environment:
         # sweep compiles every eligible algorithm x size x shape program, and
         # on real chips those compiles are the tens-of-seconds cost the cache
         # exists to amortize across restarts
-        self._apply_compile_cache()
+        self.compile_cache_dir = sysinfo.resolve_compile_cache()
         # autotuner hook: MLSL_TUNE=1 sweeps and persists a profile on the
         # live mesh; MLSL_TUNE_PROFILE loads one (stale fingerprints rejected
         # with a warning, missing/corrupt files raise). Sets
@@ -207,34 +210,6 @@ class Environment:
                     jax.distributed.shutdown()
                 except Exception:  # mlsl-lint: disable=A205 -- half-
                     pass  # initialized client: nothing to unwind
-
-    _jax_cache_defaults = None  # knob values before our first mutation
-
-    def _apply_compile_cache(self) -> None:
-        """Persistent XLA compilation cache: pre-lowered Session collectives and
-        jitted train steps reload from disk on warm restarts instead of
-        recompiling (first compiles cost tens of seconds on real chips).
-        Thresholds are zeroed while enabled so every program is cached — the
-        cache exists to eliminate recompiles, not just the largest ones. The
-        toggle is symmetric: an init() without MLSL_COMPILE_CACHE_DIR restores
-        the pre-mutation knob values, so 'empty = off' holds across
-        init/finalize cycles in one process."""
-        if Environment._jax_cache_defaults is None:
-            Environment._jax_cache_defaults = (
-                jax.config.jax_compilation_cache_dir,
-                jax.config.jax_persistent_cache_min_compile_time_secs,
-                jax.config.jax_persistent_cache_min_entry_size_bytes,
-            )
-        if self.config.compile_cache_dir:
-            jax.config.update("jax_compilation_cache_dir",
-                              self.config.compile_cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        else:
-            d, t, s = Environment._jax_cache_defaults
-            jax.config.update("jax_compilation_cache_dir", d)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", t)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", s)
 
     def _dump_config(self) -> None:
         """One-time config/world dump at init (the reference's rank-0 env-var dump,

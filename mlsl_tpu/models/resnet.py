@@ -131,18 +131,20 @@ def _stem_conv(x, w):
 
 def _use_s2d_stem() -> bool:
     """MLSL_RESNET_S2D: '1' forces the space-to-depth stem, '0' forces the
-    direct conv; unset defaults to on for TPU backends (measured on v5e at
-    batch 256: median MFU 0.2835 -> 0.287; identical math, pinned by
-    test_s2d_stem_matches_direct_conv)."""
+    direct conv; unset defaults to on for TPU backends (identical math,
+    pinned by test_s2d_stem_matches_direct_conv). Any other value raises."""
     import os
+
+    from mlsl_tpu.log import mlsl_assert
+    from mlsl_tpu.sysinfo import on_tpu
 
     v = os.environ.get("MLSL_RESNET_S2D", "").strip().lower()
     if v in ("0", "false", "off"):
         return False
     if v in ("1", "true", "on"):
         return True
-    from mlsl_tpu.sysinfo import on_tpu
-
+    mlsl_assert(v == "", "MLSL_RESNET_S2D must be 0/false/off, 1/true/on or "
+                "unset (got %r)", v)
     return on_tpu()
 
 

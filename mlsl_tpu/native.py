@@ -4,8 +4,10 @@ Mirrors the reference's binding pattern (flat C API src/c_bind.cpp consumed by a
 ctypes module include/mlsl/mlsl.py): the C++ library owns the grid math, the five-case
 selection, block layouts, parameter partitioning, the priority dispatch queue and
 request storage; Python owns the XLA data plane. The library is built on demand with
-the in-image toolchain; if the build fails, ``load()`` returns None and callers fall
-back to the pure-Python implementations (both are tested for agreement).
+the in-image toolchain. With no toolchain and no library, ``load()`` says so and
+returns None, and callers fall back to the pure-Python implementations (both are
+tested for agreement); a failed build beside an existing library is an error,
+never a silent load of whatever was left on disk.
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ import subprocess
 import threading
 from typing import Optional
 
-from mlsl_tpu.log import log_info
+from mlsl_tpu.log import MLSLError, log_warning
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libmlsl_core.so")
@@ -24,6 +26,7 @@ _SO_PATH = os.path.join(_NATIVE_DIR, "libmlsl_core.so")
 _lib = None
 _lib_lock = threading.Lock()
 _load_failed = False
+_built_this_run = False
 
 
 class Block(ctypes.Structure):
@@ -91,12 +94,22 @@ def _declare(lib) -> None:
     lib.mlsl_core_version.restype = ctypes.c_char_p
 
 
+def _so_mtime() -> Optional[float]:
+    try:
+        return os.stat(_SO_PATH).st_mtime
+    except FileNotFoundError:
+        return None
+
+
 def load() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None on failure."""
-    global _lib, _load_failed
+    """Load (building if needed) the native library. None = no library and
+    no way to build one (the pure-Python paths serve); raises MLSLError when
+    the build fails beside an existing library, which may be stale."""
+    global _lib, _load_failed, _built_this_run
     with _lib_lock:
         if _lib is not None or _load_failed:
             return _lib
+        before = _so_mtime()
         try:
             # Always run make: a no-op when the .so is current, a rebuild when the
             # sources changed (a stale library would fail _declare below).
@@ -105,19 +118,33 @@ def load() -> Optional[ctypes.CDLL]:
                 capture_output=True, timeout=120,
             )
         except (subprocess.SubprocessError, OSError) as e:
-            if not os.path.exists(_SO_PATH):
-                log_info("native build failed, using pure-Python paths: %s", e)
-                _load_failed = True
-                return None
+            detail = getattr(e, "stderr", b"") or b""
+            if before is not None:
+                raise MLSLError(
+                    f"native build failed beside an existing {_SO_PATH} "
+                    f"(possibly stale; refusing to load it): {e} "
+                    f"{detail.decode(errors='replace')[-400:]}"
+                ) from e
+            log_warning("native build failed, using pure-Python paths: %s", e)
+            _load_failed = True
+            return None
+        _built_this_run = _so_mtime() != before
         try:
             lib = ctypes.CDLL(_SO_PATH)
             _declare(lib)
             assert lib.mlsl_core_version().decode().startswith("mlsl_core")
             _lib = lib
         except (OSError, AssertionError, AttributeError) as e:
-            log_info("native load failed, using pure-Python paths: %s", e)
+            log_warning("native load failed, using pure-Python paths: %s", e)
             _load_failed = True
         return _lib
+
+
+def status() -> dict:
+    """What load() did in this process: whether the library is loaded and
+    whether make (re)built it here or found it current."""
+    return {"loaded": _lib is not None, "built_this_run": _built_this_run,
+            "path": _SO_PATH}
 
 
 class NativeScheduler:

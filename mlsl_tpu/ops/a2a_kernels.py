@@ -57,6 +57,10 @@ def eligible(kind: str, group: ProcessGroup, count: Optional[int] = None,
         return False
     if not rk.available():
         return False
+    if quant_enabled() and not rk.interpret_mode():
+        # the int8 wire is parked on the compiled backend (rk.QUANT_PARKED):
+        # a TPU runs this kernel dense (MLSL_PALLAS_A2A_QUANT=0) or not at all
+        return False
     if group.colors is not None or not group.axes or not group.is_uniform:
         return False
     if not (1 < int(group.size) <= rk.MAX_GROUP):
@@ -140,7 +144,7 @@ def static_accounting(g: int, slots: int):
 
 def _a2a_kernel_factory(
     *, G: int, rows: int, cols: int, quantized: bool, slots: int,
-    handshake: bool,
+    handshake: bool, barrier: bool,
 ) -> Callable:
     """Build the kernel body: G-1 shifted-permutation steps unrolled in
     Python. Step t=1..G-1 (hop index h = t-1): quantize chunk (pos+t)%G out
@@ -158,6 +162,10 @@ def _a2a_kernel_factory(
             cap = scr[6] if handshake else None
 
         pos = pos_ref[0]
+        if barrier:
+            # every other member is a send target exactly once, and sends
+            # here exactly once: G-1 signals out, G-1 in
+            rk.entry_barrier([to_ref[h] for h in range(hops)])
 
         def dmod(v):
             return lax.rem(v + 4 * G, G)
@@ -262,7 +270,7 @@ def _a2a_call(
 
     kern = _a2a_kernel_factory(
         G=G, rows=rows, cols=cols, quantized=quantized, slots=slots_eff,
-        handshake=handshake,
+        handshake=handshake, barrier=not interpret,
     )
     if quantized:
         scratch = [
@@ -292,8 +300,8 @@ def _a2a_call(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,      # pos, send-target table, recv-from table
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
@@ -301,7 +309,7 @@ def _a2a_call(
         out_shape=jax.ShapeDtypeStruct((G * rows, cols), jnp.float32),
         grid_spec=grid_spec,
         compiler_params=rk._compiler_params(
-            ("a2a", G, rows, cols, quantized, slots_eff)
+            ("a2a", G, rows, cols, quantized, slots_eff), interpret,
         ),
         interpret=interpret,
     )
@@ -388,6 +396,9 @@ def alltoall_body_ef(
         mlsl_assert(block % 128 == 0,
                     "pallas_a2a int8 codec needs block %% 128 == 0 (got %d)",
                     block)
+        mlsl_assert(rk.interpret_mode(),
+                    "pallas_a2a int8 wire is parked on the compiled backend "
+                    "(%s); set MLSL_PALLAS_A2A_QUANT=0", rk.QUANT_PARKED)
     rc, chunk, rows = geometry(g, count, block, quantized)
     cols = block if quantized else 128
     err_len = g * chunk if quantized else 0

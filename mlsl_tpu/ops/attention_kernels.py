@@ -27,11 +27,16 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (jax 0.7); accept either so
-# the flash kernels build on both sides of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG = -1e30
+
+
+#: Sk innermost and "arbitrary": the VMEM scratch carries across it. The
+#: (512, 2048) f32 score tiles fit Mosaic's default scoped VMEM on v5e at
+#: head_dim 64 and 128, forward and backward (PERF.md, PR 21 chip run), so no
+#: vmem_limit_bytes is set.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+)
 
 
 def _pick_tiles(sq: int, sk: int):
@@ -192,9 +197,7 @@ def _flash_fwd(q, k, v, q_offset, k_offset, causal=False, interpret=False,
             if want_lse
             else [jax.ShapeDtypeStruct((bh, sq, d), q.dtype)]
         ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q_offset, k_offset, q, k, v)
     if want_lse:
@@ -326,9 +329,7 @@ def _flash_bwd(q, k, v, do, out, lse, q_offset, k_offset, causal, interpret):
             scratch_shapes=[pltpu.VMEM((tq, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q_offset, k_offset, q, k, v, do, lse, dd)
 
@@ -359,9 +360,7 @@ def _flash_bwd(q, k, v, do, out, lse, q_offset, k_offset, causal, interpret):
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q_offset, k_offset, q, k, v, do, lse, dd)
     return dq, dk, dv
@@ -480,9 +479,7 @@ def _block_update_fwd(q, k, v, acc, m, l, q_offset, k_offset,
         # acc, m, l) -> acc/m/l reuse their input buffers, saving one HBM copy of
         # the dominant long-sequence state per ring hop
         input_output_aliases={5: 0, 6: 1, 7: 2},
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q_offset, k_offset, q, k, v, acc, m, l)
 

@@ -144,7 +144,7 @@ def static_accounting(g: int, slots: int):
 
 
 def _rhd_kernel_factory(
-    *, G: int, m_rows: int, slots: int, handshake: bool,
+    *, G: int, m_rows: int, slots: int, handshake: bool, barrier: bool,
 ) -> Callable:
     """Build the kernel body: the full pre-fold / halving / doubling /
     post-fold schedule unrolled in Python (G <= MAX_GROUP => at most
@@ -159,6 +159,10 @@ def _rhd_kernel_factory(
         pos = pos_ref[0]
         rel = lax.rem(pos, c)
         active = pos < c
+        if barrier:
+            # one signal per round partner (self in a masked fold round):
+            # the pairing is symmetric, so R signals arrive here too
+            rk.entry_barrier([peers_ref[h] for h in range(R)])
 
         cin = pltpu.make_async_copy(x_ref, acc, csem.at[0])
         cin.start()
@@ -269,6 +273,7 @@ def _rhd_call(G: int, m_rows: int, slots: int, interpret: bool) -> Callable:
 
     kern = _rhd_kernel_factory(
         G=G, m_rows=m_rows, slots=slots_eff, handshake=handshake,
+        barrier=not interpret,
     )
     scratch = [
         pltpu.VMEM((m_rows, 128), jnp.float32),              # acc
@@ -282,8 +287,8 @@ def _rhd_call(G: int, m_rows: int, slots: int, interpret: bool) -> Callable:
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # pos, per-round partner ranks
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
@@ -291,7 +296,7 @@ def _rhd_call(G: int, m_rows: int, slots: int, interpret: bool) -> Callable:
         out_shape=jax.ShapeDtypeStruct((m_rows, 128), jnp.float32),
         grid_spec=grid_spec,
         compiler_params=rk._compiler_params(
-            ("rhd", G, m_rows, slots_eff, handshake)
+            ("rhd", G, m_rows, slots_eff, handshake), interpret,
         ),
         interpret=interpret,
     )

@@ -50,12 +50,16 @@ remote-DMA semantics — with two restrictions the module works around:
   statically elided); on TPU the handshake compiles in.
 
 Gate: ``MLSL_PALLAS_INTERPRET`` (``1`` force-interpret, ``0``
-force-compiled, unset = compiled on TPU and the interpreter elsewhere).
+force-compiled, unset = compiled on TPU, interpreted where the platform was
+chosen to be the CPU — sysinfo.pallas_interpret).
+
+A compiled kernel opens with ``entry_barrier`` (the barrier semaphore that
+``collective_id`` allocates): no device starts its first remote DMA before
+both ring neighbors have entered the kernel.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import os
 from typing import Callable, List, Optional, Tuple
@@ -69,9 +73,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mlsl_tpu.comm.mesh import GRID_AXES, ProcessGroup
 from mlsl_tpu.log import mlsl_assert
-
-# jax renamed TPUCompilerParams -> CompilerParams (jax 0.7); accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 #: dense ring chunk alignment (elements): 32 sublane rows x 128 lanes keeps
 #: every per-chunk VMEM buffer tile-legal for f32/bf16/i32 alike
@@ -94,19 +95,36 @@ DEFAULT_SLOTS = 2
 _collective_ids: dict = {}
 
 
-def _compiler_params(key: tuple):
-    """collective_id marks the kernel as a cross-device collective for
-    Mosaic and must (a) agree across every device running THIS kernel and
-    (b) differ between distinct kernels that may be in flight concurrently
-    (the overlap engine can interleave several ring units) — allocated
-    sequentially per kernel configuration from the registry above.
-    has_side_effects (newer jax only — a DMA kernel must not be DCE'd) is
-    passed when the dataclass knows the field."""
+def _compiler_params(key: tuple, interpret: bool):
+    """Mosaic parameters for one cross-device kernel configuration. A
+    compiled kernel opens with ``entry_barrier`` on the barrier semaphore,
+    which Mosaic allocates by ``collective_id``: the id must (a) agree across
+    every device running THIS kernel and (b) differ between distinct kernels
+    that may be in flight concurrently (the overlap engine can interleave
+    several ring units) — allocated sequentially per kernel configuration
+    from the registry above. Interpreted kernels run in lockstep and emit no
+    barrier, and this jax refuses a collective_id on a kernel without one.
+    has_side_effects: a DMA kernel must not be CSE'd or DCE'd."""
+    if interpret:
+        return pltpu.CompilerParams(has_side_effects=True)
     cid = _collective_ids.setdefault(key, len(_collective_ids))
-    kw = {"collective_id": cid}
-    if "has_side_effects" in {f.name for f in dataclasses.fields(_CompilerParams)}:
-        kw["has_side_effects"] = True
-    return _CompilerParams(**kw)
+    return pltpu.CompilerParams(collective_id=cid, has_side_effects=True)
+
+
+def entry_barrier(peers) -> None:
+    """Open a compiled cross-device kernel: tell every peer this device has
+    entered, then wait until every peer said the same. Without it the first
+    remote DMA can land in the VMEM scratch of a device that is still
+    running the previous program. ``peers`` are LOGICAL device ids; the
+    pairing is symmetric in every kernel of the family (each device hears
+    from exactly the peers it signals), so the semaphore drains to zero."""
+    sem = pltpu.get_barrier_semaphore()
+    for dev in peers:
+        pltpu.semaphore_signal(
+            sem, inc=1, device_id=dev,
+            device_id_type=pltpu.DeviceIdType.LOGICAL,
+        )
+    pltpu.semaphore_wait(sem, len(peers))
 
 
 # ---------------------------------------------------------------------------
@@ -121,16 +139,12 @@ def _on_tpu() -> bool:
 
 
 def interpret_mode() -> bool:
-    """Whether kernel builds run under the Pallas interpreter. Resolution:
-    ``MLSL_PALLAS_INTERPRET=1`` forces the interpreter (debugging a TPU
-    lowering), ``0`` forces compiled Mosaic, unset = compiled on TPU and the
-    interpreter everywhere else (the tier-1 CPU-mesh parity path)."""
-    v = os.environ.get("MLSL_PALLAS_INTERPRET", "").strip()
-    if v == "1":
-        return True
-    if v == "0":
-        return False
-    return not _on_tpu()
+    """Whether kernel builds run under the Pallas interpreter
+    (sysinfo.pallas_interpret: ``MLSL_PALLAS_INTERPRET=1`` or a chosen CPU
+    platform — never a quiet slide off a backend that failed to start)."""
+    from mlsl_tpu.sysinfo import pallas_interpret
+
+    return pallas_interpret()
 
 
 def available() -> bool:
@@ -236,10 +250,23 @@ def eligible_allgather(group: ProcessGroup) -> bool:
     return 1 < int(group.size) <= MAX_GROUP
 
 
+#: why the int8-fused variants (this ring's and pallas_a2a's) are parked on
+#: the compiled backend: Mosaic (jax 0.9.0, libtpu 0.0.34, v5e) refuses the
+#: (rows, 1) f32 scale buffers as RDMA operands (KNOWN_FAILURES.md)
+QUANT_PARKED = (
+    "Mosaic failed to compile TPU kernel: Slice shape along dimension 1 "
+    "must be aligned to tiling (128), but is 1 — the per-row scale slots "
+    "(rows, 1) cannot be sliced for a DMA"
+)
+
+
 def eligible_quant(group: ProcessGroup, block: int) -> bool:
     """Eligibility for the int8-fused variant: dense eligibility plus the
-    codec's lane constraint (the quant block rides the VMEM lane dim)."""
-    if block % 128 != 0 or not available():
+    codec's lane constraint (the quant block rides the VMEM lane dim).
+    Interpreted builds only — the compiled kernel is parked (QUANT_PARKED),
+    so on a TPU a forced or tuned pallas_ring keeps the composed quant
+    ring, decided at selection and never at dispatch."""
+    if block % 128 != 0 or not available() or not interpret_mode():
         return False
     ax = ring_axis(group)
     return ax is not None and 1 < int(group.size) <= MAX_GROUP
@@ -480,11 +507,13 @@ def _ring_kernel_factory(
     slots: int,
     dirs: Tuple[Tuple[int, int, int], ...],  # (sign, row_lo, row_len)
     handshake: bool,
+    barrier: bool,
 ) -> Callable:
     """Build the kernel body. Hops are unrolled in Python (G <= MAX_GROUP):
     every hop's send slot is quantized on the way out of VMEM, RDMA'd with
     its scales, and dequantize-accumulated on the way in; slot reuse is
-    guarded by the remote capacity handshake when compiled for the chip."""
+    guarded by the remote capacity handshake when compiled for the chip,
+    and a compiled kernel opens with the neighbor ``entry_barrier``."""
     hops = G - 1
     total_hops = hops * (2 if mode == "allreduce" else 1)
     ndirs = len(dirs)
@@ -504,6 +533,8 @@ def _ring_kernel_factory(
         pos = pos_ref[0]
         right = right_ref[0]
         left = left_ref[0]
+        if barrier:
+            entry_barrier((left, right))
 
         def copy_in(idx, dst, r0, rl, sem):
             c = pltpu.make_async_copy(
@@ -733,6 +764,7 @@ def _ring_call(
     kern = _ring_kernel_factory(
         mode=mode, G=G, rows=rows, cols=cols, quantized=quantized,
         slots=slots_eff, dirs=dirs, handshake=handshake,
+        barrier=not interpret,
     )
 
     out_rows = rows if mode == "reduce_scatter" else G * rows
@@ -765,8 +797,8 @@ def _ring_call(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,           # pos, right, left (world ranks)
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
@@ -775,7 +807,7 @@ def _ring_call(
         grid_spec=grid_spec,
         compiler_params=_compiler_params(
             (mode, G, rows, cols, dtype_str, quantized, slots_eff,
-             bidir, ndirs)
+             bidir, ndirs), interpret,
         ),
         interpret=interpret,
     )

@@ -72,8 +72,9 @@ def gpipe_forward(
         "wire width (see pad_stage_weights)"
     )
 
-    outs = _pvary(jnp.zeros((m_count, mb, d_out), probe.dtype), axis)
-    recv = _pvary(jnp.zeros((mb, d_out), probe.dtype), axis)
+    like = (probe, x_micro)
+    outs = _pvary(jnp.zeros((m_count, mb, d_out), probe.dtype), axis, like)
+    recv = _pvary(jnp.zeros((mb, d_out), probe.dtype), axis, like)
 
     def tick(t, state):
         recv, outs = state
@@ -200,9 +201,10 @@ def one_f1b_step(
     # In-flight boundary inputs: slot i % S is free again strictly before
     # microbatch i+S forwards (B_i at tick 2i+2S-1-2s < F_{i+S} at 2i+2S+s...
     # equality never holds since parities differ at s=0: 2i+2S-1 < 2i+2S).
-    x_buf = _pvary(jnp.zeros((S, mb, d), probe.dtype), axis)
-    recv_f = _pvary(jnp.zeros((mb, d), probe.dtype), axis)
-    recv_b = _pvary(jnp.zeros((mb, d), probe.dtype), axis)
+    like = (probe, x_micro)
+    x_buf = _pvary(jnp.zeros((S, mb, d), probe.dtype), axis, like)
+    recv_f = _pvary(jnp.zeros((mb, d), probe.dtype), axis, like)
+    recv_b = _pvary(jnp.zeros((mb, d), probe.dtype), axis, like)
     grads0 = jax.tree.map(lambda p: jnp.zeros_like(p), stage_params)
     is_last = me == S - 1
 
@@ -514,9 +516,10 @@ def interleaved_1f1b_step(
     )
     dt = probe.dtype
 
-    fwd_in = _pvary(jnp.zeros((V * k_f, mb, d_wire), dt), axis)
-    bwd_in = _pvary(jnp.zeros((V * k_b, mb, d_wire), dt), axis)
-    x_saved = _pvary(jnp.zeros((V * k_s, mb, d_wire), dt), axis)
+    like = (probe, x_micro)
+    fwd_in = _pvary(jnp.zeros((V * k_f, mb, d_wire), dt), axis, like)
+    bwd_in = _pvary(jnp.zeros((V * k_b, mb, d_wire), dt), axis, like)
+    x_saved = _pvary(jnp.zeros((V * k_s, mb, d_wire), dt), axis, like)
     grads0 = jax.tree.map(lambda p: jnp.zeros_like(p), chunk_params)
     zero_wire = jnp.zeros((mb, d_wire), dt)
 

@@ -26,19 +26,24 @@ import jax.numpy as jnp
 from jax import lax
 
 from mlsl_tpu.log import mlsl_assert
+from mlsl_tpu.sysinfo import on_tpu, pallas_interpret
 
 _NEG = -1e30
 
 
-def _pvary(x, axis):
-    """Mark x as device-varying over axis (no-op on JAX versions without VMA)."""
-    try:
-        return lax.pcast(x, (axis,), to="varying")
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        try:
-            return lax.pvary(x, (axis,))
-        except AttributeError:
-            return x
+def _pvary(x, axis, like=()):
+    """Mark x as device-varying over ``axis`` and over every manual axis a
+    leaf of ``like`` varies over: a loop carry must enter with the varying
+    type its body produces. ``like`` takes arrays and ``jax.eval_shape``
+    results alike — the abstract output of a stage function names every axis
+    its body varies over, closures included (a pipeline stage inside a
+    data x seq x model grid varies over all of them)."""
+    axes = {axis}
+    for leaf in jax.tree.leaves(like):
+        vma = (leaf if isinstance(leaf, jax.ShapeDtypeStruct)
+               else jax.typeof(leaf)).vma
+        axes |= set(vma or ())
+    return lax.pcast(x, tuple(sorted(axes)), to="varying")
 
 
 def _attn_block_update(q, k_blk, v_blk, acc, m, l, q_pos, k_pos, causal, scale):
@@ -74,7 +79,8 @@ def ring_attention(
     """Exact attention over the full (sharded) sequence via a k/v ring.
 
     use_flash: None = auto (fused Pallas block kernel on TPU when the tiling
-    admits); True/False forces the choice (True uses interpret mode off-TPU)."""
+    admits); True/False forces the choice (True on a chosen CPU platform runs
+    the kernel under the interpreter — sysinfo.pallas_interpret)."""
     if axis_size == 1:
         return _dense_attention(q, k, v, causal, 0)
     b, h, sl, d = q.shape
@@ -140,7 +146,7 @@ def _ring_flash(q, k, v, axis: str, axis_size: int, causal: bool) -> jax.Array:
 
     b, h, sl, d = q.shape
     bh = b * h
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     qf = q.reshape(bh, sl, d)
     # The scalar-prefetch offsets only matter for the causal mask / DMA-skip
     # maps. Non-causal, feed constants: an axis_index-derived operand that the
@@ -237,7 +243,7 @@ def zigzag_ring_attention(
 
     use_flash: None = auto (the fused Pallas block kernel on TPU when the
     chunk tiling admits — no (c x c) score materialization); True forces it
-    (interpret mode off-TPU), False forces the einsum fallback.
+    (interpreted on a chosen CPU platform), False forces the einsum fallback.
     """
     if axis_size == 1:
         return _dense_attention(q, k, v, True, 0)
@@ -264,7 +270,7 @@ def zigzag_ring_attention(
             "use_flash=False",
             c, d,
         )
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
         zoff = jnp.zeros((1,), jnp.int32)
 
         def _update(causal):
@@ -401,7 +407,7 @@ def _dense_attention(q, k, v, causal: bool, pos_offset: int) -> jax.Array:
         off = jnp.full((1,), pos_offset, jnp.int32)
         out = flash_attention(
             q.reshape(b * h, s, d), k.reshape(b * h, s, d), v.reshape(b * h, s, d),
-            off, off, causal, False,
+            off, off, causal, pallas_interpret(),
         )
         return out.reshape(b, h, s, d)
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
@@ -417,12 +423,9 @@ def _dense_attention(q, k, v, causal: bool, pos_offset: int) -> jax.Array:
 
 def _use_flash(sq: int, sk: int, d: int) -> bool:
     """Route through the fused Pallas kernel on TPU when the tiling admits it
-    (1.3x over the XLA einsum at S=2048 on v5e, and O(S*D) HBM instead of O(S^2))."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-        from mlsl_tpu.ops.attention_kernels import supports
-
-        return supports(sq, sk, d)
-    except Exception:  # pragma: no cover
+    (O(S*D) HBM instead of the einsum's O(S^2))."""
+    if not on_tpu():
         return False
+    from mlsl_tpu.ops.attention_kernels import supports
+
+    return supports(sq, sk, d)

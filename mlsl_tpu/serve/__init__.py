@@ -43,12 +43,14 @@ __all__ = [
     "Request",
     "PagedKVCache",
     "oracle_generate",
+    "oracle_logits",
 ]
 
 _LAZY = {
     "InferenceEngine": "mlsl_tpu.serve.engine",
     "Request": "mlsl_tpu.serve.engine",
     "oracle_generate": "mlsl_tpu.serve.engine",
+    "oracle_logits": "mlsl_tpu.serve.engine",
     "PagedKVCache": "mlsl_tpu.serve.kv_cache",
 }
 

@@ -595,20 +595,28 @@ class InferenceEngine:
             sla._set_active(None)
 
 
+def oracle_logits(engine: InferenceEngine, seq) -> np.ndarray:
+    """Next-token logits (V,) of the UNPAGED oracle for the token sequence
+    ``seq``: the engine's own compiled prefill over the whole sequence — no
+    KV cache, no pages."""
+    seq = np.asarray(seq, np.int32).reshape(-1)
+    tokens = np.zeros((engine.ctx_len,), np.int32)
+    tokens[:seq.size] = seq
+    logits, _, _ = engine._prefill(
+        engine.params, jnp.asarray(tokens), jnp.int32(seq.size))
+    return np.asarray(logits)
+
+
 def oracle_generate(engine: InferenceEngine, prompt, max_new_tokens: int,
                     eos_token: Optional[int] = None) -> List[int]:
     """The UNPAGED oracle: greedy decode by re-running the engine's own
-    compiled prefill over the growing full sequence each step — no KV
-    cache, no pages. The bit-exactness tests pin the paged engine against
+    compiled prefill over the growing full sequence each step
+    (``oracle_logits``). The bit-exactness tests pin the paged engine against
     this (identical program structure, identical reduction extents)."""
     seq = list(np.asarray(prompt, np.int32).reshape(-1))
     out: List[int] = []
     for _ in range(max_new_tokens):
-        tokens = np.zeros((engine.ctx_len,), np.int32)
-        tokens[:len(seq)] = seq
-        logits, _, _ = engine._prefill(
-            engine.params, jnp.asarray(tokens), jnp.int32(len(seq)))
-        tok = int(np.argmax(np.asarray(logits)))
+        tok = int(np.argmax(oracle_logits(engine, seq)))
         out.append(tok)
         seq.append(tok)
         if eos_token is not None and tok == eos_token:

@@ -23,7 +23,7 @@ export LD_LIBRARY_PATH
 
 case "${1:-tpu}" in
   cpusim)
-    export MLSL_TPU_PLATFORM=cpu
+    export JAX_PLATFORMS=cpu
     export XLA_FLAGS="--xla_force_host_platform_device_count=8 ${XLA_FLAGS}"
     echo "mlsl_tpu: 8-device CPU simulation mode"
     ;;

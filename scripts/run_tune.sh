@@ -12,7 +12,7 @@ cd "$(dirname "$0")/.."
 PROFILE="${1:-/tmp/mlsl_tune_profile.demo.json}"
 shift || true
 
-env JAX_PLATFORMS=cpu MLSL_TPU_PLATFORM=cpu \
+env JAX_PLATFORMS=cpu \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python benchmarks/algo_sweep_bench.py --smoke --profile-out "$PROFILE" "$@"
 

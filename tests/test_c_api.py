@@ -21,7 +21,7 @@ def test_c_api_end_to_end():
 
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["MLSL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["MLSL_STATS"] = "1"  # exercise the statistics queries section
     run = subprocess.run(
@@ -49,7 +49,7 @@ def test_cpp_api_end_to_end():
     assert build.returncode == 0, build.stderr
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["MLSL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     run = subprocess.run(
         [os.path.join(NATIVE, "test_cpp_api")], capture_output=True, text=True,

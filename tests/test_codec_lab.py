@@ -710,7 +710,6 @@ def test_codec_lab_bench_smoke():
     no timing, no retry, the assertions stay hard."""
     env_vars = dict(
         os.environ,
-        MLSL_TPU_PLATFORM="cpu",
         JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )

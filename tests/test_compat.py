@@ -30,7 +30,7 @@ def compat_binary():
 def _run(binary, group_count, dist_update, user_buf, use_test):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["MLSL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     run = subprocess.run(
         [binary, str(group_count), str(dist_update), str(user_buf),
@@ -70,7 +70,7 @@ def test_compat_watchdog_on_divergent_ranks(compat_binary):
     per-rank diagnostic (the reference dies loudly via MPI), not hang."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["MLSL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["MLSL_COMPAT_WATCHDOG_S"] = "3"
     run = subprocess.run(
@@ -96,7 +96,7 @@ def test_compat_watchdog_rearms_for_slow_collective(compat_binary):
     above keeps the compat watchdog in tier-1."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["MLSL_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["MLSL_COMPAT_WATCHDOG_S"] = "1"
     run = subprocess.run(

@@ -803,7 +803,6 @@ def test_hier_bench_smoke_beats_flat():
     env_vars = dict(
         os.environ,
         JAX_PLATFORMS="cpu",
-        MLSL_TPU_PLATFORM="cpu",
         MLSL_MESH_TIERS="2x4",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )

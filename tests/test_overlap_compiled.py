@@ -587,7 +587,6 @@ def test_overlap_compiled_bench_smoke():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env_vars = dict(
         os.environ,
-        MLSL_TPU_PLATFORM="cpu",
         JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )

@@ -693,19 +693,22 @@ def test_tpu_compiled_dense_parity(rng, env, monkeypatch):
 
 
 @pytest.mark.tpu
-def test_tpu_compiled_quant_parity(rng, env, monkeypatch):
+def test_tpu_quant_variant_is_parked(env, monkeypatch):
+    """The int8-fused variant does not compile for the chip (rk.QUANT_PARKED,
+    KNOWN_FAILURES.md): on the compiled backend it is ineligible, so a
+    forced pallas_ring on a quantized request keeps the composed ring at
+    selection — nothing raises or degrades at dispatch."""
     monkeypatch.setenv("MLSL_PALLAS_INTERPRET", "0")
-    n_dev = jax.device_count()
-    topo = Topology(n_dev, 1)
+    topo = Topology(jax.device_count(), 1)
     g = ProcessGroup(topo, ("data",))
-    count = n_dev * BLOCK * 32
-    ofn, pfn, el = _quant_pair(g, count)
-    buf = topo.shard_buffer(
-        _exact_scale_vals(rng, n_dev, count, topo.grid_shape))
-    oo, oe = ofn(buf, _zerr(topo, el))
-    po, pe = pfn(buf, _zerr(topo, el))
-    np.testing.assert_array_equal(np.asarray(po), np.asarray(oo))
-    np.testing.assert_array_equal(np.asarray(pe), np.asarray(oe))
+    assert not rk.eligible_quant(g, BLOCK)
+    env.config.collective_algo = "pallas_ring"
+    env.config.quant_block_elems = BLOCK
+    env.config.validate()
+    assert algos.select("allreduce", g, 1 << 20,
+                        CompressionType.QUANTIZATION, env.config) == "lax"
+    assert algos.select("allreduce", g, 1 << 20, CompressionType.NONE,
+                        env.config, op=ReductionType.SUM) == "pallas_ring"
 
 
 @pytest.mark.tpu
@@ -727,3 +730,38 @@ def test_tpu_overlap_in_graph_emission(rng, env, monkeypatch):
     fn_l, _ = overlap.build_multi_reduce(g, counts, algo="lax")
     for got, want in zip(fn_p(bufs), fn_l(bufs)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.tpu
+def test_tpu_compiled_snake_parity(rng, env, monkeypatch):
+    """pallas_ring2d compiled: the snake cycle over the 2x2 torus of a
+    four-chip host, bit-exact vs lax on integer sums."""
+    monkeypatch.setenv("MLSL_PALLAS_INTERPRET", "0")
+    if jax.device_count() < 4:
+        pytest.skip("needs four chips")
+    topo = Topology(2, 2, devices=jax.devices()[:4])
+    g = ProcessGroup(topo, ("data", "model"))
+    vals = _int_vals(rng, topo, 1 << 16)
+    base = algos.build("allreduce", g, np.float32, "lax",
+                       op=ReductionType.SUM)
+    fn = algos.build("allreduce", g, np.float32, "pallas_ring2d",
+                     op=ReductionType.SUM)
+    np.testing.assert_array_equal(_run(fn, topo, vals), _run(base, topo, vals))
+
+
+@pytest.mark.tpu
+def test_tpu_compiled_all_gather_phase(rng, env, monkeypatch):
+    """The ZeRO-1 all-gather phase kernel compiled: every member ends with
+    every member's shard in group-position order."""
+    monkeypatch.setenv("MLSL_PALLAS_INTERPRET", "0")
+    n_dev = jax.device_count()
+    topo = Topology(n_dev, 1)
+    group = ProcessGroup(topo, ("data",))
+    for shard in (640, 130):  # chunk-aligned and padded
+        vals = _int_vals(rng, topo, shard)
+        body = rk.dense_ring_body("all_gather", group, shard, np.float32)
+        fn = rk.build_flat_program(body, group, "all_gather")
+        out = _run(fn, topo, vals).reshape(n_dev, n_dev * shard)
+        want = vals.reshape(-1)
+        for i in range(n_dev):
+            np.testing.assert_array_equal(out[i], want)
