@@ -1,0 +1,23 @@
+"""Published peaks of one chip, keyed by ``device_kind``. An unknown kind is
+an error, never a default."""
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" (system architecture page): 197
+    # TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s, 1,600 Gbit/s ICI
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "ici_bits_per_s": 1600e9,
+        "source": "cloud.google.com/tpu/docs/v5e (system architecture)",
+    },
+}
+
+
+def of(device_kind):
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}: add a row "
+            f"to perf/lib/peaks.py with its source")
+    return PEAKS[device_kind]

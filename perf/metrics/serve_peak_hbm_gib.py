@@ -1,0 +1,7 @@
+"""memory_stats()['peak_bytes_in_use'], read when the last request had
+drained and before the reference ran."""
+
+
+def read(run):
+    peak = run.device.get("memory_peak_bytes")
+    return peak / 2**30 if peak else None
