@@ -588,7 +588,7 @@ def _overlap_probe_cpu_mesh(timeout: float = 600.0, attempts: int = 2):
     # timeout), and the span tracer must not tax a comparative timing probe
     env_vars.pop("MLSL_CHAOS", None)
     env_vars.pop("MLSL_WATCHDOG_TIMEOUT", None)
-    env_vars.pop("MLSL_TRACE", None)
+    env_vars["MLSL_TRACE"] = "0"
     # a chip-run tuner sweep (MLSL_TUNE) must not re-run — or its chip-keyed
     # profile load — inside the CPU-mesh probe (mismatched fingerprint), and
     # a chip-targeted algorithm override must not reroute the probe's

@@ -464,10 +464,11 @@ class Config:
 
     # --- observability tier (mlsl_tpu.obs span tracer) ---
     # Kept for discoverability/printing only, like chaos_spec: the tracer is
-    # process-wide (armed at import from MLSL_TRACE, or obs.enable()) and the
+    # process-wide (armed at import unless MLSL_TRACE=0: the ring is the
+    # flight recorder; obs.enable()/disable() at run time) and the
     # output dir / ring capacity are read from the SAME env vars per call —
     # override via the obs API, not by mutating these fields.
-    trace: bool = False             # MLSL_TRACE: arm the comm timeline tracer
+    trace: bool = True              # MLSL_TRACE: 0 disarms the span tracer
     trace_dir: str = ""             # MLSL_TRACE_DIR: trace-*.json output dir
     # MLSL_TRACE_CAPACITY: ring size (events); single source of truth is the
     # tracer's own default

@@ -1,21 +1,30 @@
 """mlsl_tpu.obs — structured comm-timeline tracing (docs/DESIGN.md
 "Observability & tracing").
 
-Quick start::
+The span ring is the flight recorder and is on in every run: the most recent
+65,536 events sit in memory at one tuple and one deque append an event, and
+cost nothing else until somebody asks for them::
 
-    MLSL_TRACE=1 python train.py        # arm at launch
-    # or programmatically:
+    python serve.py                     # the ring is armed
     from mlsl_tpu import obs
-    obs.enable()
     ... run ...
     path = obs.write_trace()            # load in ui.perfetto.dev
 
-Env knobs: ``MLSL_TRACE`` (arm), ``MLSL_TRACE_DIR`` (output directory,
-default CWD), ``MLSL_TRACE_CAPACITY`` (ring size in events, default 65536).
+``MLSL_TRACE=0`` (or ``obs.disable()``) disarms it: every instrumented site
+is then one attribute load and a ``None`` test, and allocates nothing.
+Other knobs: ``MLSL_TRACE_DIR`` (output directory, default CWD),
+``MLSL_TRACE_CAPACITY`` (ring size in events, default 65536).
+
+The serving engine records one span tree a step (``serve.step`` and its
+children, each carrying ``step=<n>``, the request-scoped ones ``req=<id>``
+too) and one ``serve.request`` a request on its own track; PERF.md section 3
+lists every span with the metric that reads it.
 
 On a watchdog trip (``MLSLTimeoutError``) the flight recorder dumps the
 trailing window of spans to ``trace-crash-<ts>.json`` automatically (and,
-with ``MLSL_PROFILE_ON_TRIP=1``, a jax.profiler device trace next to it).
+with ``MLSL_PROFILE_ON_TRIP=1``, a jax.profiler device trace next to it);
+since the ring is armed by default the dump holds the timeline that led to
+the trip without anybody having armed anything beforehand.
 
 The telemetry plane (docs/DESIGN.md "Telemetry plane") rides in the same
 package: ``obs.metrics`` (typed time-series registry, ``MLSL_METRICS=1``),
@@ -35,7 +44,6 @@ from mlsl_tpu.obs.tracer import (  # noqa: F401
     enable,
     enabled,
     get_tracer,
-    span,
     trace_dir,
 )
 from mlsl_tpu.obs.export import (  # noqa: F401
@@ -64,7 +72,6 @@ __all__ = [
     "enable",
     "enabled",
     "get_tracer",
-    "span",
     "trace_dir",
     "flight_record",
     "render",

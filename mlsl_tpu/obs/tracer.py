@@ -16,8 +16,13 @@ Hot-path contract (mirrors the chaos-site ``if chaos._plans:`` pattern):
 instrumented code reads the module global once per operation and guards with
 ``tr = tracer._tracer`` / ``if tr is not None:`` — when tracing is off that is
 ONE attribute load and a None test, with zero allocations (asserted by
-tests/test_trace.py). Nothing else in this module runs until tracing is armed
-via ``MLSL_TRACE=1`` or :func:`enable`.
+tests/test_trace.py).
+
+The ring is the flight recorder, so it is **armed by default**: ``MLSL_TRACE``
+unset or truthy arms it at import, and a watchdog trip or a slow run always
+finds the spans that led to it. ``MLSL_TRACE=0`` disarms it and buys the
+contract above back (nothing else in this module runs); armed, an event is one
+tuple and one deque append, under a microsecond, a dozen a serving step.
 
 Event record (a plain tuple, one allocation per event when enabled)::
 
@@ -80,13 +85,16 @@ class Tracer:
         return ident
 
     def complete(self, name: str, cat: str, t0_ns: int,
-                 track: Optional[str] = None, **args) -> None:
-        """Record a complete span that began at ``t0_ns`` and ends now."""
+                 track: Optional[str] = None, **args) -> int:
+        """Record a complete span that began at ``t0_ns`` and ends now.
+        Returns the end stamp, so that what follows the span can start on
+        it."""
         end = time.perf_counter_ns()
         self.events.append(
             ("X", name, cat, t0_ns, end - t0_ns, self._tid(), track,
              args or None)
         )
+        return end
 
     def instant(self, name: str, cat: str, track: Optional[str] = None,
                 **args) -> None:
@@ -135,20 +143,6 @@ class Tracer:
             and (cat is None or ev[CAT] == cat)
         ]
 
-    def wait_stall_stats(self) -> Dict[str, dict]:
-        """Per-request wait-stall summary:
-        ``{request_name: {n, p50_ms, p95_ms, max_ms}}``."""
-        out = {}
-        for key, durs in self.wait_stall_durations().items():
-            durs.sort()
-            out[key] = {
-                "n": len(durs),
-                "p50_ms": _percentile(durs, 50) / 1e6,
-                "p95_ms": _percentile(durs, 95) / 1e6,
-                "max_ms": durs[-1] / 1e6,
-            }
-        return out
-
 
 def _percentile(sorted_vals: List[int], pct: float) -> float:
     """Nearest-rank percentile of an already-sorted list (stdlib-only; the
@@ -194,44 +188,20 @@ def trace_dir() -> str:
     return os.environ.get(ENV_DIR) or "."
 
 
-class span:
-    """Context-manager convenience for user code and cold paths::
-
-        with obs.span("load", "data", shard=3):
-            ...
-
-    Captures the tracer ONCE at __enter__ (a disable mid-block records
-    nothing; an enable mid-block records nothing — consistent either way).
-    Instrumented framework hot paths use the explicit ``_tracer`` guard
-    instead: this object allocates even when tracing is off.
-    """
-
-    __slots__ = ("name", "cat", "track", "args", "_t0", "_tr")
-
-    def __init__(self, name: str, cat: str = "user",
-                 track: Optional[str] = None, **args):
-        self.name = name
-        self.cat = cat
-        self.track = track
-        self.args = args
-
-    def __enter__(self) -> "span":
-        self._tr = _tracer
-        self._t0 = self._tr.now() if self._tr is not None else 0
-        return self
-
-    def __exit__(self, *exc) -> None:
-        if self._tr is not None:
-            self._tr.complete(self.name, self.cat, self._t0,
-                              track=self.track, **self.args)
-
-
 def _env_truthy(v: Optional[str]) -> bool:
     return (v or "").strip().lower() not in ("", "0", "false", "no", "off")
 
 
+def armed_by_env() -> bool:
+    """What ``MLSL_TRACE`` asks for: armed unless it is set to a falsy value
+    (``0``, ``false``, ``no``, ``off``); unset or empty is the default, armed.
+    ``Config.trace`` follows."""
+    v = os.environ.get(ENV_TRACE)
+    return v in (None, "") or _env_truthy(v)
+
+
 # Arm from the environment at import: instrumented modules import this module,
-# so MLSL_TRACE=1 on the launch command works with no code changes (the same
-# contract as MLSL_CHAOS in mlsl_tpu/chaos.py).
-if _env_truthy(os.environ.get(ENV_TRACE)):
+# so the flight recorder holds the run's spans with no code changes, and
+# MLSL_TRACE=0 on the launch command turns it off.
+if armed_by_env():
     enable()

@@ -74,7 +74,7 @@ class Request:
     state: str = "queued"          # queued | active | done | failed
     tokens: List[int] = field(default_factory=list)
     error: Optional[BaseException] = None
-    t_submit: float = 0.0
+    t_submit: int = 0              # time.perf_counter_ns(), the span ring's clock
     ttft_ms: Optional[float] = None
     _done: threading.Event = field(default_factory=threading.Event, repr=False)
     _resume: Optional[np.ndarray] = field(default=None, repr=False)
@@ -183,8 +183,9 @@ class InferenceEngine:
         self._next_seq_id = 0
         self._admit_counter = 0
         self._decode_fails = 0
-        self._t_start: Optional[float] = None
+        self._t_start: Optional[int] = None     # perf_counter_ns of step 0
         self._tokens_total = 0
+        self._step_no = -1      # counter of step() calls; every span's `step`
 
     # -- compiled programs -------------------------------------------------
 
@@ -317,7 +318,8 @@ class InferenceEngine:
                 )
             req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
                           id=self._next_req_id, route=route,
-                          eos_token=eos_token, t_submit=time.monotonic())
+                          eos_token=eos_token,
+                          t_submit=time.perf_counter_ns())
             self._next_req_id += 1
             self._pending.append(req)
             stats.record_serve("admitted")
@@ -329,9 +331,22 @@ class InferenceEngine:
         """One scheduler iteration: observe/tick the SLA ladder, admit up
         to the rung's batch limit, advance every in-flight sequence one
         token, retire the finished. Returns the number of in-flight
-        sequences after the step."""
+        sequences after the step.
+
+        With the span ring armed (the default) a step leaves one tree:
+        ``serve.step`` over ``serve.schedule``, a ``serve.admit`` an
+        admitted request (``serve.prefill``, ``serve.kv_write``,
+        ``serve.first_token``), ``serve.capacity``, ``serve.decode``
+        (``.prepare``, ``.dispatch``, ``.wait``, ``.sample``) and
+        ``serve.retire``. Every span carries ``step``, the request-scoped
+        ones ``req`` too; all stamps are the ring's (``perf_counter_ns``).
+        A step that finds nothing queued and nothing in flight leaves no
+        span: an idle engine must not flush the flight recorder."""
+        tr = obs_trace._tracer
+        t_step = time.perf_counter_ns()
+        self._step_no += 1
         if self._t_start is None:
-            self._t_start = time.monotonic()
+            self._t_start = t_step
         sentinel = obs_straggler.get_active()
         straggler = (sentinel is not None
                      and sentinel.shed_candidate() is not None)
@@ -339,12 +354,22 @@ class InferenceEngine:
             qlen = len(self._pending)
         self.governor.observe(queue_len=qlen, straggler=straggler)
         self.governor.tick()
+        if not qlen and not self._active:
+            tr = None
+        if tr is not None:
+            tr.complete("serve.schedule", "serve", t_step, step=self._step_no)
 
         self._admit()
         if self._active:
             self._decode_step()
-        self._retire()
+        t0 = time.perf_counter_ns()
+        retired = self._retire()
         self._gauges()
+        if tr is not None:
+            tr.complete("serve.retire", "serve", t0, step=self._step_no,
+                        retired=retired)
+            tr.complete("serve.step", "serve", t_step, step=self._step_no,
+                        inflight=len(self._active), queued=qlen)
         return len(self._active)
 
     def run(self, deadline_s: Optional[float] = None,
@@ -373,17 +398,19 @@ class InferenceEngine:
 
     def _admit(self) -> None:
         while len(self._active) < self.governor.batch_limit:
+            t0 = time.perf_counter_ns()
             with self._lock:
                 if not self._pending:
                     return
                 req = self._pending.popleft()
             seq_id = self._next_seq_id
             self._next_seq_id += 1
+            resumed = req._resume is not None
+            prefix = req._resume if resumed else req.prompt
             admitted_kv = False
+            error = None
             try:
                 chaos.inject("serve.admit", req_id=req.id)
-                prefix = req._resume if req._resume is not None \
-                    else req.prompt
                 if not self.cache.admit(seq_id, prefix.size + 1):
                     # pool backpressure: leave it queued, stop admitting
                     with self._lock:
@@ -392,27 +419,35 @@ class InferenceEngine:
                 admitted_kv = True
                 self._prefill_seq(req, seq_id, prefix)
             except Exception as e:  # fail this one request closed
+                error = type(e).__name__
                 if admitted_kv:
                     self.cache.release(seq_id)
                 self._active.pop(seq_id, None)
-                req.state = "failed"
-                req.error = e
-                req._done.set()
-                stats.record_serve("failed")
+                self._fail(req, e)
                 m = metrics._registry
                 if m is not None:
                     m.inc("mlsl_serve_requests_total", 1.0,
                           route=req.route, outcome="failed")
+            tr = obs_trace._tracer
+            if tr is not None:
+                tr.complete("serve.admit", "serve", t0, step=self._step_no,
+                            req=req.id, seq=seq_id,
+                            prompt_tokens=int(prefix.size),
+                            queue_wait_ns=t0 - req.t_submit,
+                            resumed=resumed, error=error)
 
     def _prefill_seq(self, req: Request, seq_id: int,
                      prefix: np.ndarray) -> None:
+        tr = obs_trace._tracer
+        step, rid = self._step_no, req.id
+        t0 = time.perf_counter_ns()
         n = int(prefix.size)
         tokens = np.zeros((self.ctx_len,), np.int32)
         tokens[:n] = prefix
-        tr = obs_trace._tracer
-        t0 = tr.now() if tr is not None else 0
         logits, k, v = self._prefill(
             self.params, jnp.asarray(tokens), jnp.int32(n))
+        if tr is not None:
+            t0 = tr.complete("serve.prefill", "serve", t0, step=step, req=rid)
         page_ids = jnp.asarray(
             np.asarray(self.cache.table_padded(seq_id), np.int32))
         if self.quant:
@@ -422,15 +457,18 @@ class InferenceEngine:
         else:
             self.kpool, self.vpool = self._write(
                 self.kpool, self.vpool, k, v, page_ids)
-        tok = int(np.argmax(np.asarray(logits)))
         if tr is not None:
-            tr.complete("serve.prefill", "serve", t0, seq=seq_id, tokens=n)
+            t0 = tr.complete("serve.kv_write", "serve", t0, step=step,
+                             req=rid, pages=self.cache.pages_for(n + 1))
+        tok = int(np.argmax(np.asarray(logits)))
+        t_first = tr.complete("serve.first_token", "serve", t0, step=step,
+                              req=rid) \
+            if tr is not None else time.perf_counter_ns()
         stats.record_serve("prefills")
         stats.record_serve("tokens_out")
         self._tokens_total += 1
-        resumed = req._resume is not None
-        if not resumed:
-            req.ttft_ms = (time.monotonic() - req.t_submit) * 1e3
+        if req.ttft_ms is None:         # a resumed request keeps its first
+            req.ttft_ms = (t_first - req.t_submit) / 1e6
             m = metrics._registry
             if m is not None:
                 m.observe("mlsl_serve_ttft_ms", req.ttft_ms,
@@ -447,6 +485,25 @@ class InferenceEngine:
             seq.finished = True
         self._active[seq_id] = seq
 
+    def _fail(self, req: Request, e: BaseException) -> None:
+        """Fail one request closed."""
+        req.state = "failed"
+        req.error = e
+        self._finish(req)
+        stats.record_serve("failed")
+
+    def _finish(self, req: Request) -> None:
+        """The request is done or failed: its ``serve.request`` span (from
+        ``submit()``, on the track ``req/<id>``), then wake whoever waits."""
+        tr = obs_trace._tracer
+        if tr is not None:
+            first = None if req.ttft_ms is None else int(req.ttft_ms * 1e6)
+            tr.complete("serve.request", "serve", req.t_submit,
+                        track=f"req/{req.id}", step=self._step_no,
+                        req=req.id, outcome=req.state,
+                        tokens=len(req.tokens), first_token_ns=first)
+        req._done.set()
+
     def _evict_youngest(self) -> None:
         """Preempt the youngest in-flight sequence: free its pages, stash
         prompt + everything generated as the resume prefix, put it back at
@@ -461,20 +518,31 @@ class InferenceEngine:
         with self._lock:
             self._pending.appendleft(req)
 
-    def _ensure_capacity(self) -> None:
+    def _ensure_capacity(self) -> int:
         """Every live sequence needs pages covering its next KV write; a
         pool that cannot extend evicts the youngest until it can. The
         budget invariant (num_pages >= max_pages_per_seq) guarantees this
-        terminates with at least one sequence still running."""
+        terminates with at least one sequence still running. Returns the
+        number of sequences it evicted."""
+        evicted = 0
         for seq in sorted(self._active.values(), key=lambda s: s.admitted_at):
             while seq.seq_id in self._active \
                     and not self.cache.extend(seq.seq_id, seq.position + 1):
                 self._evict_youngest()
+                evicted += 1
+        return evicted
 
     def _decode_step(self) -> None:
-        self._ensure_capacity()
+        tr = obs_trace._tracer
+        step = self._step_no
+        t0 = time.perf_counter_ns()
+        evicted = self._ensure_capacity()
+        if tr is not None:
+            tr.complete("serve.capacity", "serve", t0, step=step,
+                        evicted=evicted)
         if not self._active:
             return
+        t_decode = t0 = time.perf_counter_ns()
         live = sorted(self._active.values(), key=lambda s: s.admitted_at)
         b, mpp = self.max_batch, self.cache.max_pages_per_seq
         tokens = np.zeros((b,), np.int32)
@@ -487,19 +555,18 @@ class InferenceEngine:
             pt[i] = self.cache.table_padded(seq.seq_id)
         dtype = "bfloat16" if self.governor.precision_shed else None
         prog = self._decode_prog(dtype or self.cfg.dtype)
+        args = (jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(pt))
+        if tr is not None:
+            tr.complete("serve.decode.prepare", "serve", t0, step=step)
         attempt = 0
-        tr = obs_trace._tracer
         while True:
-            t_step = time.monotonic()
-            t0 = tr.now() if tr is not None else 0
+            t_try = time.perf_counter_ns()
             try:
                 # a chaos 'hang' here is a slow step, not an exception: it
                 # lands inside the timed window, breaches the TPOT SLO, and
                 # the governor sheds — the degraded-not-down path
                 chaos.inject("serve.decode", inflight=len(live))
-                out = prog(self.params, jnp.asarray(tokens),
-                           jnp.asarray(positions), jnp.asarray(pt),
-                           self.kpool, self.vpool,
+                out = prog(self.params, *args, self.kpool, self.vpool,
                            *((self.kscale, self.vscale)
                              if self.quant else ()))
                 break
@@ -514,14 +581,18 @@ class InferenceEngine:
                     continue
                 self._decode_fault(e)
                 return
+        t0 = tr.complete("serve.decode.dispatch", "serve", t_try, step=step,
+                         attempt=attempt) \
+            if tr is not None else t_try
         if self.quant:
             logits, self.kpool, self.vpool, self.kscale, self.vscale = out
         else:
             logits, self.kpool, self.vpool = out
         logits = np.asarray(logits)           # blocks until the step is done
-        step_ms = (time.monotonic() - t_step) * 1e3
-        if tr is not None:
-            tr.complete("serve.decode", "serve", t0, inflight=len(live))
+        t0 = tr.complete("serve.decode.wait", "serve", t0, step=step,
+                         bytes=logits.nbytes) \
+            if tr is not None else time.perf_counter_ns()
+        step_ms = (t0 - t_try) / 1e6
         self._decode_fails = 0
         if attempt > 0:
             stats.record_serve("recoveries")
@@ -532,8 +603,10 @@ class InferenceEngine:
         stats.record_serve("decode_steps")
         stats.record_serve("tokens_out", len(live))
         self._tokens_total += len(live)
+        tokens_live = 0
         for seq in live:
             tok = int(np.argmax(logits[seq.slot]))
+            tokens_live += seq.position + 1
             seq.position += 1
             seq.last_token = tok
             seq.req.tokens.append(tok)
@@ -542,6 +615,13 @@ class InferenceEngine:
                     or len(seq.req.tokens) >= seq.req.max_new_tokens \
                     or seq.position >= self.ctx_len:
                 seq.finished = True
+        if tr is not None:
+            tr.complete("serve.decode.sample", "serve", t0, step=step)
+            tr.complete("serve.decode", "serve", t_decode, step=step,
+                        inflight=len(live), tokens_live=tokens_live,
+                        pages_held=self.cache.held_pages,
+                        pages_gathered=b * mpp,
+                        pool_pages=self.cache.num_pages)
 
     def _decode_fault(self, e: BaseException) -> None:
         cls = supervisor.classify(e)
@@ -555,23 +635,22 @@ class InferenceEngine:
         for seq in list(self._active.values()):
             self._active.pop(seq.seq_id)
             self.cache.release(seq.seq_id)
-            seq.req.state = "failed"
-            seq.req.error = e
-            seq.req._done.set()
-            stats.record_serve("failed")
+            self._fail(seq.req, e)
         self._decode_fails = 0
 
-    def _retire(self) -> None:
+    def _retire(self) -> int:
         m = metrics._registry
-        for seq in [s for s in self._active.values() if s.finished]:
+        finished = [s for s in self._active.values() if s.finished]
+        for seq in finished:
             self._active.pop(seq.seq_id)
             self.cache.release(seq.seq_id)
             seq.req.state = "done"
-            seq.req._done.set()
+            self._finish(seq.req)
             stats.record_serve("completed")
             if m is not None:
                 m.inc("mlsl_serve_requests_total", 1.0,
                       route=seq.req.route, outcome="done")
+        return len(finished)
 
     def _gauges(self) -> None:
         m = metrics._registry
@@ -584,7 +663,7 @@ class InferenceEngine:
         m.set("mlsl_serve_kv_free_pages", float(self.cache.free_pages))
         m.set("mlsl_serve_batch_limit", float(self.governor.batch_limit))
         if self._t_start is not None:
-            dt = time.monotonic() - self._t_start
+            dt = (time.perf_counter_ns() - self._t_start) / 1e9
             if dt > 0:
                 m.set("mlsl_serve_tokens_per_s", self._tokens_total / dt)
 

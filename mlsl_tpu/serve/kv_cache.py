@@ -94,6 +94,11 @@ class PagedKVCache:
     def free_pages(self) -> int:
         return len(self._free)
 
+    @property
+    def held_pages(self) -> int:
+        """Pages that some sequence holds (the garbage page is not one)."""
+        return self.num_pages - len(self._free)
+
     def __len__(self) -> int:
         return len(self._tables)
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Produce a demo comm-timeline trace from the MLP example workload on the
-# 8-device CPU proof mesh: a few per-layer-sync training steps under
-# MLSL_TRACE=1, dumped as Perfetto JSON and summarized in the terminal.
+# 8-device CPU proof mesh: a few per-layer-sync training steps with the span
+# ring armed (the default), dumped as Perfetto JSON and summarized in the terminal.
 # Load the printed trace path in ui.perfetto.dev (or chrome://tracing) to see
 # one track per request/bucket plus the trainer/dispatcher thread tracks.
 set -euo pipefail

@@ -143,6 +143,19 @@ def _route_artifacts(tmp_path, monkeypatch):
     monkeypatch.setenv("MLSL_TRACE_DIR", str(tmp_path))
 
 
+@pytest.fixture(autouse=True)
+def _default_tracer():
+    """Every test starts as a fresh process would: the span ring armed unless
+    MLSL_TRACE=0 says otherwise, and empty. The ring is process-wide, so
+    without this a test that disarms it (or fills it) decides what the next
+    one in its worker sees."""
+    from mlsl_tpu.obs import tracer
+
+    tracer.disable()
+    if tracer.armed_by_env():
+        tracer.enable()
+
+
 def skip_if_loaded(detail: str) -> None:
     """Comparative-timing deflake contract (KNOWN_FAILURES.md "Known
     flakes"): a bench smoke's LIVE timing comparison gets best-of-N inside

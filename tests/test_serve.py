@@ -362,20 +362,133 @@ def test_serve_metric_families_and_healthz(env):
         obs_metrics.disable()
 
 
-def test_serve_spans_on_timeline(env):
-    from mlsl_tpu.obs import tracer as obs_trace
+#: the serving span tree: child -> parent (both of one ``step``; the
+#: children of ``serve.admit`` of one ``req`` too)
+SPAN_PARENT = {
+    "serve.schedule": "serve.step", "serve.admit": "serve.step",
+    "serve.capacity": "serve.step", "serve.decode": "serve.step",
+    "serve.retire": "serve.step",
+    "serve.prefill": "serve.admit", "serve.kv_write": "serve.admit",
+    "serve.first_token": "serve.admit",
+    "serve.decode.prepare": "serve.decode",
+    "serve.decode.dispatch": "serve.decode",
+    "serve.decode.wait": "serve.decode", "serve.decode.sample": "serve.decode",
+}
+REQUEST_SCOPED = {"serve.admit", "serve.prefill", "serve.kv_write",
+                  "serve.first_token", "serve.request"}
 
-    tr = obs_trace.enable()
-    try:
-        cfg = _cfg()
+
+@pytest.mark.parametrize("evict", [False, True],
+                         ids=["plain", "evicted_and_resumed"])
+def test_serve_spans_on_timeline(env, evict):
+    """One span tree a step, in the ring without anybody arming it: names,
+    ``step`` on every span and ``req`` on the request-scoped ones, children
+    inside their parents, one ``serve.request`` a request, and the parts of
+    a first token's time (queue wait, admission, the rest of the step)
+    summing to the TTFT a caller measures round ``step()``."""
+    import time
+
+    from mlsl_tpu.obs import tracer as obs_trace
+    from mlsl_tpu.obs.tracer import ARGS, CAT, DUR, NAME, PH, TRACK, TS
+
+    tr = obs_trace.get_tracer()
+    assert tr is not None               # armed by default (MLSL_TRACE unset)
+    cfg = _cfg()
+    if evict:
+        # the pool of test_engine_eviction_preempts_youngest_and_resumes
+        eng = serve.InferenceEngine(env, cfg, tp=1, seed=0, max_batch=2)
+        eng.cache = PagedKVCache(cfg, page_elems=16, budget_mb=0.04,
+                                 max_len=64)
+        prompts, new = [np.arange(1, 31, dtype=np.int32),
+                        np.arange(2, 32, dtype=np.int32)], 8
+    else:
         eng = serve.InferenceEngine(env, cfg, tp=1, seed=0)
-        eng.submit(np.arange(1, 9), 3)
-        eng.run()
-        names = {e[1] for e in tr.snapshot()}
-        assert "serve.prefill" in names and "serve.decode" in names
-        eng.close()
-    finally:
-        obs_trace.disable()
+        prompts, new = _prompts(cfg, 3), 4
+    tr.clear()
+    reqs = [eng.submit(p, new) for p in prompts]
+    first_seen = {}                     # req id -> stamp after its step
+    while not all(r.done() for r in reqs):
+        eng.step()
+        now = time.perf_counter_ns()
+        for r in reqs:
+            if r.tokens:
+                first_seen.setdefault(r.id, now)
+    eng.step()                          # idle: must leave no span
+    assert all(r.state == "done" for r in reqs)
+    spans = [e for e in tr.snapshot() if e[CAT] == "serve" and e[PH] == "X"]
+    names = {e[NAME] for e in spans}
+    assert names == set(SPAN_PARENT) | {"serve.step", "serve.request"}
+    assert all("step" in e[ARGS] for e in spans)
+    assert all("req" in e[ARGS] for e in spans if e[NAME] in REQUEST_SCOPED)
+    steps = {e[ARGS]["step"]: e for e in spans if e[NAME] == "serve.step"}
+    assert sorted(steps) == list(range(min(steps), min(steps) + len(steps)))
+
+    def parent_of(e):
+        want = SPAN_PARENT[e[NAME]]
+        if want == "serve.step":
+            return steps[e[ARGS]["step"]]
+        hits = [p for p in spans if p[NAME] == want
+                and p[ARGS]["step"] == e[ARGS]["step"]
+                and p[TS] <= e[TS] and e[TS] + e[DUR] <= p[TS] + p[DUR]
+                and ("req" not in e[ARGS] or p[ARGS]["req"] == e[ARGS]["req"])]
+        assert len(hits) == 1, (e, hits)
+        return hits[0]
+
+    for e in spans:
+        if e[NAME] in SPAN_PARENT:
+            p = parent_of(e)
+            assert p[TS] <= e[TS] and e[TS] + e[DUR] <= p[TS] + p[DUR], (e, p)
+            assert e[TRACK] is None
+    decodes = [e for e in spans if e[NAME] == "serve.decode"]
+    for e in decodes:
+        a = e[ARGS]
+        assert a["pages_gathered"] == eng.max_batch * eng.cache.max_pages_per_seq
+        assert a["pool_pages"] == eng.cache.num_pages
+        assert 0 < a["pages_held"] <= a["pool_pages"]
+        assert a["inflight"] <= a["tokens_live"] <= a["pages_held"] * 16
+    waits = [e for e in spans if e[NAME] == "serve.decode.wait"]
+    assert len(waits) == len(decodes)
+    assert all(e[ARGS]["bytes"] == eng.max_batch * cfg.vocab * 4 for e in waits)
+    evicted = sum(e[ARGS]["evicted"] for e in spans
+                  if e[NAME] == "serve.capacity")
+    assert evicted == stats.SERVE_COUNTERS["kv_evictions"]
+    assert (evicted >= 1) == evict
+    assert eng.cache.held_pages == 0
+
+    for r in reqs:
+        done = [e for e in spans if e[NAME] == "serve.request"
+                and e[ARGS]["req"] == r.id]
+        assert len(done) == 1
+        d = done[0]
+        assert d[TRACK] == f"req/{r.id}" and d[TS] == r.t_submit
+        assert d[ARGS]["outcome"] == "done" and d[ARGS]["tokens"] == new
+        admits = [e for e in spans if e[NAME] == "serve.admit"
+                  and e[ARGS]["req"] == r.id]
+        assert [a[ARGS]["resumed"] for a in admits] \
+            == [False] + [True] * (len(admits) - 1)
+        a = admits[0]
+        assert a[ARGS]["prompt_tokens"] == r.prompt.size
+        step = steps[a[ARGS]["step"]]
+        tail = step[TS] + step[DUR] - (a[TS] + a[DUR])
+        parts = a[ARGS]["queue_wait_ns"] + a[DUR] + tail
+        # the parts are stamped on one clock, so they close on the end of
+        # the admitting step, which is when a caller can see the token
+        assert parts == step[TS] + step[DUR] - r.t_submit
+        nxt = steps.get(a[ARGS]["step"] + 1)
+        measured = first_seen[r.id] - r.t_submit
+        assert parts <= measured
+        if nxt is not None:
+            assert measured <= nxt[TS] - r.t_submit
+        # the engine's own TTFT is to the first token's read-back
+        first = [e for e in spans if e[NAME] == "serve.first_token"
+                 and e[ARGS]["req"] == r.id][0]
+        assert d[ARGS]["first_token_ns"] == pytest.approx(
+            first[TS] + first[DUR] - r.t_submit, abs=1)
+        assert r.ttft_ms == pytest.approx(d[ARGS]["first_token_ns"] / 1e6)
+    resumed = [e for e in spans if e[NAME] == "serve.admit"
+               and e[ARGS]["resumed"]]
+    assert bool(resumed) == evict
+    eng.close()
 
 
 def test_serve_stats_line(env, tmp_path, monkeypatch):

@@ -165,11 +165,36 @@ def test_bucketed_request_lifecycle(env, tracing):
 # -- disabled path ------------------------------------------------------------
 
 
-def test_disabled_path_records_nothing_and_allocates_nothing(env):
-    """MLSL_TRACE unset: the hot paths run with the tracer global None — no
-    events anywhere, and ZERO allocations attributed to mlsl_tpu/obs/* (the
-    acceptance contract; tracemalloc attributes every allocation to the frame
-    that made it, so any tracer-side tuple/dict would show up)."""
+def _import_probe(value):
+    """What a fresh process with MLSL_TRACE=<value> (None: unset) finds at
+    import: the module's arming code run again against that environment."""
+    import importlib
+
+    before = os.environ.pop(tracer_mod.ENV_TRACE, None)
+    if value is not None:
+        os.environ[tracer_mod.ENV_TRACE] = value
+    try:
+        importlib.reload(tracer_mod)
+        return tracer_mod.get_tracer()
+    finally:
+        os.environ.pop(tracer_mod.ENV_TRACE, None)
+        if before is not None:
+            os.environ[tracer_mod.ENV_TRACE] = before
+        importlib.reload(tracer_mod)
+
+
+def test_disabled_path_records_nothing_and_allocates_nothing(env, monkeypatch):
+    """MLSL_TRACE=0: the ring does not exist after import and the hot paths
+    run with the tracer global None — no events anywhere, and ZERO
+    allocations attributed to mlsl_tpu/obs/* (the acceptance contract;
+    tracemalloc attributes every allocation to the frame that made it, so any
+    tracer-side tuple/dict would show up)."""
+    assert _import_probe("0") is None
+    monkeypatch.setenv(tracer_mod.ENV_TRACE, "0")
+    assert not tracer_mod.armed_by_env()
+    from mlsl_tpu.config import Config
+
+    assert Config.from_env().trace is False
     obs.disable()
     assert obs.get_tracer() is None
     req, buf = _request(env, name="offreq")
@@ -188,6 +213,22 @@ def test_disabled_path_records_nothing_and_allocates_nothing(env):
     ).statistics("filename")
     assert not stats, f"tracer allocated while disabled: {stats}"
     assert obs.get_tracer() is None
+
+
+@pytest.mark.parametrize("value", [None, "", "1", "true"])
+def test_ring_is_armed_by_default(monkeypatch, value):
+    """MLSL_TRACE unset (or truthy): the ring is the flight recorder and
+    exists after import at its full capacity; Config.trace follows."""
+    tr = _import_probe(value)
+    assert tr is not None and tr.capacity == tracer_mod.DEFAULT_CAPACITY
+    if value is None:
+        monkeypatch.delenv(tracer_mod.ENV_TRACE, raising=False)
+    else:
+        monkeypatch.setenv(tracer_mod.ENV_TRACE, value)
+    assert tracer_mod.armed_by_env()
+    from mlsl_tpu.config import Config
+
+    assert Config.from_env().trace is True
 
 
 # -- ring buffer --------------------------------------------------------------
