@@ -395,8 +395,10 @@ class Smoke:
 
     def oracle_agrees(self, engine, prompt, tokens):
         """Greedy decoding is a chain of argmaxes. The paged decode step and
-        the unpaged prefill are different programs: bit-exact on the CPU, on
-        the chip their logits differ in the last bf16 bits, so a near-tie may
+        the unpaged prefill are different programs (the step sums over the
+        live pages, the prefill over its padded context): on the CPU their
+        float32 logits agree to rounding, on the chip they differ in the
+        last bf16 bits, so a near-tie may
         fall the other way and the two chains part for good. Walk the
         engine's chain: wherever oracle_generate picks another token, the
         engine's token must be a near-tie under the ORACLE's own logits, and

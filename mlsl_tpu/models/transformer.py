@@ -44,6 +44,7 @@ from mlsl_tpu.models.train import (
     _unflatten_like,
 )
 from mlsl_tpu.comm.mesh import NUM_GRID_AXES
+from mlsl_tpu.ops import paged_attention
 from mlsl_tpu.parallel.sequence import (
     ring_attention, ulysses_attention, zigzag_perm, zigzag_ring_attention,
 )
@@ -320,17 +321,19 @@ def local_loss(params, tokens, labels, cfg, sp, tp, comm=None):
 # The serving engine (serve/engine.py) compiles these bodies as model-axis
 # shard_map programs (dp = sp = 1): a per-sequence prefill, and the batched
 # decode step over the paged KV pool. KV pages shard over 'model' on the
-# heads dim (the wqkv spec); TP output reductions route through the
-# collective engine's selection table (algos.inline_allreduce) when a
-# (model group, config) pair is passed, so the µs-class decode allreduces
-# are pallas_rhd-eligible and breaker degradation to lax stays intact.
+# merged heads x head_dim axis (whole heads a rank, the wqkv spec); TP output
+# reductions route through the collective engine's selection table
+# (algos.inline_allreduce) when a (model group, config) pair is passed, so
+# the µs-class decode allreduces are pallas_rhd-eligible and breaker
+# degradation to lax stays intact.
 #
-# Bit-exactness contract (tests/test_serve.py): attention math runs in f32
-# over f32-at-rest KV in BOTH paths, and the engine pins the paged decode's
-# gathered-context extent (max_pages * page_elems) to the prefill length, so
-# every reduction has the same extent in both programs — masked-out page
-# slots contribute exact float zeros and the paged step reproduces the
-# unpaged full-context forward bit for bit.
+# Attention math runs in f32 over f32-at-rest KV (or int8 with f32 scales) in
+# both programs. The decode step reads K and V where they lie in the pool,
+# through the flat list of the pages some live sequence holds
+# (ops/paged_attention.ragged_paged_attention): its reductions walk that
+# list and not the prefill's padded context, so the two programs agree to
+# rounding, not to the bit; tests/test_serve.py holds every served token's
+# logit within a tolerance of the unpaged oracle's best.
 
 
 def _decode_reduce(x, tp: int, comm):
@@ -379,7 +382,8 @@ def prefill_local(params, tokens, length, cfg: TransformerConfig, tp: int,
     positions' K/V are computed but land on the KV cache's reserved garbage
     page (serve/kv_cache.py) and are masked out of every decode read.
     Returns (next-token logits (V,) f32 read at position length-1,
-    k, v: (n_blocks, S, Hl, Dh) f32 local head shards).
+    k, v: (n_blocks, S, Hl*Dh) f32 local head shards, heads merged with
+    head_dim as the pool's pages store them).
     """
     mlsl_assert(cfg.n_experts == 0, "decode mode serves dense-MLP models")
     mlsl_assert(not cfg.sharded_vocab,
@@ -400,8 +404,8 @@ def prefill_local(params, tokens, length, cfg: TransformerConfig, tp: int,
         q, k, v = (
             jnp.moveaxis(qkv[c], 1, 0).astype(jnp.float32) for c in range(3)
         )  # (Hl, S, Dh) f32 — the at-rest KV dtype
-        ks.append(jnp.moveaxis(k, 0, 1))   # (S, Hl, Dh): page layout
-        vs.append(jnp.moveaxis(v, 0, 1))
+        ks.append(jnp.moveaxis(k, 0, 1).reshape(n, -1))  # (S, Hl*Dh): page
+        vs.append(jnp.moveaxis(v, 0, 1).reshape(n, -1))  # layout
         attn = _causal_attn_f32(q, k, v, scale)
         o = mxu_einsum("hsx,hxd->sd", attn.astype(cdt), ap["wo"].astype(cdt))
         o = _decode_reduce(o, tp, comm)
@@ -424,19 +428,24 @@ def prefill_local(params, tokens, length, cfg: TransformerConfig, tp: int,
     return logits, jnp.stack(ks), jnp.stack(vs)
 
 
-def decode_local(params, tokens, positions, pt, kpool, vpool,
+def decode_local(params, slots, live, kpool, vpool,
                  cfg: TransformerConfig, tp: int, comm=None, dtype=None,
                  kscale=None, vscale=None):
     """One continuous-batching decode step (call inside shard_map).
 
-    tokens: (B,) int32 the token each slot feeds; positions: (B,) int32 the
+    slots: (3, B) int32, a column a batch slot: the token the slot feeds, the
     index that token occupies (its K/V is written there, and it attends over
-    indices <= it); pt: (B, M) int32 page tables (0 = the reserved garbage
-    page — inactive slots carry all-zero tables and positions and their
-    writes land there); kpool/vpool: (n_blocks, Np, page, Hl, Dh) KV pools,
-    int8 with kscale/vscale (n_blocks, Np, page, Hl) for the quantized
-    variant (kv_block_quant codec). Returns (logits (B, V) f32, kpool,
-    vpool[, kscale, vscale]) — the engine donates the pools.
+    indices <= it) and the pool page that index lies in (0 = the reserved
+    garbage page: inactive slots carry zeros and their writes land there).
+    live: (3, capacity) int32, the flat list of the pages some live sequence
+    holds, in slot order: pool page, owner slot (-1 pads the list), token
+    index of the page's first row. kpool/vpool: (n_blocks, Np, page, Hl*Dh)
+    KV pools, int8 with kscale/vscale (n_blocks, Np, Hl*page) for the
+    quantized variant (kv_block_quant codec; a page's scales head-major).
+    The new token's K and V are scattered into the pools and attention reads
+    the listed pages where they lie; no operation's cost follows the pool's
+    size. Returns (logits (B, V) f32, kpool, vpool[, kscale, vscale]) — the
+    engine donates the pools.
     """
     mlsl_assert(cfg.n_experts == 0, "decode mode serves dense-MLP models")
     mlsl_assert(not cfg.sharded_vocab,
@@ -444,16 +453,13 @@ def decode_local(params, tokens, positions, pt, kpool, vpool,
     cdt = jnp.dtype(dtype or cfg.dtype)
     quant = kscale is not None
     page = kpool.shape[2]
-    t_ctx = pt.shape[1] * page
+    tokens, positions, pages_b = slots
     emb = params["embed"]
     h = (emb["tok"][tokens] + emb["pos"][positions]).astype(cdt)  # (B, dm)
-    scale = 1.0 / float(np.sqrt(cfg.head_dim))
     b = tokens.shape[0]
-    pages_b = jnp.take_along_axis(
-        pt, (positions // page)[:, None], axis=1
-    )[:, 0]                                                       # (B,)
     offs_b = positions % page
-    mask = jnp.arange(t_ctx)[None, :] <= positions[:, None]       # (B, T)
+    pages, owners, bases = live
+    valid, mine = paged_attention.live_masks(owners, bases, positions, page)
     for i in range(cfg.n_blocks):
         lnp = params[f"blk{i}.ln"]
         ap = params[f"blk{i}.attn"]
@@ -465,28 +471,17 @@ def decode_local(params, tokens, positions, pt, kpool, vpool,
         knew = qkv[:, 1].astype(jnp.float32)
         vnew = qkv[:, 2].astype(jnp.float32)
         if quant:
-            kq, ksc = kv_block_quant(knew)
-            vq, vsc = kv_block_quant(vnew)
-            kpool = kpool.at[i, pages_b, offs_b].set(kq)
-            vpool = vpool.at[i, pages_b, offs_b].set(vq)
-            kscale = kscale.at[i, pages_b, offs_b].set(ksc)
-            vscale = vscale.at[i, pages_b, offs_b].set(vsc)
-            kseq = kpool[i][pt].astype(jnp.float32) \
-                * kscale[i][pt][..., None]
-            vseq = vpool[i][pt].astype(jnp.float32) \
-                * vscale[i][pt][..., None]
-        else:
-            kpool = kpool.at[i, pages_b, offs_b].set(knew)
-            vpool = vpool.at[i, pages_b, offs_b].set(vnew)
-            kseq = kpool[i][pt]                 # (B, M, page, Hl, Dh)
-            vseq = vpool[i][pt]
-        kseq = kseq.reshape(b, t_ctx, *kseq.shape[-2:])           # (B, T, Hl, Dh)
-        vseq = vseq.reshape(b, t_ctx, *vseq.shape[-2:])
-        s = jnp.einsum("bhx,bthx->bht", q * scale, kseq)
-        s = jnp.where(mask[:, None, :], s, -jnp.inf)
-        attn = jnp.einsum(
-            "bht,bthx->bhx", jax.nn.softmax(s, axis=-1), vseq
-        )                                                         # (B, Hl, Dh)
+            knew, ksc = kv_block_quant(knew)
+            vnew, vsc = kv_block_quant(vnew)
+            at = (i, pages_b[:, None],
+                  jnp.arange(ksc.shape[1]) * page + offs_b[:, None])
+            kscale = kscale.at[at].set(ksc)
+            vscale = vscale.at[at].set(vsc)
+        kpool = kpool.at[i, pages_b, offs_b].set(knew.reshape(b, -1))
+        vpool = vpool.at[i, pages_b, offs_b].set(vnew.reshape(b, -1))
+        attn = paged_attention.ragged_paged_attention(
+            q, kpool, vpool, i, pages, owners, valid, mine, kscale, vscale,
+            chunk=paged_attention.PAGES_PER_CHUNK)
         o = mxu_einsum("bhx,hxd->bd", attn.astype(cdt), ap["wo"].astype(cdt))
         o = _decode_reduce(o, tp, comm)
         h = (h.astype(jnp.float32) + o).astype(cdt)

@@ -43,6 +43,7 @@ __all__ = [
     "Request",
     "PagedKVCache",
     "oracle_generate",
+    "oracle_logit_gap",
     "oracle_logits",
 ]
 
@@ -50,6 +51,7 @@ _LAZY = {
     "InferenceEngine": "mlsl_tpu.serve.engine",
     "Request": "mlsl_tpu.serve.engine",
     "oracle_generate": "mlsl_tpu.serve.engine",
+    "oracle_logit_gap": "mlsl_tpu.serve.engine",
     "oracle_logits": "mlsl_tpu.serve.engine",
     "PagedKVCache": "mlsl_tpu.serve.kv_cache",
 }

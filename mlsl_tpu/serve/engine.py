@@ -12,7 +12,10 @@ device arrays, and three compiled smap programs:
   HBM). The int8 variant quantizes in-graph via ``kv_block_quant``.
 - **decode** — one iteration-level step over the whole slot array
   (``models.transformer.decode_local``): every in-flight sequence advances
-  one token per call, sequences join and retire between calls. Built per
+  one token per call, sequences join and retire between calls. Attention
+  reads the pools in place through the flat list of the pages the live
+  sequences hold, a chunk of pages a trip, so a step's work follows that
+  list's length and not the batch's or the pool's capacity. Built per
   compute dtype so the SLA governor's precision shed (bf16) is just a
   different entry in the program cache — KV at rest stays f32/int8 either
   way, which is why recovery is numerically clean.
@@ -54,6 +57,7 @@ from mlsl_tpu.log import mlsl_assert
 from mlsl_tpu.models import transformer as tfm
 from mlsl_tpu.obs import metrics, tracer as obs_trace
 from mlsl_tpu.obs import straggler as obs_straggler
+from mlsl_tpu.ops import paged_attention
 from mlsl_tpu.serve import kv_cache as kvc, sla
 
 #: consecutive failed decode steps before the in-flight batch is failed
@@ -139,9 +143,7 @@ class InferenceEngine:
             max_len=cfg.seq_len,
             quant=self.quant,
         )
-        # the bit-exactness pin: gathered decode context extent == prefill
-        # pad length (kv_cache asserts seq_len % page_elems == 0)
-        self.ctx_len = self.cache.ctx_len
+        self.ctx_len = self.cache.ctx_len   # the prefill's one padded shape
         self.max_batch = int(max_batch if max_batch is not None
                              else self.config.serve_max_batch)
         self.governor = sla.SLAGovernor(
@@ -153,11 +155,19 @@ class InferenceEngine:
         sla._set_active(self.governor)
 
         # KV pools: page 0 is the reserved garbage page (kv_cache.py), so
-        # the page axis is num_pages + 1. Heads shard over 'model'.
+        # the page axis is num_pages + 1. A page's row is one token's heads
+        # merged with head_dim (a lane-dense minor axis: the device keeps a
+        # page contiguous, and a 64-wide one would be padded or transposed);
+        # whole heads shard over 'model'. Scales: one a token and head, a
+        # page's on one row, head-major for the same two reasons.
         npg, page = self.cache.num_pages + 1, self.cache.page_elems
-        pool_shape = (cfg.n_blocks, npg, page, cfg.n_heads, cfg.head_dim)
-        self._pool_spec = P(None, None, None, MODEL_AXIS, None)
-        self._scale_spec = P(None, None, None, MODEL_AXIS)
+        pool_shape = (cfg.n_blocks, npg, page, cfg.n_heads * cfg.head_dim)
+        self._pool_spec = P(None, None, None, MODEL_AXIS)
+        self._scale_spec = P(None, None, MODEL_AXIS)
+        # the decode program's live-page list: room for every page of the
+        # pool, a whole number of the chunks the attention walks
+        self._chunk = paged_attention.PAGES_PER_CHUNK
+        self._list_cap = -(-self.cache.num_pages // self._chunk) * self._chunk
         kv_dt = jnp.int8 if self.quant else jnp.float32
         self.kpool = jax.device_put(
             jnp.zeros(pool_shape, kv_dt),
@@ -166,7 +176,7 @@ class InferenceEngine:
             jnp.zeros(pool_shape, kv_dt),
             NamedSharding(self.mesh, self._pool_spec))
         if self.quant:
-            sshape = pool_shape[:-1]
+            sshape = pool_shape[:2] + (cfg.n_heads * page,)
             self.kscale = jax.device_put(
                 jnp.ones(sshape, jnp.float32),
                 NamedSharding(self.mesh, self._scale_spec))
@@ -191,7 +201,7 @@ class InferenceEngine:
 
     def _build_programs(self) -> None:
         cfg, tp, comm = self.cfg, self.tp, self.comm
-        kv_spec = P(None, None, MODEL_AXIS, None)
+        kv_spec = P(None, None, MODEL_AXIS)
 
         def prefill_body(params, tokens, length):
             return tfm.prefill_local(params, tokens, length, cfg, tp,
@@ -209,14 +219,17 @@ class InferenceEngine:
         if self.quant:
             def write_body(kpool, vpool, kscale, vscale, k, v, page_ids):
                 m = page_ids.shape[0]
-                kq, ksc = tfm.kv_block_quant(k)
-                vq, vsc = tfm.kv_block_quant(v)
-                shp = (cfg.n_blocks, m, page) + kq.shape[-2:]
+                heads = k.shape[:2] + (-1, cfg.head_dim)   # one scale a head
+                kq, ksc = tfm.kv_block_quant(k.reshape(heads))
+                vq, vsc = tfm.kv_block_quant(v.reshape(heads))
+                shp = (cfg.n_blocks, m, page, -1)
                 kpool = kpool.at[:, page_ids].set(kq.reshape(shp))
                 vpool = vpool.at[:, page_ids].set(vq.reshape(shp))
-                sshp = shp[:-1]
-                kscale = kscale.at[:, page_ids].set(ksc.reshape(sshp))
-                vscale = vscale.at[:, page_ids].set(vsc.reshape(sshp))
+                sshp = (cfg.n_blocks, m, -1)
+                kscale = kscale.at[:, page_ids].set(
+                    ksc.reshape(shp).swapaxes(2, 3).reshape(sshp))
+                vscale = vscale.at[:, page_ids].set(
+                    vsc.reshape(shp).swapaxes(2, 3).reshape(sshp))
                 return kpool, vpool, kscale, vscale
 
             self._write = jax.jit(smap(
@@ -231,7 +244,7 @@ class InferenceEngine:
         else:
             def write_body(kpool, vpool, k, v, page_ids):
                 m = page_ids.shape[0]
-                shp = (cfg.n_blocks, m, page) + k.shape[-2:]
+                shp = (cfg.n_blocks, m, page, -1)
                 kpool = kpool.at[:, page_ids].set(k.reshape(shp))
                 vpool = vpool.at[:, page_ids].set(v.reshape(shp))
                 return kpool, vpool
@@ -253,27 +266,27 @@ class InferenceEngine:
         cfg, tp, comm = self.cfg, self.tp, self.comm
 
         if self.quant:
-            def decode_body(params, tokens, positions, pt,
+            def decode_body(params, slots, live,
                             kpool, vpool, kscale, vscale):
                 return tfm.decode_local(
-                    params, tokens, positions, pt, kpool, vpool, cfg, tp,
+                    params, slots, live, kpool, vpool, cfg, tp,
                     comm=comm, dtype=dtype, kscale=kscale, vscale=vscale)
 
-            in_specs = (self.specs, P(), P(), P(), self._pool_spec,
+            in_specs = (self.specs, P(), P(), self._pool_spec,
                         self._pool_spec, self._scale_spec, self._scale_spec)
             out_specs = (P(), self._pool_spec, self._pool_spec,
                          self._scale_spec, self._scale_spec)
-            donate = (4, 5, 6, 7)
+            donate = (3, 4, 5, 6)
         else:
-            def decode_body(params, tokens, positions, pt, kpool, vpool):
+            def decode_body(params, slots, live, kpool, vpool):
                 return tfm.decode_local(
-                    params, tokens, positions, pt, kpool, vpool, cfg, tp,
+                    params, slots, live, kpool, vpool, cfg, tp,
                     comm=comm, dtype=dtype)
 
-            in_specs = (self.specs, P(), P(), P(),
+            in_specs = (self.specs, P(), P(),
                         self._pool_spec, self._pool_spec)
             out_specs = (P(), self._pool_spec, self._pool_spec)
-            donate = (4, 5)
+            donate = (3, 4)
 
         prog = jax.jit(
             smap(decode_body, self.mesh, in_specs=in_specs,
@@ -544,18 +557,18 @@ class InferenceEngine:
             return
         t_decode = t0 = time.perf_counter_ns()
         live = sorted(self._active.values(), key=lambda s: s.admitted_at)
-        b, mpp = self.max_batch, self.cache.max_pages_per_seq
-        tokens = np.zeros((b,), np.int32)
-        positions = np.zeros((b,), np.int32)
-        pt = np.zeros((b, mpp), np.int32)     # inactive slots: garbage page
+        # a column a slot: token, position, the page the position lies in
+        # (inactive slots: zeros, so their writes land on the garbage page)
+        slots = np.zeros((3, self.max_batch), np.int32)
         for i, seq in enumerate(live):
             seq.slot = i
-            tokens[i] = seq.last_token
-            positions[i] = seq.position
-            pt[i] = self.cache.table_padded(seq.seq_id)
+            slots[:, i] = (seq.last_token, seq.position,
+                           self.cache.page_of(seq.seq_id, seq.position))
+        pages, held = self.cache.live_list(
+            [seq.seq_id for seq in live], self._list_cap)
         dtype = "bfloat16" if self.governor.precision_shed else None
         prog = self._decode_prog(dtype or self.cfg.dtype)
-        args = (jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(pt))
+        args = (jnp.asarray(slots), jnp.asarray(pages))
         if tr is not None:
             tr.complete("serve.decode.prepare", "serve", t0, step=step)
         attempt = 0
@@ -620,7 +633,7 @@ class InferenceEngine:
             tr.complete("serve.decode", "serve", t_decode, step=step,
                         inflight=len(live), tokens_live=tokens_live,
                         pages_held=self.cache.held_pages,
-                        pages_gathered=b * mpp,
+                        pages_gathered=-(-held // self._chunk) * self._chunk,
                         pool_pages=self.cache.num_pages)
 
     def _decode_fault(self, e: BaseException) -> None:
@@ -686,12 +699,29 @@ def oracle_logits(engine: InferenceEngine, seq) -> np.ndarray:
     return np.asarray(logits)
 
 
+def oracle_logit_gap(engine: InferenceEngine, prompt, tokens):
+    """How far served ``tokens`` lie from the UNPAGED oracle's choices:
+    (the widest gap by which a token's oracle logit lies under the oracle's
+    best, the largest logit magnitude met), the oracle fed the prompt and
+    the served tokens before each. A gap of 0 = token for token the oracle's
+    own greedy chain; the magnitude is what a relative tolerance scales by."""
+    prompt = np.asarray(prompt, np.int32).reshape(-1)
+    served = np.asarray(tokens, np.int32).reshape(-1)
+    gap = top = 0.0
+    for k, tok in enumerate(served):
+        logits = oracle_logits(engine, np.concatenate([prompt, served[:k]]))
+        gap = max(gap, float(logits.max() - logits[tok]))
+        top = max(top, float(np.abs(logits).max()))
+    return gap, top
+
+
 def oracle_generate(engine: InferenceEngine, prompt, max_new_tokens: int,
                     eos_token: Optional[int] = None) -> List[int]:
     """The UNPAGED oracle: greedy decode by re-running the engine's own
     compiled prefill over the growing full sequence each step
-    (``oracle_logits``). The bit-exactness tests pin the paged engine against
-    this (identical program structure, identical reduction extents)."""
+    (``oracle_logits``). The tests and chip_smoke.py hold the paged engine's
+    tokens against this oracle's logits within a tolerance: the decode step
+    sums over the live pages, the prefill over its padded context."""
     seq = list(np.asarray(prompt, np.int32).reshape(-1))
     out: List[int] = []
     for _ in range(max_new_tokens):

@@ -8,18 +8,20 @@ KV side keeps the same :class:`~mlsl_tpu.data.cache.AdmissionBudget`
 admit-or-reject contract underneath, and adds what serving needs on top:
 
 - **fixed-size HBM pages** — the pool is ``(n_blocks, num_pages+1, page,
-  heads, head_dim)`` per K and V, owned by the engine as donated device
+  heads * head_dim)`` per K and V, owned by the engine as donated device
   arrays; this class is the host-side allocator (free-list + page tables)
   and never touches device memory itself. Page granularity kills the
   fragmentation that per-sequence max-length slabs would cause: a
   16-token-context sequence holds 1 page, not seq_len/page of them.
-- **per-sequence page tables** — ``table_padded()`` hands the engine a
-  fixed-width int32 gather index (padded with page 0) so the compiled
-  decode program has a static shape regardless of how many pages a
-  sequence actually holds.
+- **per-sequence page tables** — ``table_padded()`` hands the engine's KV
+  write a fixed-width int32 scatter index (padded with page 0), and
+  ``live_list()`` hands the decode program one flat list of the pages the
+  in-flight sequences hold, each with its owner slot and the token index
+  of its first row, padded to a fixed capacity: the compiled programs have
+  static shapes, and the decode step's work follows the list's live length.
 - **page 0 is reserved garbage** — never allocated, never counted against
-  the budget. Padded prefill scatter-writes and inactive batch slots land
-  there; the decode mask guarantees it is never read into attention.
+  the budget, never listed. Padded prefill scatter-writes and inactive
+  batch slots land there; nothing reads it into attention.
 - **eviction** — ``release(evict=True)`` is the preemption path: the engine
   evicts the youngest active sequence when a decode step cannot extend,
   re-queues it for a resume-prefill, and the freed pages go back on the
@@ -33,7 +35,10 @@ truth for how many pages a given ``MLSL_SERVE_KV_CACHE_MB`` buys.
 
 from __future__ import annotations
 
-from typing import Dict, List
+import itertools
+from typing import Dict, List, Sequence, Tuple
+
+import numpy as np
 
 from mlsl_tpu.data.cache import AdmissionBudget
 from mlsl_tpu.log import MLSLError, mlsl_assert
@@ -47,8 +52,7 @@ class PagedKVCache:
     n_blocks/n_heads/head_dim); ``page_elems`` tokens per page
     (MLSL_SERVE_KV_PAGE_ELEMS); ``budget_mb`` the HBM budget
     (MLSL_SERVE_KV_CACHE_MB); ``max_len`` the context ceiling (defaults to
-    cfg.seq_len and must stay there for the bit-exactness contract — see
-    models/transformer.py decode section)."""
+    cfg.seq_len, the one padded shape the engine's prefill compiles)."""
 
     def __init__(self, cfg, *, page_elems: int, budget_mb: float,
                  max_len: int = 0, quant: bool = False):
@@ -59,7 +63,7 @@ class PagedKVCache:
             self.ctx_len % self.page_elems == 0,
             f"context length {self.ctx_len} must be a multiple of "
             f"MLSL_SERVE_KV_PAGE_ELEMS={self.page_elems} (the compiled "
-            "decode program gathers whole pages)",
+            "KV write scatters the padded prefill as whole pages)",
         )
         self.max_pages_per_seq = self.ctx_len // self.page_elems
         # bytes for ONE page across all layers, K and V: int8 stores
@@ -159,10 +163,35 @@ class PagedKVCache:
                            pages=len(table))
 
     def table_padded(self, seq_id: int) -> List[int]:
-        """Fixed-width page table for the compiled decode gather: the live
+        """Fixed-width page table for the compiled KV write: the live
         pages, padded to ``max_pages_per_seq`` with the garbage page 0."""
         table = self._tables[seq_id]
         return table + [0] * (self.max_pages_per_seq - len(table))
+
+    def page_of(self, seq_id: int, position: int) -> int:
+        """The pool page that holds token ``position`` of a sequence."""
+        return self._tables[seq_id][position // self.page_elems]
+
+    def live_list(self, seq_ids: Sequence[int],
+                  capacity: int) -> Tuple[np.ndarray, int]:
+        """The flat list the decode program walks: every page the sequences
+        ``seq_ids`` hold, in that order and each table in its own, as a
+        (3, capacity) int32 array of rows (pool page, owner = the sequence's
+        index in ``seq_ids``, token index of the page's first row), padded
+        with (0, -1, 0); and the number of live entries."""
+        tables = [self._tables[s] for s in seq_ids]
+        lens = np.fromiter(map(len, tables), np.int64, len(tables))
+        n = int(lens.sum())
+        mlsl_assert(n <= capacity, "%d live pages exceed the list's %d",
+                    n, capacity)
+        out = np.zeros((3, capacity), np.int32)
+        out[0, :n] = np.fromiter(
+            itertools.chain.from_iterable(tables), np.int32, n)
+        out[1, :n] = np.repeat(np.arange(len(tables)), lens)
+        out[1, n:] = -1
+        first = np.repeat(np.cumsum(lens) - lens, lens)
+        out[2, :n] = (np.arange(n) - first) * self.page_elems
+        return out, n
 
     # -- invariants (tests) ------------------------------------------------
 

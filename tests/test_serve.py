@@ -1,6 +1,8 @@
-"""Serving engine (mlsl_tpu/serve): paged-KV bit-exactness against the
-unpaged full-context oracle, free-list/eviction invariants under churn, the
-int8 paged codec vs the dequantize oracle, SLA ladder
+"""Serving engine (mlsl_tpu/serve): the paged decode's tokens held against
+the unpaged full-context oracle's logits, the ragged paged attention against
+the dense masked reference, the live-page list against the allocator's
+tables, free-list/eviction invariants under churn, the int8 paged codec vs
+the dequantize oracle, SLA ladder
 escalation/recovery/admission-rejection, chaos soak (degraded, never down),
 knob validation, the serving metric families on the telemetry plane, and
 the serving_bench --smoke wiring (the ``bench_smoke`` marker)."""
@@ -18,7 +20,8 @@ from mlsl_tpu import chaos, serve, supervisor
 from mlsl_tpu.core import stats
 from mlsl_tpu.log import MLSLError
 from mlsl_tpu.models.transformer import TransformerConfig, kv_block_quant
-from mlsl_tpu.serve.engine import oracle_generate
+from mlsl_tpu.ops import paged_attention
+from mlsl_tpu.serve.engine import oracle_generate, oracle_logit_gap
 from mlsl_tpu.serve.kv_cache import PagedKVCache
 
 
@@ -44,33 +47,141 @@ def _prompts(cfg, n, rng_seed=0):
 
 # -- paged decode correctness -------------------------------------------------
 
+#: the benchmark's contract (``served_logit_gap_max``) at the CPU's precision:
+#: the decode step sums over the live pages and the oracle's prefill over its
+#: padded context, so float32 logits agree to rounding (seen: under 1e-5)
+LOGIT_TOL = 1e-4
 
-def test_paged_decode_bitexact_vs_unpaged_oracle(env):
-    """The tentpole acceptance pin: continuous-batched paged decode must
-    reproduce the unpaged full-context forward bit for bit (f32 attention
-    over f32-at-rest KV, equal reduction extents in both programs)."""
+
+def served_logit_gap(eng, prompt, tokens):
+    return oracle_logit_gap(eng, prompt, tokens)[0]
+
+
+def test_paged_decode_logits_within_tolerance_of_unpaged_oracle(env):
+    """The tentpole acceptance pin: every token the continuous-batched paged
+    decode serves has an oracle logit within LOGIT_TOL of the oracle's best
+    (f32 attention over f32-at-rest KV in both programs)."""
     cfg = _cfg()
     eng = serve.InferenceEngine(env, cfg, tp=1, seed=0)
     reqs = [eng.submit(p, 6) for p in _prompts(cfg, 5)]
     eng.run()
     for req, p in zip(reqs, _prompts(cfg, 5)):
-        assert req.result(timeout=5) == oracle_generate(eng, p, 6)
+        got = req.result(timeout=5)
+        assert len(got) == 6
+        assert served_logit_gap(eng, p, got) <= LOGIT_TOL
     assert all(r.state == "done" for r in reqs)
     eng.cache.check()
     assert len(eng.cache) == 0          # every sequence released its pages
     eng.close()
 
 
-def test_paged_decode_bitexact_tp2(env):
+def test_paged_decode_logits_within_tolerance_tp2(env):
     """Same pin with the decode allreduces live on the model axis (routed
-    through the selection table via algos.inline_allreduce)."""
+    through the selection table via algos.inline_allreduce) and the pools'
+    heads sharded over it."""
     cfg = _cfg(n_heads=8)
     eng = serve.InferenceEngine(env, cfg, tp=2, seed=0)
     p = np.arange(1, 11, dtype=np.int32)
     req = eng.submit(p, 5)
     eng.run()
-    assert req.result(timeout=5) == oracle_generate(eng, p, 5)
+    got = req.result(timeout=5)
+    assert len(got) == 5 and served_logit_gap(eng, p, got) <= LOGIT_TOL
     eng.close()
+
+
+def _paged_case(case, rng):
+    """(positions a slot, -1 = inactive; the list's capacity or None)."""
+    fixed = {
+        "one_live_sequence": ([-1, 37, -1, -1], None),
+        "position_0": ([0, 20, -1, 5], None),
+        # rows 0 and 15 of a last page, and a page begun this step
+        "crossing_page_boundary": ([32, 47, 16, -1], None),
+        "at_ctx_len_minus_1": ([127, 3, -1, 64], None),
+        "list_fills_capacity": ([127, 127, 63, 63], 24),  # 8 + 8 + 4 + 4
+    }
+    return fixed.get(
+        case, ([int(n) for n in rng.integers(0, 128, size=4)], None))
+
+
+@pytest.mark.parametrize("case", [
+    "one_live_sequence", "full_batch", "position_0", "crossing_page_boundary",
+    "at_ctx_len_minus_1", "list_fills_capacity", "int8_pool", "tp2"])
+def test_ragged_paged_attention_matches_dense_masked_reference(env, case):
+    """ops.paged_attention.ragged_paged_attention, fed by the allocator's
+    live list, against the dense form it replaced: every slot's padded page
+    table gathered whole and masked past the slot's position."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from mlsl_tpu.comm.collectives import smap
+    from mlsl_tpu.comm.mesh import MODEL_AXIS
+
+    rng = np.random.default_rng(7)
+    positions, cap = _paged_case(case, rng)
+    hl, dh, page, chunk, layers, layer = 4, 8, 16, 4, 3, 1
+    quant, tp = case == "int8_pool", 2 if case == "tp2" else 1
+    cfg = _cfg(n_heads=hl, head_dim=dh, n_blocks=layers, seq_len=128)
+    cache = PagedKVCache(cfg, page_elems=page, budget_mb=1, max_len=128,
+                         quant=quant)
+    live = [b for b, n in enumerate(positions) if n >= 0]
+    for b in rng.permutation(live):          # scattered, unordered pages
+        assert cache.admit(int(b), 1)
+    for b in rng.permutation(live):
+        assert cache.extend(int(b), positions[b] + 1)
+    lst, n = cache.live_list(live, cap or -(-cache.num_pages // chunk) * chunk)
+    assert cap is None or n == cap
+    slot_of = np.asarray(live)[np.maximum(lst[1], 0)]    # owner -> batch slot
+    lst[1] = np.where(lst[1] >= 0, slot_of, -1)
+    npg = cache.num_pages + 1
+    k = rng.normal(size=(layers, npg, page, hl, dh)).astype(np.float32)
+    v = rng.normal(size=(layers, npg, page, hl, dh)).astype(np.float32)
+    q = rng.normal(size=(len(positions), hl, dh)).astype(np.float32)
+    pos = np.maximum(np.asarray(positions, np.int32), 0)
+    scales = ()
+    if quant:
+        (kq, ks), (vq, vs) = kv_block_quant(k), kv_block_quant(v)
+        k, v = (np.asarray(x, np.float32) * np.asarray(sc)[..., None]
+                for x, sc in ((kq, ks), (vq, vs)))    # what the pool holds
+        scales = tuple(jnp.asarray(sc).swapaxes(2, 3).reshape(layers, npg, -1)
+                       for sc in (ks, vs))
+        kp, vp = (jnp.asarray(x).reshape(layers, npg, page, -1)
+                  for x in (kq, vq))
+    else:
+        kp, vp = (jnp.asarray(x).reshape(layers, npg, page, -1)
+                  for x in (k, v))
+
+    def attend(q, kp, vp, *scales):
+        pages, owners, bases = jnp.asarray(lst)
+        valid, mine = paged_attention.live_masks(
+            owners, bases, jnp.asarray(pos), page)
+        return paged_attention.ragged_paged_attention(
+            q, kp, vp, layer, pages, owners, valid, mine, *scales,
+            chunk=chunk)
+
+    if tp == 1:
+        got = jax.jit(attend)(jnp.asarray(q), kp, vp, *scales)
+    else:
+        mesh = env.create_distribution(1, tp).topology.mesh
+        pool = P(None, None, None, MODEL_AXIS)
+        got = jax.jit(smap(
+            attend, mesh, in_specs=(P(None, MODEL_AXIS, None), pool, pool),
+            out_specs=P(None, MODEL_AXIS, None), check=False,
+        ))(jnp.asarray(q), kp, vp)
+    got = np.asarray(got)
+
+    for b, n_pos in enumerate(positions):
+        if n_pos < 0:                        # owns no entry: reads zeros
+            assert not got[b].any()
+            continue
+        table = cache.table_padded(b)
+        ks_, vs_ = (x[layer][table].reshape(-1, hl, dh).astype(np.float64)
+                    for x in (k, v))         # (M * page, Hl, Dh), dense
+        s = np.einsum("hx,thx->ht", q[b].astype(np.float64) / np.sqrt(dh), ks_)
+        s[:, np.arange(s.shape[1]) > n_pos] = -np.inf
+        w = np.exp(s - s.max(axis=1, keepdims=True))
+        want = np.einsum("ht,thx->hx", w / w.sum(axis=1, keepdims=True), vs_)
+        np.testing.assert_allclose(got[b], want, rtol=2e-5, atol=2e-5)
 
 
 def test_kv_block_quant_matches_dequantize_oracle():
@@ -142,6 +253,55 @@ def test_kv_cache_free_list_invariants_under_churn():
     assert cache.budget.bytes == 0
 
 
+def test_live_list_matches_tables_under_churn():
+    """The flat list the decode program walks is exactly the allocator's
+    tables of the listed sequences, in the order given: pages, owners,
+    token bases, count; padded with (0, -1, 0); the garbage page never
+    listed. Under the churn above, eviction and resume included."""
+    cfg = _cfg()
+    cache = PagedKVCache(cfg, page_elems=16, budget_mb=1, max_len=64)
+    cap = cache.num_pages + 3
+    rng = np.random.default_rng(2)
+    live, next_id = {}, 0
+    for _ in range(200):
+        op = rng.integers(0, 4)
+        if op == 0 or not live:
+            n = int(rng.integers(1, 65))
+            if cache.admit(next_id, n):
+                live[next_id] = n
+            next_id += 1
+        elif op == 1:
+            sid = int(rng.choice(list(live)))
+            n = min(live[sid] + int(rng.integers(1, 20)), cache.ctx_len)
+            if cache.extend(sid, n):
+                live[sid] = n
+        elif op == 2:
+            sid = int(rng.choice(list(live)))
+            cache.release(sid, evict=bool(rng.integers(0, 2)))
+            del live[sid]
+        else:       # evicted, then resumed under a new id with its prefix
+            sid = int(rng.choice(list(live)))
+            cache.release(sid, evict=True)
+            n = live.pop(sid)
+            if cache.admit(next_id, min(n + 1, cache.ctx_len)):
+                live[next_id] = min(n + 1, cache.ctx_len)
+            next_id += 1
+        order = [int(s) for s in rng.permutation(list(live))]
+        lst, n = cache.live_list(order, cap)
+        assert lst.shape == (3, cap) and lst.dtype == np.int32
+        want = [(pg, slot, i * 16) for slot, sid in enumerate(order)
+                for i, pg in enumerate(cache._tables[sid])]
+        assert n == len(want) == cache.held_pages
+        assert [tuple(e) for e in lst[:, :n].T] == want
+        assert 0 not in lst[0, :n]
+        assert (lst[:, n:] == np.array([[0], [-1], [0]])).all()
+        for slot, sid in enumerate(order):   # a slot's entries cover its tokens
+            assert (lst[1, :n] == slot).sum() == cache.pages_for(live[sid])
+            assert cache.page_of(sid, live[sid] - 1) == cache._tables[sid][-1]
+    with pytest.raises(MLSLError):
+        cache.live_list(list(live), max(cache.held_pages - 1, 0))
+
+
 def test_kv_cache_rejects_and_budget_floor():
     cfg = _cfg()
     # budget below one full-context sequence fails loudly at init
@@ -161,10 +321,11 @@ def test_kv_cache_rejects_and_budget_floor():
     cache.check()
 
 
-def test_engine_eviction_preempts_youngest_and_resumes(env):
+def test_engine_eviction_preempts_youngest_and_resumes_within_tolerance(env):
     """Pool exhaustion mid-decode evicts the YOUNGEST sequence (pages
     freed, counted, kv.evict instant), requeues it with its generated
-    prefix, and the resumed output is still bit-exact vs the oracle."""
+    prefix, and every token of the resumed output still lies within
+    LOGIT_TOL of the oracle's best."""
     cfg = _cfg()
     eng = serve.InferenceEngine(env, cfg, tp=1, seed=0, max_batch=2)
     # shrink the pool to 5 pages (8 KiB each; one full sequence + 1): two
@@ -177,8 +338,9 @@ def test_engine_eviction_preempts_youngest_and_resumes(env):
     r1, r2 = eng.submit(p1, 8), eng.submit(p2, 8)
     eng.run()
     assert stats.SERVE_COUNTERS["kv_evictions"] >= 1
-    assert r1.result(timeout=5) == oracle_generate(eng, p1, 8)
-    assert r2.result(timeout=5) == oracle_generate(eng, p2, 8)
+    for r, p in ((r1, p1), (r2, p2)):
+        got = r.result(timeout=5)
+        assert len(got) == 8 and served_logit_gap(eng, p, got) <= LOGIT_TOL
     eng.cache.check()
     eng.close()
 
@@ -261,14 +423,15 @@ def test_chaos_admit_fault_fails_one_request_closed(env):
     eng.close()
 
 
-def test_chaos_decode_transient_retries_in_place(env):
+def test_chaos_decode_transient_retries_in_place_within_tolerance(env):
     cfg = _cfg()
     eng = serve.InferenceEngine(env, cfg, tp=1, seed=0)
     chaos.plan("serve.decode", "error", exc=OSError, times=2)
     p = np.arange(1, 9, dtype=np.int32)
     req = eng.submit(p, 4)
     eng.run()
-    assert req.result(timeout=5) == oracle_generate(eng, p, 4)
+    got = req.result(timeout=5)
+    assert len(got) == 4 and served_logit_gap(eng, p, got) <= LOGIT_TOL
     assert stats.SERVE_COUNTERS["retries"] >= 1
     assert serve.status()["state"] == "healthy"   # retry != shed
     eng.close()
@@ -380,7 +543,7 @@ REQUEST_SCOPED = {"serve.admit", "serve.prefill", "serve.kv_write",
 
 @pytest.mark.parametrize("evict", [False, True],
                          ids=["plain", "evicted_and_resumed"])
-def test_serve_spans_on_timeline(env, evict):
+def test_serve_spans_on_timeline(env, monkeypatch, evict):
     """One span tree a step, in the ring without anybody arming it: names,
     ``step`` on every span and ``req`` on the request-scoped ones, children
     inside their parents, one ``serve.request`` a request, and the parts of
@@ -393,9 +556,10 @@ def test_serve_spans_on_timeline(env, evict):
 
     tr = obs_trace.get_tracer()
     assert tr is not None               # armed by default (MLSL_TRACE unset)
+    monkeypatch.setattr(paged_attention, "PAGES_PER_CHUNK", 2)
     cfg = _cfg()
     if evict:
-        # the pool of test_engine_eviction_preempts_youngest_and_resumes
+        # the pool of test_engine_eviction_preempts_youngest_and_resumes_...
         eng = serve.InferenceEngine(env, cfg, tp=1, seed=0, max_batch=2)
         eng.cache = PagedKVCache(cfg, page_elems=16, budget_mb=0.04,
                                  max_len=64)
@@ -442,7 +606,12 @@ def test_serve_spans_on_timeline(env, evict):
     decodes = [e for e in spans if e[NAME] == "serve.decode"]
     for e in decodes:
         a = e[ARGS]
-        assert a["pages_gathered"] == eng.max_batch * eng.cache.max_pages_per_seq
+        # what the decode program walks: the held pages, rounded up to a
+        # whole number of chunks, not the batch's padded tables
+        assert a["pages_held"] <= a["pages_gathered"] \
+            < a["pages_held"] + eng._chunk
+        assert a["pages_gathered"] % eng._chunk == 0
+        assert a["pages_gathered"] <= eng.max_batch * eng.cache.max_pages_per_seq
         assert a["pool_pages"] == eng.cache.num_pages
         assert 0 < a["pages_held"] <= a["pool_pages"]
         assert a["inflight"] <= a["tokens_live"] <= a["pages_held"] * 16
@@ -491,6 +660,34 @@ def test_serve_spans_on_timeline(env, evict):
     eng.close()
 
 
+def test_decode_walks_chunks_in_step_with_held_pages(env, monkeypatch):
+    """The decode program's work is data: a step with few held pages walks
+    fewer chunks than one with many (``serve.decode``'s ``pages_gathered``,
+    the count ``serve_kv_gather_useful_share`` reads)."""
+    from mlsl_tpu.obs import tracer as obs_trace
+    from mlsl_tpu.obs.tracer import ARGS, NAME
+
+    monkeypatch.setattr(paged_attention, "PAGES_PER_CHUNK", 2)
+    tr = obs_trace.get_tracer()
+    cfg = _cfg()
+    eng = serve.InferenceEngine(env, cfg, tp=1, seed=0)
+
+    def walked(prompts, new):
+        tr.clear()
+        reqs = [eng.submit(p, new) for p in prompts]
+        eng.run()
+        assert all(r.state == "done" for r in reqs)
+        return [(e[ARGS]["pages_held"], e[ARGS]["pages_gathered"])
+                for e in tr.snapshot() if e[NAME] == "serve.decode"]
+
+    few = walked([np.arange(1, 6, dtype=np.int32)], 3)         # 1 page held
+    many = walked([np.arange(1, 41, dtype=np.int32)] * 6, 3)   # 3 pages each
+    assert {g for _, g in few} == {2}
+    assert {g for _, g in many} == {18}
+    assert all(h <= g < h + 2 for h, g in few + many)
+    eng.close()
+
+
 def test_serve_stats_line(env, tmp_path, monkeypatch):
     monkeypatch.setenv("MLSL_STATS_DIR", str(tmp_path))
     cfg = _cfg()
@@ -512,8 +709,9 @@ def test_serve_stats_line(env, tmp_path, monkeypatch):
 @pytest.mark.bench_smoke
 def test_serving_bench_smoke():
     """Tier-1 wiring for benchmarks/serving_bench.py: the smoke rows must
-    parse, the paged engine must be bit-exact vs the unpaged oracle, and
-    the chaos soak must come back degraded-not-down (exit 0 gates all)."""
+    parse, the paged engine's tokens must lie within the row's tolerance of
+    the unpaged oracle's best logits, and the chaos soak must come back
+    degraded-not-down (exit 0 gates all)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env_vars = dict(
         os.environ,
@@ -533,7 +731,8 @@ def test_serving_bench_smoke():
     assert load["completed"] + load["rejected"] == load["requests"]
     assert load["tokens_per_s"] and load["ttft_ms"]["p50"] is not None
     parity = next(r for r in rows if r["metric"] == "serving_bench_parity")
-    assert parity["paged_bitexact_vs_unpaged"] is True
+    assert 0 <= parity["paged_logit_gap_vs_unpaged"] \
+        <= parity["paged_logit_gap_tolerance"]
     chaos_row = next(r for r in rows
                      if r["metric"] == "serving_bench_chaos")
     assert chaos_row["unhandled"] == 0
