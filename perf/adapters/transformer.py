@@ -6,13 +6,20 @@ the benchmark that import the program."""
 import copy
 import functools
 import itertools
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 
-def environment():
+def environment(ring_events=None):
+    """``ring_events``: the serving runner's size for the program's span
+    ring, the operator's ``MLSL_TRACE_CAPACITY``. The ring is sized when the
+    program is imported; a training cell asks for nothing and runs the
+    program's own 65,536."""
+    if ring_events:
+        os.environ.setdefault("MLSL_TRACE_CAPACITY", str(ring_events))
     import mlsl_tpu as mlsl
 
     return mlsl.Environment.get_env().init()

@@ -32,11 +32,19 @@ CAT = "serve"
 STEP, REQUEST, ADMIT = "serve.step", "serve.request", "serve.admit"
 WAIT, FIRST, DECODE = "serve.decode.wait", "serve.first_token", "serve.decode"
 DISPATCH = "serve.decode.dispatch"
+PREPARE, SAMPLE, KV_WRITE = ("serve.decode.prepare", "serve.decode.sample",
+                             "serve.kv_write")
+SUMMARY_SPANS = (STEP, PREPARE, DISPATCH, WAIT, SAMPLE, ADMIT, KV_WRITE)
 ENGINE_STEP = "perf.engine.step"
 NO_SPAN = "(no span)"
 RESIDUAL_LIMIT_NS = 200_000
 SUBMIT_AGREE_NS = 1_000_000
 CLOSE_MS = 1.0
+
+
+def _rank95(sorted_vals):
+    """Nearest-rank 95th percentile of a sorted, non-empty list."""
+    return sorted_vals[max(0, -(-95 * len(sorted_vals) // 100) - 1)]
 
 
 def clock_join(ring_starts, trace_starts):
@@ -54,7 +62,7 @@ def clock_join(ring_starts, trace_starts):
         deltas = [t - r for r, t in zip(ring, there)]
         offset = int(statistics.median(deltas))
         dev = sorted(abs(d - offset) for d in deltas)
-        residual = dev[max(0, -(-95 * n // 100) - 1)]
+        residual = _rank95(dev)
         if best is None or residual < best[1]:
             best = (offset, residual, n)
     return best
@@ -112,7 +120,9 @@ def book(idle, segments):
 class Program:
     """The serving spans of one run's window, on the ring's clock."""
 
-    def __init__(self, events, t_open_ns, seconds, traced=(None, None)):
+    def __init__(self, events, t_open_ns, seconds, traced=(None, None),
+                 capacity=None):
+        self.ring_full = capacity is not None and len(events) >= capacity
         self.t_open = t_open_ns
         self.t_close = t_open_ns + int(seconds * 1e9)
         self.traced = tuple(None if t is None else t_open_ns + int(t * 1e9)
@@ -144,12 +154,13 @@ class Program:
         return [e for _, e in sorted(self.steps.items()) if lo <= e[3] < hi]
 
 
-def from_events(run, armed, events):
+def from_events(run, armed, events, capacity=None):
     if not armed:
         run.notes["program_spans"] = "the program's span ring is not armed"
         return None
     prog = Program(events, int(round((run.t_ready + run.setup_s) * 1e9)),
-                   run.window["seconds"], run.window.get("traced") or (None, None))
+                   run.window["seconds"], run.window.get("traced") or (None, None),
+                   capacity)
     if not prog.steps:
         run.notes["program_spans"] = "the ring holds no serve.step of the window"
         return None
@@ -162,7 +173,8 @@ def load(run):
     if not hasattr(run, "_program_spans"):
         adapter = manifest.load_module(
             manifest.PERF / "adapters" / "program_trace.py")
-        run._program_spans = from_events(run, *adapter.snapshot())
+        run._program_spans = from_events(run, *adapter.snapshot(),
+                                         capacity=adapter.capacity())
     return run._program_spans
 
 
@@ -263,6 +275,69 @@ def decode_counts(run):
     return [e[6] for e in prog.named(DECODE)
             if e[6]["step"] in in_window and e[6].get("pages_gathered")
             and e[6].get("pool_pages")]
+
+
+# -- what the ring saw of the window, traced or not ---------------------------
+
+def _ms_stats(ns):
+    """-> {"n", "median_ms", "p95_ms"} (nearest rank) of durations in ns."""
+    vals = sorted(ns)
+    if not vals:
+        return {"n": 0}
+    return {"n": len(vals), "median_ms": statistics.median(vals) / 1e6,
+            "p95_ms": _rank95(vals) / 1e6}
+
+
+def ring_summary(run):
+    """What the program's ring saw of the window's steps, for the step record
+    of every serving run, so that a run that reads slow can be opened without
+    a trace: median and 95th percentile of ``SUMMARY_SPANS``; the steps
+    counted, and ``serve.step`` timed, by the admissions they made; the
+    medians again over the window's first and second half (the host's slow
+    speed lasts tens of seconds and shows as a step between the halves of
+    ``prepare`` and ``dispatch``, which cost the same at any load; those of
+    ``serve.step`` and ``wait`` also follow the load, so they compare only
+    where the traffic's arrival order puts the same load into both halves);
+    and where the 95th percentile of the gaps between tokens stands: every
+    sequence a step decodes ends one gap, of length nought for a sequence
+    the step has just admitted, so ``gap_share_admitting`` is the share of
+    the other gaps that end in a step which admitted. ``ring_full`` says that the
+    ring had wrapped and the window's first steps may be lost. ``None``
+    where the program has no such spans."""
+    prog = load(run)
+    if prog is None:
+        return None
+    steps = prog.window_steps()
+    mid = (prog.t_open + prog.t_close) // 2
+    spans = {name: [] for name in SUMMARY_SPANS}
+    halves = [{name: [] for name in SUMMARY_SPANS} for _ in range(2)]
+    by_admissions, gaps, gaps_admitting = {}, 0, 0
+    for e in steps:
+        n = e[6]["step"]
+        kids = [e] + prog._kids.get(n, [])
+        half = halves[e[3] >= mid]
+        admitted = 0
+        for k in kids:
+            if k[1] in spans:
+                spans[k[1]].append(k[4])
+                half[k[1]].append(k[4])
+            admitted += k[1] == ADMIT and not k[6].get("error")
+        by_admissions.setdefault(admitted, []).append(e[4])
+        decoded = sum(k[6].get("inflight", 0) for k in kids if k[1] == DECODE)
+        gaps += max(0, decoded - admitted)
+        if admitted:
+            gaps_admitting += max(0, decoded - admitted)
+    out = {
+        "steps": len(steps), "ring_full": prog.ring_full,
+        "spans": {name: _ms_stats(v) for name, v in spans.items()},
+        "steps_by_admissions": {str(k): _ms_stats(v)
+                                for k, v in sorted(by_admissions.items())},
+        "halves_median_ms": [
+            {name: statistics.median(v) / 1e6 for name, v in h.items() if v}
+            for h in halves],
+        "gaps": gaps, "gap_share_admitting": gaps_admitting / gaps if gaps else None}
+    run.record.note(ring_summary=out)
+    return out
 
 
 # -- the clock join and the idle table -----------------------------------------

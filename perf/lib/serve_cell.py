@@ -9,9 +9,13 @@ import time
 
 import numpy as np
 
-from perf.lib import compare, manifest, traffic as traffic_lib
+from perf.lib import compare, manifest, program_spans, traffic as traffic_lib
 
 WORST_MS = 1e9      # a failed or refused request counts as the worst
+# the program's span ring, asked to hold a whole window (15 events a step and
+# up to 200 steps a second leave 150,000 in 51 s; the program's own 65,536
+# would lose the window's beginning): the operator's setting, serving only
+RING_EVENTS = 1 << 19
 
 
 def percentile(values, q):
@@ -50,7 +54,7 @@ def run(ctx):
     ref = manifest.reference(config)
     adapter = manifest.adapter(config)
     ctx.phase("import")
-    env = adapter.environment()
+    env = adapter.environment(ring_events=RING_EVENTS)
     vocab = config["vocab_size"]
     ctx.phase("environment")
 
@@ -84,12 +88,16 @@ def run(ctx):
         while done < n:
             now = time.perf_counter() - t_open
             if trace_from is not None and traced[0] is None and now >= trace_from:
-                ctx.tracer.start()
+                ctx.tracer.start(python_tracer=False)
                 traced[0] = time.perf_counter() - t_open
                 now = traced[0]
             if ctx.tracer is not None and ctx.tracer.on and now >= seconds:
+                # stamped before the stop, which takes as long as the events
+                # it gathers (over a minute here): the traced window's
+                # tokens are counted over the time they were traced in
+                traced[1] = now
                 ctx.tracer.stop()
-                traced[1] = now = time.perf_counter() - t_open
+                now = time.perf_counter() - t_open
             while nxt < n and reqs[nxt]["due"] <= now:
                 r = reqs[nxt]
                 try:
@@ -125,8 +133,8 @@ def run(ctx):
             record.add("engine_step", t0, t1, inflight, len(live), ctx_sum, got)
         t_end = time.perf_counter() - t_open
         if ctx.tracer is not None and ctx.tracer.on:
-            ctx.tracer.stop()
             traced[1] = time.perf_counter() - t_open
+            ctx.tracer.stop()
     finally:
         gc.enable()
     for i in range(n):
@@ -188,3 +196,5 @@ def run(ctx):
     record.note(reference_s=time.perf_counter() - t, checked_requests=sample,
                 checked_tokens=int(sum(len(g) for g in gaps_ref)))
     ctx.checks = compare.serving(gaps_ref, ctx.limits)
+    # traced or not: what the program's ring saw, into the step record
+    program_spans.ring_summary(ctx)

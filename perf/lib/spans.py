@@ -28,10 +28,19 @@ class Tracer:
         self.on = False
         self._window = None
 
-    def start(self):
+    def start(self, python_tracer=True):
+        """``python_tracer=False`` leaves the profiler's Python tracer (on by
+        default) off: it stamps every call of the host's loop, which slows a
+        loop of short steps and lengthens the stop. The readers take device
+        operations and the ``perf.*`` annotations and do not need it."""
         import jax
 
-        jax.profiler.start_trace(self.dir)
+        if python_tracer:
+            jax.profiler.start_trace(self.dir)
+        else:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.dir, profiler_options=options)
         self.on = True
         self._window = jax.profiler.TraceAnnotation("perf.window")
         self._window.__enter__()

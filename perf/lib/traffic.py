@@ -1,9 +1,8 @@
 """One general generator of inputs from a traffic file and a seed.
 
-The seed orders and fills the traffic; it never sizes it. Every seed gives a
-training cell the same shapes and a serving cell the same multiset of
-requests (prompt lengths, answer lengths, gaps between arrivals), in another
-order and with other token ids.
+The seed fills the traffic; it never sizes it. Every seed gives a training
+cell the same shapes and a serving cell the same requests (prompt lengths,
+answer lengths, arrival times) with other token ids.
 """
 
 import math
@@ -77,24 +76,25 @@ def request_count(traffic, seconds):
 
 
 def requests(seed, traffic, seconds, vocab):
-    """-> list of dicts (due, prompt, max_new) sorted by due time. The
-    multiset of (prompt length), of (answer length) and of (gap) is the same
-    for every seed. The gaps stand in one order, drawn once from the traffic
-    file's ``arrival_order_seed`` (bursts where that order puts short gaps
-    together), so every seed offers the same arrival times; the seed permutes
-    the prompt lengths and the answer lengths over them, independently, and
-    draws the token ids."""
+    """-> list of dicts (due, prompt, max_new) sorted by due time. Every seed
+    offers the same requests at the same moments: the gaps, the prompt
+    lengths and the answer lengths each stand in one order, drawn once from
+    the traffic file's ``arrival_order_seed`` (bursts where that order puts
+    short gaps together); the seed draws the token ids (and, in the runner,
+    the weights). Near its knee a server's tails follow which long requests
+    meet in the batch: lengths permuted by the seed moved ``itl_p95_ms`` by
+    9% from seed to seed where two runs of one seed lay 1 to 3% apart
+    (PERF.md section 6, PR 28)."""
     t = traffic
     n = request_count(t, seconds)
     p, a = t["prompt_tokens"], t["answer_tokens"]
     prompts = lognormal_midpoints(n, p["median"], p["sigma"], p["min"], p["max"])
     answers = lognormal_midpoints(n, a["median"], a["sigma"], a["min"], a["max"])
     gaps = exponential_midpoints(n, t["rate_per_s"])
+    gaps, prompts, answers = (
+        [values[i] for i in rng_for(t["arrival_order_seed"], stream).permutation(n)]
+        for values, stream in ((gaps, 5), (prompts, 6), (answers, 7)))
     rng = rng_for(seed, 3)
-    prompts = [prompts[i] for i in rng.permutation(n)]
-    answers = [answers[i] for i in rng.permutation(n)]
-    order = rng_for(t["arrival_order_seed"], 5).permutation(n)
-    gaps = [gaps[i] for i in order]
     limit = t["max_total_tokens"]
     due, out = 0.0, []
     for i in range(n):

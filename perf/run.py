@@ -48,7 +48,9 @@ class Context:
         self.limits = limits["tiny" if args.tiny else "limits"]
         self.record = record_lib.Record(args.workload, args.seed, args.trace)
         self.spans = spans_lib.Spans(bool(args.trace))
-        self.trace_dir = manifest.PERF / "out" / "trace"
+        # one a process: two traced runs side by side (the tests' workers)
+        # would delete each other's profile
+        self.trace_dir = manifest.PERF / "out" / f"trace.{os.getpid()}"
         self.tracer = spans_lib.Tracer(self.trace_dir) if args.trace else None
         self.t_ready = t0        # ready() moves it to when the chip was up
         self.setup_s = None

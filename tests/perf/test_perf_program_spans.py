@@ -163,6 +163,55 @@ def test_the_parts_of_a_first_tokens_time_close_on_the_benchmarks(doc):
     assert _read("serve_idle_attributed_share", run) is None
 
 
+def test_ring_summary_of_the_windows_steps_by_hand(doc):
+    """Steps 1-3 lie in the window (1,000 to 1,400 ms, its middle at 1,200):
+    steps 1 and 2 in the first half, step 3 in the second; 1 and 3 admit."""
+    for e in doc["events"]:
+        if e[1] == "serve.decode" and e[6]["step"] == 3:
+            e[6]["inflight"] = 3
+    run = Run(doc, with_trace=False)
+    got = program_spans.ring_summary(run)
+    assert run.record.meta["ring_summary"] == got
+    assert got["steps"] == 3 and got["ring_full"] is False
+    spans = got["spans"]
+    assert spans["serve.step"] == {"n": 3, "median_ms": 80, "p95_ms": 110}
+    assert spans["serve.decode.prepare"] == {"n": 3, "median_ms": 2, "p95_ms": 2}
+    assert spans["serve.decode.dispatch"] == {"n": 3, "median_ms": 2, "p95_ms": 2}
+    assert spans["serve.decode.wait"] == {"n": 3, "median_ms": 54, "p95_ms": 64}
+    assert spans["serve.decode.sample"] == {"n": 3, "median_ms": 5, "p95_ms": 6}
+    assert spans["serve.admit"] == {"n": 2, "median_ms": 40, "p95_ms": 40}
+    assert spans["serve.kv_write"] == {"n": 2, "median_ms": 5, "p95_ms": 5}
+    assert set(spans) == set(program_spans.SUMMARY_SPANS)
+    # step 2 admits nothing; steps 1 and 3 one each: 80 and 110 ms
+    assert got["steps_by_admissions"] == {
+        "0": {"n": 1, "median_ms": 80, "p95_ms": 80},
+        "1": {"n": 2, "median_ms": 95, "p95_ms": 110}}
+    first, second = got["halves_median_ms"]
+    assert first == {"serve.step": 80, "serve.decode.prepare": 2,
+                     "serve.decode.dispatch": 2, "serve.decode.wait": 46,
+                     "serve.decode.sample": 4.5, "serve.admit": 40,
+                     "serve.kv_write": 5}
+    assert second == {"serve.step": 110, "serve.decode.prepare": 2,
+                      "serve.decode.dispatch": 2, "serve.decode.wait": 54,
+                      "serve.decode.sample": 5, "serve.admit": 40,
+                      "serve.kv_write": 5}
+    # a decoded sequence ends one gap, of length nought where the step has
+    # just admitted it: 1 - 1, 1 and 3 - 1 gaps; step 3's two end in a step
+    # that admitted
+    assert got["gaps"] == 3
+    assert got["gap_share_admitting"] == pytest.approx(2 / 3)
+
+
+def test_ring_summary_says_when_the_ring_had_wrapped(doc):
+    run = Run(doc, with_trace=False)
+    run._program_spans = program_spans.from_events(
+        run, True, doc["events"], capacity=len(doc["events"]))
+    assert program_spans.ring_summary(run)["ring_full"] is True
+    run._program_spans = None
+    assert program_spans.ring_summary(run) is None
+    assert "ring_summary" not in Run(doc).record.meta
+
+
 def test_requests_that_cannot_be_matched_are_not_guessed(doc):
     doc["series"]["refused"] = []
     run = Run(doc, with_trace=False)

@@ -43,6 +43,53 @@ def test_tiny_run_prints_the_contracts_last_line(capsys, workload, series):
     assert record["series"][series] and record["meta"]["correct"] is True
 
 
+def test_every_serving_step_record_keeps_what_the_ring_saw(capsys):
+    """Untraced too: a run that reads slow can be opened afterwards."""
+    seed = 2**31 + 41
+    tiny_run(capsys, "gpt2-medium-serve-chat", seed=seed, seconds=1.5)
+    records = sorted((ROOT / "perf" / "out").glob(
+        f"gpt2-medium-serve-chat.seed{seed}.trace0.*.json"),
+        key=lambda p: p.stat().st_mtime)
+    record = json.loads(records[-1].read_text())
+    ring = record["meta"]["ring_summary"]
+    in_window = [s for s in record["series"]["engine_step"] if s[0] < 1.5]
+    assert ring["steps"] == len(in_window) and ring["ring_full"] is False
+    assert ring["spans"]["serve.step"]["n"] == ring["steps"]
+    assert sum(v["n"] for v in ring["steps_by_admissions"].values()) \
+        == ring["steps"]
+    assert sum(int(k) * v["n"] for k, v in ring["steps_by_admissions"].items()) \
+        == ring["spans"]["serve.admit"]["n"] > 0
+    assert 0 <= ring["gap_share_admitting"] <= 1 and len(
+        ring["halves_median_ms"]) == 2
+
+
+def test_the_serving_pool_is_whole_pages_within_what_its_tables_address():
+    """``kv_cache_mb`` is a whole number of pages, each the float32 keys and
+    values of ``kv_page_tokens`` tokens in every layer, and no more of them
+    than ``max_batch`` sequences of ``max_total_tokens`` could ever hold: what
+    the traffic fills of it is measured (``serve_kv_pool_peak_share``), and
+    ``kv_cache_from`` says which reading sized it."""
+    from perf.lib import manifest
+
+    man = manifest.load()
+    cell = manifest.cell(man, "gpt2-medium-serve-chat")
+    config = manifest.config_file(man, cell["config"])
+    traffic = manifest.traffic_file(cell["traffic"])
+    page_bytes = (config["n_layer"] * 2 * traffic["kv_page_tokens"]
+                  * config["n_head"] * config["head_dim"] * 4)
+    assert page_bytes == 3 << 20
+    assert traffic["max_total_tokens"] == config["n_positions"]
+    addressable = (traffic["max_batch"] * traffic["max_total_tokens"]
+                   // traffic["kv_page_tokens"])
+    assert addressable == 2048
+    pages, rest = divmod(traffic["kv_cache_mb"] << 20, page_bytes)
+    assert rest == 0 and pages == 1024 <= addressable
+    # one request of the longest kind the traffic can send always fits
+    assert pages >= traffic["max_total_tokens"] // traffic["kv_page_tokens"]
+    assert "1,024 pages" in traffic["kv_cache_from"]
+    assert traffic["tiny"]["kv_cache_mb"] == 8      # the dry run's, untouched
+
+
 def test_tiny_traced_run_reports_counters_and_no_device_number(capsys):
     out, last, _ = tiny_run(capsys, "gpt2-medium-serve-chat", seed=5,
                             seconds=1.5, trace=1)

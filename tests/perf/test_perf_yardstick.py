@@ -8,6 +8,7 @@ import pathlib
 import shutil
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -141,8 +142,33 @@ def test_validate_names_the_faults():
 SERVE = manifest.traffic_file("chat-open-loop")
 
 
+def _offered_cv(order, bins=6, seconds=51):
+    """The larger coefficient of variation, over ``bins`` equal parts of the
+    window, of the prompt tokens and of the answer tokens that come due."""
+    reqs = traffic.requests(1, {**SERVE, "arrival_order_seed": order},
+                            seconds, 50257)
+    prompt, answer = np.zeros(bins), np.zeros(bins)
+    for r in reqs:
+        b = min(bins - 1, int(r["due"] / seconds * bins))
+        prompt[b] += len(r["prompt"])
+        answer[b] += r["max_new"]
+    return max(prompt.std() / prompt.mean(), answer.std() / answer.mean())
+
+
+def test_the_arrival_order_is_the_most_even_of_its_candidates():
+    """``arrival_order_from``'s rule: the cell stands at the same share of
+    the knee all through its window, so a slow stretch of the machine weighs
+    the same wherever it falls and the halves of a step record compare."""
+    chosen = SERVE["arrival_order_seed"]
+    cvs = {order: _offered_cv(order) for order in range(1, 33)}
+    assert min(cvs, key=cvs.get) == chosen == 6
+    assert cvs[chosen] < 0.05 < 0.12 < _offered_cv(2024)
+    halves = _offered_cv(chosen, bins=2)
+    assert halves < 0.03
+
+
 @pytest.mark.parametrize("seed", [0, 7, 2**31 + 11])
-def test_every_seed_offers_the_same_multiset_in_another_order(seed):
+def test_every_seed_offers_the_same_requests_with_other_tokens(seed):
     a = traffic.requests(1, SERVE, 30, 50257)
     b = traffic.requests(seed, SERVE, 30, 50257)
     assert len(a) == len(b) == traffic.request_count(SERVE, 30)
@@ -158,8 +184,16 @@ def test_every_seed_offers_the_same_multiset_in_another_order(seed):
     due = [0.0] + [r["due"] for r in a]
     in_order = [y - x for x, y in zip(due, due[1:])]
     assert in_order != sorted(in_order)
+    # so do the lengths, in an order that is not the sorted one and is not
+    # the same for prompts and answers; the seed draws the token ids
+    assert [len(r["prompt"]) for r in a] == [len(r["prompt"]) for r in b]
+    assert [r["max_new"] for r in a] == [r["max_new"] for r in b]
+    lengths = [len(r["prompt"]) for r in a]
+    assert lengths != sorted(lengths)
+    rank = sorted(range(len(a)), key=lambda i: lengths[i])
+    assert [a[i]["max_new"] for i in rank] != sorted(r["max_new"] for r in a)
     if seed != 1:
-        assert [len(r["prompt"]) for r in a] != [len(r["prompt"]) for r in b]
+        assert any((x["prompt"] != y["prompt"]).any() for x, y in zip(a, b))
     p = SERVE["prompt_tokens"]
     assert all(p["min"] <= len(r["prompt"]) <= p["max"] for r in b)
     assert all(len(r["prompt"]) + r["max_new"] <= SERVE["max_total_tokens"]
