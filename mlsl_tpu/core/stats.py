@@ -600,7 +600,8 @@ SERVE_COUNTERS: Dict[str, float] = {
     "rejected": 0,        # 429-style admission rejections (ladder rung 3)
     "completed": 0,       # sequences that finished (eos or max_tokens)
     "failed": 0,          # sequences abandoned by a non-retryable fault
-    "prefills": 0,        # prefill programs launched
+    "prefills": 0,        # prompts prefilled (first tokens produced)
+    "prefill_chunks": 0,  # chunk programs launched (chunked prefill)
     "decode_steps": 0,    # iteration-level decode steps over the batch
     "tokens_out": 0,      # total generated tokens across all sequences
     "retries": 0,         # TRANSIENT decode-step retries (rung 2)

@@ -188,3 +188,102 @@ def moe_ffn_dense(x, wg, w1, w2, ep: int = 1, capacity_factor: float = 1.25,
         outs.append(o)
         auxes.append(a)
     return jnp.concatenate(outs, axis=0), jnp.stack(auxes).mean()
+
+
+# -- dropless experts (serving) ----------------------------------------------
+#
+# The capacity path above builds a dense (T, E, C) dispatch tensor and drops
+# what does not fit: right for training at a handful of experts, gigabytes at
+# 2,048 tokens x 128 experts. The serving path sorts the token-expert pairs by
+# expert and walks the sorted rows a tile at a time, each tile one expert's:
+# no pair is ever dropped, only the experts that got a token are read, and
+# the trip count is data (decode: 16 tokens touch about 80 of 128 experts and
+# the step is bound by the bytes of their weights; prefill: every expert is
+# busy and the step is bound by the products).
+
+
+def init_dropless_params(key, d_model: int, d_expert: int, n_experts: int,
+                         std=0.02, dtype=jnp.float32) -> Dict:
+    """Router over all experts; gate and up projections side by side in one
+    matrix an expert (one product a tile), then down."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    return {
+        "wr": (jax.random.normal(k1, (d_model, n_experts)) * std).astype(dtype),
+        "wgu": (jax.random.normal(k2, (n_experts, d_model, 2 * d_expert))
+                * std).astype(dtype),
+        "wd": (jax.random.normal(k3, (n_experts, d_expert, d_model))
+               * std).astype(dtype),
+    }
+
+
+def route_top_k(y, wr, top_k: int):
+    """Softmax over every expert in float32, then the ``top_k`` largest with
+    their weights renormalised to sum to one (``norm_topk_prob``). ->
+    (experts (T, K) int32, weights (T, K) f32). ``lax.top_k`` is exact and
+    puts the lower index first among equals."""
+    logits = jnp.dot(y.astype(jnp.float32), wr.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    topv, topi = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return topi, topv / jnp.sum(topv, axis=-1, keepdims=True)
+
+
+def expert_tile(n_tokens: int) -> int:
+    """Rows a trip of the walk multiplies: a token meets an expert once, so
+    no expert gets more than ``n_tokens`` rows; 256 keeps a trip's products
+    level with the bytes of the expert's weights (2 x 256 rows x 3 x d x f
+    operations against 6 x d x f bytes)."""
+    return max(8, min(256, -(-n_tokens // 8) * 8))
+
+
+def dropless_experts(y, params: Dict, top_k: int, valid=None,
+                     compute_dtype=jnp.float32):
+    """Gated-SiLU experts, every routed pair computed.
+
+    y: (T, D) f32, the normed residual. params: ``wr`` (D, E), ``wgu``
+    (E, D, 2F) gate then up, ``wd`` (E, F, D). ``valid``: (T,) bool, rows
+    that are tokens (padding of a prefill chunk and idle decode slots are
+    routed nowhere and read zeros). -> (out (T, D) f32 = sum over a token's
+    experts of weight * ((silu(y Wg) * (y Wu)) Wd), experts that got a token,
+    token-expert pairs), the two counts int32 scalars.
+    """
+    cdt = jnp.dtype(compute_dtype)
+    prec = lax.Precision.HIGHEST if cdt == jnp.float32 else None
+    t, d = y.shape
+    n_e, _, f2 = params["wgu"].shape
+    f, k = f2 // 2, top_k
+    tile = expert_tile(t)
+    topi, gates = route_top_k(y, params["wr"], k)
+    if valid is not None:
+        topi = jnp.where(valid[:, None], topi, n_e)     # sorts last, no tile
+    flat_e = topi.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)            # sorted row -> pair
+    counts = jnp.sum(flat_e[:, None] == jnp.arange(n_e)[None, :], axis=0,
+                     dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    tiles_of = (counts + tile - 1) // tile
+    tile_ends = jnp.cumsum(tiles_of)
+    xs = jnp.pad(y.astype(cdt)[order // k], ((0, tile), (0, 0)))
+    gs = jnp.pad(gates.reshape(-1)[order], (0, tile))
+    wgu, wd = params["wgu"], params["wd"]
+
+    def one_tile(i, out):
+        e = jnp.sum(tile_ends <= i)                     # whose tile this is
+        at = starts[e] + (i - (tile_ends[e] - tiles_of[e])) * tile
+        x = lax.dynamic_slice(xs, (at, 0), (tile, d))
+        gu = jnp.dot(x, wgu[e].astype(cdt), precision=prec,
+                     preferred_element_type=jnp.float32)
+        a = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(cdt)
+        o = jnp.dot(a, wd[e].astype(cdt), precision=prec,
+                    preferred_element_type=jnp.float32)
+        o = o * lax.dynamic_slice(gs, (at,), (tile,))[:, None]
+        # the tile's last rows may be the next expert's: leave them as they are
+        keep = (at + jnp.arange(tile) < ends[e])[:, None]
+        old = lax.dynamic_slice(out, (at, 0), (tile, d))
+        return lax.dynamic_update_slice(out, jnp.where(keep, o, old), (at, 0))
+
+    out = lax.fori_loop(0, tile_ends[-1], one_tile,
+                        jnp.zeros((t * k + tile, d), jnp.float32))
+    back = jnp.argsort(order)                           # pair -> sorted row
+    out = jnp.sum(out[back].reshape(t, k, d), axis=1)
+    return out, jnp.sum(counts > 0, dtype=jnp.int32), jnp.sum(counts)

@@ -34,7 +34,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from mlsl_tpu.comm.collectives import _BUF_SPEC
 from mlsl_tpu.comm.mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
 from mlsl_tpu.log import mlsl_assert
-from mlsl_tpu.models.moe import init_moe_params, moe_ffn, mxu_einsum
+from mlsl_tpu.models.moe import (
+    dropless_experts, init_dropless_params, init_moe_params, moe_ffn, mxu_einsum,
+)
 from mlsl_tpu.models.train import (
     _leaf_buf_spec,
     build_owned_increment_fn,
@@ -80,9 +82,55 @@ class TransformerConfig:
     moe_aux_weight: float = 0.01
     capacity_factor: float = 2.0
     sharded_vocab: bool = False  # shard the LM head over 'model'; CE via collectives
+    # -- the block's description. The defaults are GPT-2's block (LayerNorm,
+    # learned positions, as many key-value heads as query heads, GELU MLP or
+    # the capacity experts above); the serving programs (prefill_local,
+    # chunk_local, decode_local) run any instance of it through _block.
+    # Training (forward_local) runs the default instance only.
+    norm: str = "layer"          # 'layer' (scale and bias) | 'rms' (scale)
+    norm_eps: float = 1e-5
+    positions: str = "learned"   # 'learned' table | 'rope' (rotate-half over
+    # the whole head; the cache then holds rotated keys)
+    rope_theta: float = 10000.0
+    n_kv_heads: int = 0          # 0 = n_heads; else grouped-query attention
+    qk_norm: bool = False        # RMS norm over head_dim on q and k, learned
+    mlp: str = "gelu"            # 'gelu' | 'experts': gated-SiLU experts,
+    # dropless (moe.dropless_experts), n_experts of expert_width, moe_top_k a
+    # token renormalised; no biases
+    expert_width: int = 0
+    # learned selection: index_heads heads of index_dim over one shared index
+    # key a token; a query attends to the index_topk highest-scored positions
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    weights_dtype: str = "float32"   # matrices at rest (norm scales stay f32)
+    kv_dtype: str = "float32"        # K, V and index keys at rest
+    residual_dtype: str = ""         # '' = dtype; 'float32' keeps the stream
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def index_row(self) -> int:
+        """Lanes an index key takes at rest: ``index_dim`` rounded up to the
+        device's 128. A 64-wide row is padded to 128 lanes by the device
+        anyway, and the compiler then lays the whole pool out anew around
+        every gather (12 ms of a 32 ms decode step and 25 ms of a chunk:
+        PERF.md section 6, PR 29); the zeros above ``index_dim`` are stored,
+        counted in the pool's page bytes, and add nothing to a score."""
+        return -(-self.index_dim // 128) * 128 if self.index_topk else 0
+
+    @property
+    def gpt2_block(self) -> bool:
+        return (self.norm == "layer" and self.positions == "learned"
+                and self.mlp == "gelu" and not self.n_kv_heads
+                and not self.qk_norm and not self.index_topk)
 
 
 def init_params(key, cfg: TransformerConfig) -> Dict:
+    if not cfg.gpt2_block:
+        return _init_described(key, cfg)
     ks = iter(jax.random.split(key, 8 + 8 * cfg.n_blocks))
     dm, h, dh = cfg.d_model, cfg.n_heads, cfg.head_dim
     f = cfg.mlp_ratio * dm
@@ -121,8 +169,56 @@ def init_params(key, cfg: TransformerConfig) -> Dict:
     return params
 
 
+def _init_described(key, cfg: TransformerConfig) -> Dict:
+    """Weights of a block that is not GPT-2's (RMS norm, rotary positions,
+    grouped-query heads, an indexer, dropless experts): matrices normal 0.02
+    in ``weights_dtype``, scales 1 and biases 0 in float32. No bias on any
+    projection, no position table."""
+    mlsl_assert(cfg.norm == "rms" and cfg.positions == "rope"
+                and cfg.mlp == "experts" and cfg.n_experts > 0,
+                "the described block is RMS norm, rotary, dropless experts")
+    wdt = jnp.dtype(cfg.weights_dtype)
+    dm, h, g, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    std = 0.02
+
+    def normal(k, shape):
+        return (jax.random.normal(k, shape) * std).astype(wdt)
+
+    ks = iter(jax.random.split(key, 2 + 8 * cfg.n_blocks))
+    params = {
+        "embed": {"tok": normal(next(ks), (cfg.vocab, dm))},
+        "final": {"ln_scale": jnp.ones((dm,)),
+                  "head": normal(next(ks), (dm, cfg.vocab))},
+    }
+    for i in range(cfg.n_blocks):
+        params[f"blk{i}.ln"] = {"ln1_scale": jnp.ones((dm,)),
+                                "ln2_scale": jnp.ones((dm,))}
+        attn = {"wq": normal(next(ks), (dm, h, dh)),
+                "wkv": normal(next(ks), (dm, 2, g, dh)),
+                "wo": normal(next(ks), (h, dh, dm))}
+        if cfg.qk_norm:
+            attn["q_norm"] = jnp.ones((dh,))
+            attn["k_norm"] = jnp.ones((dh,))
+        if cfg.index_topk:
+            ji, di = cfg.index_heads, cfg.index_dim
+            attn["wiq"] = normal(next(ks), (dm, ji, di))
+            attn["wik"] = normal(next(ks), (dm, di))
+            attn["wiw"] = normal(next(ks), (dm, ji))
+            attn["ik_scale"] = jnp.ones((di,))
+            attn["ik_bias"] = jnp.zeros((di,))
+        params[f"blk{i}.attn"] = attn
+        params[f"blk{i}.mlp"] = init_dropless_params(
+            next(ks), dm, cfg.expert_width, cfg.n_experts, std, wdt)
+    return params
+
+
 def param_specs(cfg: TransformerConfig) -> Dict:
     """PartitionSpec pytree: which leaves are TP-sharded over 'model'."""
+    if not cfg.gpt2_block:
+        # served on one chip of a pipeline stage: nothing is sharded yet
+        return jax.tree.map(
+            lambda _: P(), jax.eval_shape(
+                lambda: _init_described(jax.random.PRNGKey(0), cfg)))
     specs = {
         "embed": {"tok": P(), "pos": P()},
         "final": {
@@ -190,6 +286,9 @@ def forward_local(params, tokens, cfg: TransformerConfig, sp: int, tp: int,
     dispatch/combine exchanges route through the collective engine's selection
     table (comm/algos.inline_alltoall) instead of pinning the lax baseline.
     """
+    mlsl_assert(cfg.gpt2_block, "training runs the learned-position "
+                "LayerNorm block (ROADMAP D4: forward_local has a body of its "
+                "own; the serving programs share _block)")
     emb = params["embed"]
     cdt = jnp.dtype(cfg.dtype)
     aux_total = jnp.float32(0.0)
@@ -374,6 +473,131 @@ def kv_block_quant(x):
     return q, scale
 
 
+# -- the block, as the serving programs run it --------------------------------
+#
+# One description (TransformerConfig: norm, positions, head counts, MLP kind,
+# selection) and one body: rows of (T, d_model) in, the attention handed in by
+# the program that calls it (a whole padded prompt, a chunk of a prompt over
+# the paged cache, one token a slot over the paged cache). GPT-2's block and
+# the RMS-norm / rotary / grouped-query / indexer / dropless-experts block
+# are instances.
+
+
+def _norm(x, p, name: str, cfg: TransformerConfig):
+    """Float32 norm of the rows of x by the scale (and bias) ``name`` of p."""
+    x = x.astype(jnp.float32)
+    if cfg.norm == "rms":
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * lax.rsqrt(ms + cfg.norm_eps) * p[name + "_scale"]
+    return _ln(x, p[name + "_scale"], p[name + "_bias"], cfg.norm_eps)
+
+
+def _rope(x, positions, theta: float):
+    """Rotate-half rotary embedding over the whole trailing axis. x: (T, ...,
+    D) f32 with its rows at ``positions`` (T,)."""
+    half = x.shape[-1] // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0
+                           / x.shape[-1]))
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def _project(a, ap, cfg: TransformerConfig, positions):
+    """Queries, keys and values of the normed rows a (T, d_model), float32:
+    q (T, Hl, Dh), k and v (T, Gl, Dh), keys rotated where positions are
+    rotary; and the indexer's (queries (T, J, Dr), key (T, Dr), head weights
+    (T, J)) where the configuration has one, else None: Dr = ``index_row``
+    lanes, zeros above ``index_dim``, as the index keys' pool stores them."""
+    cdt = a.dtype
+    if "wqkv" in ap:
+        qkv = jnp.einsum("td,dchx->cthx", a, ap["wqkv"].astype(cdt))
+        q, k, v = (qkv[c].astype(jnp.float32) for c in range(3))
+    else:
+        q = mxu_einsum("td,dhx->thx", a, ap["wq"].astype(cdt))
+        kv = mxu_einsum("td,dchx->cthx", a, ap["wkv"].astype(cdt))
+        k, v = kv[0], kv[1]
+    if cfg.qk_norm:
+        def head_rms(x, scale):
+            ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+            return x * lax.rsqrt(ms + cfg.norm_eps) * scale
+
+        q, k = head_rms(q, ap["q_norm"]), head_rms(k, ap["k_norm"])
+    if cfg.positions == "rope":
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    index = None
+    if cfg.index_topk:
+        qi = mxu_einsum("td,djx->tjx", a, ap["wiq"].astype(cdt))
+        ki = _ln(mxu_einsum("td,dx->tx", a, ap["wik"].astype(cdt)),
+                 ap["ik_scale"], ap["ik_bias"], cfg.norm_eps)
+        wi = mxu_einsum("td,dj->tj", a, ap["wiw"].astype(cdt))
+        lanes = [(0, 0), (0, cfg.index_row - cfg.index_dim)]
+        index = (jnp.pad(_rope(qi, positions, cfg.rope_theta), [(0, 0)] + lanes),
+                 jnp.pad(_rope(ki, positions, cfg.rope_theta), lanes), wi)
+    return q, k, v, index
+
+
+def _embed(params, tokens, positions, cfg: TransformerConfig, cdt):
+    emb = params["embed"]
+    h = emb["tok"][tokens]
+    if cfg.positions == "learned":
+        h = h + emb["pos"][positions]
+    return h.astype(jnp.dtype(cfg.residual_dtype or cdt))
+
+
+def _logits(params, h, cfg: TransformerConfig, cdt):
+    """Final norm and the output head on rows h -> float32 logits. Float32
+    weights multiply in float32; weights at rest in bfloat16 go to the MXU as
+    they are, the sums in float32."""
+    fin = params["final"]
+    h = _norm(h, fin, "ln", cfg)
+    if fin["head"].dtype == jnp.float32:
+        return h @ fin["head"]
+    return mxu_einsum("td,dv->tv", h.astype(cdt), fin["head"].astype(cdt))
+
+
+def _block(h, params, i: int, cfg: TransformerConfig, cdt, tp: int, comm,
+           attend, valid=None):
+    """Block i on rows h (T, d_model). ``attend(i, a, ap)`` -> (T, Hl, Dh)
+    f32 is the calling program's attention over the normed rows a (it also
+    keeps the cache). -> (h, int32 [experts that got a token, token-expert
+    pairs]; zeros where the MLP is dense)."""
+    lnp = params[f"blk{i}.ln"]
+    ap = params[f"blk{i}.attn"]
+    mp = params[f"blk{i}.mlp"]
+    rdt = jnp.dtype(cfg.residual_dtype or cdt)
+    a = _norm(h, lnp, "ln1", cfg).astype(cdt)
+    attn = attend(i, a, ap)
+    o = mxu_einsum("thx,hxd->td", attn.astype(cdt), ap["wo"].astype(cdt))
+    o = _decode_reduce(o, tp, comm)
+    h = (h.astype(jnp.float32) + o).astype(rdt)
+
+    a = _norm(h, lnp, "ln2", cfg)
+    if cfg.mlp == "experts":
+        o, hit, pairs = dropless_experts(a, mp, cfg.moe_top_k, valid, cdt)
+        return (h.astype(jnp.float32) + o).astype(rdt), jnp.stack([hit, pairs])
+    a = a.astype(cdt)
+    f = jax.nn.gelu(
+        jnp.einsum("td,df->tf", a, mp["w1"].astype(cdt))
+        + mp["b1"].astype(cdt)
+    )
+    o = mxu_einsum("tf,fd->td", f, mp["w2"].astype(cdt))
+    o = _decode_reduce(o, tp, comm)
+    h = (h.astype(jnp.float32) + o + mp["b2"]).astype(rdt)
+    return h, jnp.zeros((2,), jnp.int32)
+
+
+def _check_decode_mode(cfg: TransformerConfig):
+    mlsl_assert(cfg.mlp == "experts" or cfg.n_experts == 0,
+                "decode mode serves dense MLPs and dropless experts "
+                "(mlp='experts'), not the capacity path")
+    mlsl_assert(not cfg.sharded_vocab,
+                "decode mode serves a replicated LM head")
+
+
 def prefill_local(params, tokens, length, cfg: TransformerConfig, tp: int,
                   comm=None, dtype=None):
     """Decode-mode prefill over one sequence (call inside shard_map).
@@ -382,55 +606,115 @@ def prefill_local(params, tokens, length, cfg: TransformerConfig, tp: int,
     positions' K/V are computed but land on the KV cache's reserved garbage
     page (serve/kv_cache.py) and are masked out of every decode read.
     Returns (next-token logits (V,) f32 read at position length-1,
-    k, v: (n_blocks, S, Hl*Dh) f32 local head shards, heads merged with
+    k, v: (n_blocks, S, Gl*Dh) f32 local head shards, heads merged with
     head_dim as the pool's pages store them).
     """
-    mlsl_assert(cfg.n_experts == 0, "decode mode serves dense-MLP models")
-    mlsl_assert(not cfg.sharded_vocab,
-                "decode mode serves a replicated LM head")
+    _check_decode_mode(cfg)
+    mlsl_assert(not cfg.index_topk and not cfg.n_kv_heads,
+                "a grouped-query or indexer configuration prefills by "
+                "chunks over the paged cache (chunk_local)")
     cdt = jnp.dtype(dtype or cfg.dtype)
-    emb = params["embed"]
     n = tokens.shape[0]
-    h = (emb["tok"][tokens] + emb["pos"][:n]).astype(cdt)
+    positions = jnp.arange(n)
+    h = _embed(params, tokens, positions, cfg, cdt)
     scale = 1.0 / float(np.sqrt(cfg.head_dim))
     ks, vs = [], []
+
+    def attend(i, a, ap):
+        q, k, v, _ = _project(a, ap, cfg, positions)   # f32, the at-rest KV
+        ks.append(k.reshape(n, -1))                     # (S, Hl*Dh): page
+        vs.append(v.reshape(n, -1))                     # layout
+        q, k, v = (jnp.moveaxis(x, 1, 0) for x in (q, k, v))
+        return jnp.moveaxis(_causal_attn_f32(q, k, v, scale), 0, 1)
+
+    valid = positions < length
     for i in range(cfg.n_blocks):
-        lnp = params[f"blk{i}.ln"]
-        ap = params[f"blk{i}.attn"]
-        mp = params[f"blk{i}.mlp"]
-        a = _ln(h.astype(jnp.float32),
-                lnp["ln1_scale"], lnp["ln1_bias"]).astype(cdt)
-        qkv = jnp.einsum("sd,dchx->cshx", a, ap["wqkv"].astype(cdt))
-        q, k, v = (
-            jnp.moveaxis(qkv[c], 1, 0).astype(jnp.float32) for c in range(3)
-        )  # (Hl, S, Dh) f32 — the at-rest KV dtype
-        ks.append(jnp.moveaxis(k, 0, 1).reshape(n, -1))  # (S, Hl*Dh): page
-        vs.append(jnp.moveaxis(v, 0, 1).reshape(n, -1))  # layout
-        attn = _causal_attn_f32(q, k, v, scale)
-        o = mxu_einsum("hsx,hxd->sd", attn.astype(cdt), ap["wo"].astype(cdt))
-        o = _decode_reduce(o, tp, comm)
-        h = (h.astype(jnp.float32) + o).astype(cdt)
+        h, _ = _block(h, params, i, cfg, cdt, tp, comm, attend, valid)
+    last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
+    return _logits(params, last, cfg, cdt)[0], jnp.stack(ks), jnp.stack(vs)
 
-        a = _ln(h.astype(jnp.float32),
-                lnp["ln2_scale"], lnp["ln2_bias"]).astype(cdt)
-        f = jax.nn.gelu(
-            jnp.einsum("sd,df->sf", a, mp["w1"].astype(cdt))
-            + mp["b1"].astype(cdt)
-        )
-        o = mxu_einsum("sf,fd->sd", f, mp["w2"].astype(cdt))
-        o = _decode_reduce(o, tp, comm)
-        h = (h.astype(jnp.float32) + o + mp["b2"]).astype(cdt)
 
-    fin = params["final"]
-    h = _ln(h.astype(jnp.float32), fin["ln_scale"], fin["ln_bias"])
-    last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)[0]
-    logits = last @ fin["head"].astype(jnp.float32)
-    return logits, jnp.stack(ks), jnp.stack(vs)
+def _keys_per_trip(pages: int, page: int, want: int = 512) -> int:
+    """Keys a trip of a chunk's walks reads: whole pages, a divisor of the
+    sequence's page table, about ``want``."""
+    n = max(1, min(pages, want // page))
+    while pages % n:
+        n -= 1
+    return n * page
+
+
+def chunk_local(params, tokens, offset, n_valid, table, kpool, vpool, ipool,
+                cfg: TransformerConfig, tp: int, comm=None, dtype=None):
+    """One chunk of one sequence's prompt, through the paged cache (call
+    inside shard_map).
+
+    tokens: (C,) int32, the chunk's ids at positions offset .. offset + C -
+    1, valid up to ``n_valid`` (the last chunk is padded to the chunk's
+    shape; its padding writes to the garbage page, is routed to no expert
+    and is read by no query). table: (P,) int32, the sequence's page table
+    padded with page 0. The chunk's K, V (and index keys: ``ipool``, None
+    for a configuration without an indexer) are written into the pools,
+    then each query attends to what the cache holds of the sequence up to
+    itself: every such position, or the ``index_topk`` that its indexer
+    scores highest (paged_attention.exact_top_k_mask). The walks over the
+    context stop at offset + n_valid. Returns (logits (V,) f32 at the last
+    valid position, int32 [experts that got a token, token-expert pairs]
+    summed over layers, kpool, vpool[, ipool]) - the engine donates the
+    pools.
+    """
+    _check_decode_mode(cfg)
+    mlsl_assert(tp == 1, "chunked prefill serves one chip a stage")
+    cdt = jnp.dtype(dtype or cfg.dtype)
+    c = tokens.shape[0]
+    page, n_pages = kpool.shape[2], table.shape[0]
+    s_max = n_pages * page
+    g, dh = cfg.kv_heads, cfg.head_dim
+    block = _keys_per_trip(n_pages, page)
+    qpos = offset + jnp.arange(c)
+    valid = jnp.arange(c) < n_valid
+    n_keys = offset + n_valid
+    at_page = jnp.where(valid, table[jnp.minimum(qpos // page, n_pages - 1)], 0)
+    at_row = qpos % page
+    kpos = jnp.arange(s_max)
+    # what a query may read at all: itself and before, inside what is held
+    causal = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] < n_keys)
+    pools = {"k": kpool, "v": vpool, "i": ipool}
+    h = _embed(params, tokens, jnp.minimum(qpos, cfg.seq_len - 1), cfg, cdt)
+
+    def attend(i, a, ap):
+        q, k, v, index = _project(a, ap, cfg, qpos)
+        kdt = pools["k"].dtype
+        pools["k"] = pools["k"].at[i, at_page, at_row].set(
+            k.reshape(c, -1).astype(kdt))
+        pools["v"] = pools["v"].at[i, at_page, at_row].set(
+            v.reshape(c, -1).astype(kdt))
+        allowed = causal
+        if index is not None:
+            qi, ki, wi = index
+            pools["i"] = pools["i"].at[i, at_page, at_row].set(ki.astype(kdt))
+            kictx = pools["i"][i, table].reshape(s_max, -1)
+            scores = paged_attention.context_index_scores(
+                qi.astype(kdt), wi, kictx, causal, n_keys, block)
+            room = jnp.minimum(jnp.minimum(qpos + 1, n_keys), cfg.index_topk)
+            allowed = paged_attention.select_in_context(
+                scores, room, n_keys, cfg.index_topk)
+        kctx = pools["k"][i, table].reshape(s_max, g, dh)
+        vctx = pools["v"][i, table].reshape(s_max, g, dh)
+        return paged_attention.masked_context_attention(
+            q, kctx, vctx, allowed, n_keys, block)
+
+    counts = jnp.zeros((2,), jnp.int32)
+    for i in range(cfg.n_blocks):
+        h, n = _block(h, params, i, cfg, cdt, tp, comm, attend, valid)
+        counts = counts + n
+    last = lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=0)
+    out = (_logits(params, last, cfg, cdt)[0], counts, pools["k"], pools["v"])
+    return out + ((pools["i"],) if ipool is not None else ())
 
 
 def decode_local(params, slots, live, kpool, vpool,
                  cfg: TransformerConfig, tp: int, comm=None, dtype=None,
-                 kscale=None, vscale=None):
+                 kscale=None, vscale=None, ipool=None):
     """One continuous-batching decode step (call inside shard_map).
 
     slots: (3, B) int32, a column a batch slot: the token the slot feeds, the
@@ -439,69 +723,83 @@ def decode_local(params, slots, live, kpool, vpool,
     garbage page: inactive slots carry zeros and their writes land there).
     live: (3, capacity) int32, the flat list of the pages some live sequence
     holds, in slot order: pool page, owner slot (-1 pads the list), token
-    index of the page's first row. kpool/vpool: (n_blocks, Np, page, Hl*Dh)
+    index of the page's first row. kpool/vpool: (n_blocks, Np, page, Gl*Dh)
     KV pools, int8 with kscale/vscale (n_blocks, Np, Hl*page) for the
     quantized variant (kv_block_quant codec; a page's scales head-major).
     The new token's K and V are scattered into the pools and attention reads
     the listed pages where they lie; no operation's cost follows the pool's
     size. Returns (logits (B, V) f32, kpool, vpool[, kscale, vscale]) — the
     engine donates the pools.
+
+    With an indexer (``ipool``: the index keys' pool, (n_blocks, Np, page,
+    ``cfg.index_row``)) ``live`` is instead (B, P) int32, a page table a slot padded with
+    page 0: a slot scores the index keys of its whole context, keeps the
+    ``index_topk`` highest exactly and attends over those rows, gathered
+    from the pools where they lie. Returns (logits, kpool, vpool, ipool,
+    int32 [experts that got a token, token-expert pairs] summed over
+    layers).
     """
-    mlsl_assert(cfg.n_experts == 0, "decode mode serves dense-MLP models")
-    mlsl_assert(not cfg.sharded_vocab,
-                "decode mode serves a replicated LM head")
+    _check_decode_mode(cfg)
     cdt = jnp.dtype(dtype or cfg.dtype)
     quant = kscale is not None
     page = kpool.shape[2]
     tokens, positions, pages_b = slots
-    emb = params["embed"]
-    h = (emb["tok"][tokens] + emb["pos"][positions]).astype(cdt)  # (B, dm)
+    h = _embed(params, tokens, positions, cfg, cdt)               # (B, dm)
     b = tokens.shape[0]
     offs_b = positions % page
-    pages, owners, bases = live
-    valid, mine = paged_attention.live_masks(owners, bases, positions, page)
-    for i in range(cfg.n_blocks):
-        lnp = params[f"blk{i}.ln"]
-        ap = params[f"blk{i}.attn"]
-        mp = params[f"blk{i}.mlp"]
-        a = _ln(h.astype(jnp.float32),
-                lnp["ln1_scale"], lnp["ln1_bias"]).astype(cdt)
-        qkv = jnp.einsum("bd,dchx->bchx", a, ap["wqkv"].astype(cdt))
-        q = qkv[:, 0].astype(jnp.float32)                         # (B, Hl, Dh)
-        knew = qkv[:, 1].astype(jnp.float32)
-        vnew = qkv[:, 2].astype(jnp.float32)
+    pools = {"k": kpool, "v": vpool, "ks": kscale, "vs": vscale, "i": ipool}
+    if ipool is None:
+        pages, owners, bases = live
+        valid, mine = paged_attention.live_masks(owners, bases, positions, page)
+    else:
+        mlsl_assert(tp == 1 and not quant,
+                    "the indexer's decode serves one chip, unquantised pools")
+        tables = live
+        s_max = tables.shape[1] * page
+        top = min(cfg.index_topk, s_max)
+        seen = jnp.arange(s_max)[None, :] <= positions[:, None]
+
+    def attend(i, a, ap):
+        q, knew, vnew, index = _project(a, ap, cfg, positions)
+        kdt = pools["k"].dtype
         if quant:
             knew, ksc = kv_block_quant(knew)
             vnew, vsc = kv_block_quant(vnew)
             at = (i, pages_b[:, None],
                   jnp.arange(ksc.shape[1]) * page + offs_b[:, None])
-            kscale = kscale.at[at].set(ksc)
-            vscale = vscale.at[at].set(vsc)
-        kpool = kpool.at[i, pages_b, offs_b].set(knew.reshape(b, -1))
-        vpool = vpool.at[i, pages_b, offs_b].set(vnew.reshape(b, -1))
-        attn = paged_attention.ragged_paged_attention(
-            q, kpool, vpool, i, pages, owners, valid, mine, kscale, vscale,
-            chunk=paged_attention.PAGES_PER_CHUNK)
-        o = mxu_einsum("bhx,hxd->bd", attn.astype(cdt), ap["wo"].astype(cdt))
-        o = _decode_reduce(o, tp, comm)
-        h = (h.astype(jnp.float32) + o).astype(cdt)
+            pools["ks"] = pools["ks"].at[at].set(ksc)
+            pools["vs"] = pools["vs"].at[at].set(vsc)
+        pools["k"] = pools["k"].at[i, pages_b, offs_b].set(
+            knew.reshape(b, -1).astype(kdt))
+        pools["v"] = pools["v"].at[i, pages_b, offs_b].set(
+            vnew.reshape(b, -1).astype(kdt))
+        if index is None:
+            return paged_attention.ragged_paged_attention(
+                q, pools["k"], pools["v"], i, pages, owners, valid, mine,
+                pools["ks"], pools["vs"],
+                chunk=paged_attention.PAGES_PER_CHUNK)
+        qi, ki, wi = index
+        pools["i"] = pools["i"].at[i, pages_b, offs_b].set(ki.astype(kdt))
+        kictx = pools["i"][i, tables].reshape(b, s_max, -1)
+        scores = jnp.where(seen, paged_attention.index_scores(
+            qi.astype(kdt), wi, kictx), -jnp.inf)
+        chosen = paged_attention.exact_top_k_mask(
+            scores, jnp.minimum(positions + 1, top))
+        rows, ok = paged_attention.compact_selected(
+            chosen.reshape(b, -1, page), tables, top)
+        return paged_attention.selected_attention(
+            q, pools["k"], pools["v"], i, rows, ok, cfg.kv_heads // tp)
 
-        a = _ln(h.astype(jnp.float32),
-                lnp["ln2_scale"], lnp["ln2_bias"]).astype(cdt)
-        f = jax.nn.gelu(
-            jnp.einsum("bd,df->bf", a, mp["w1"].astype(cdt))
-            + mp["b1"].astype(cdt)
-        )
-        o = mxu_einsum("bf,fd->bd", f, mp["w2"].astype(cdt))
-        o = _decode_reduce(o, tp, comm)
-        h = (h.astype(jnp.float32) + o + mp["b2"]).astype(cdt)
-
-    fin = params["final"]
-    h = _ln(h.astype(jnp.float32), fin["ln_scale"], fin["ln_bias"])
-    logits = h @ fin["head"].astype(jnp.float32)
+    counts = jnp.zeros((2,), jnp.int32)
+    for i in range(cfg.n_blocks):
+        h, n = _block(h, params, i, cfg, cdt, tp, comm, attend, pages_b > 0)
+        counts = counts + n
+    logits = _logits(params, h, cfg, cdt)
     if quant:
-        return logits, kpool, vpool, kscale, vscale
-    return logits, kpool, vpool
+        return logits, pools["k"], pools["v"], pools["ks"], pools["vs"]
+    if ipool is not None:
+        return logits, pools["k"], pools["v"], pools["i"], counts
+    return logits, pools["k"], pools["v"]
 
 
 class HybridTrainer:

@@ -107,3 +107,210 @@ def ragged_paged_attention(q, kpool, vpool, layer, pages, owners, valid, mine,
          jnp.zeros((b, hl), jnp.float32)))
     out = acc / jnp.dot(jnp.maximum(l, 1e-30), seg.T, precision=hi)
     return out.reshape(b, hl, dh)
+
+
+# -- learned selection inside paged attention ---------------------------------
+#
+# A configuration with an indexer (``TransformerConfig.index_topk`` > 0)
+# keeps a third pool of index keys under the same page tables. A query scores
+# every earlier position of its sequence against the index keys, keeps the
+# ``topk`` highest exactly (ties to the lower position), and attends over
+# those positions only: the decode step gathers the selected rows of K and V
+# from the pool where they lie, the prefill chunk masks a walk over the
+# sequence's own pages. Plain JAX; what a Pallas kernel would fuse is in
+# ROADMAP queue 2.
+
+
+def _prec(dtype):
+    """Float32 operands multiply at full precision (the tests' exactness);
+    bfloat16 ones go to the MXU as they are."""
+    return lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 else None
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order (both
+    zeros as +0: a sum of ``w * relu(.)`` terms comes out as either)."""
+    bits = lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def exact_top_k_mask(scores, k):
+    """The ``k[r]`` highest of each row of ``scores`` (R, S) f32, exactly,
+    ties to the lower column: -> (R, S) bool. Positions that may not be
+    chosen hold -inf, and ``k[r]`` is at most the number that may. No sort:
+    the k-th highest value is found bit by bit (32 counting passes over the
+    scores), then the row keeps what lies above it and the first of its
+    equals. ``lax.approx_max_k`` may miss a member and is a different
+    result, not a faster one."""
+    u = _ordered_bits(scores)
+    k = k.astype(jnp.int32)
+
+    def one_bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(u >= cand[:, None], axis=1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = lax.fori_loop(0, 32, one_bit, jnp.zeros(u.shape[:1], jnp.uint32))
+    above = u > thr[:, None]
+    equal = u == thr[:, None]
+    room = k - jnp.sum(above, axis=1, dtype=jnp.int32)
+    return above | (equal & (jnp.cumsum(equal, axis=1, dtype=jnp.int32)
+                             <= room[:, None]))
+
+
+def index_scores(qi, wi, ki):
+    """The indexer's score of each key for each query: ``sum_j w_j *
+    relu(qI_j . kI_s)``. qi: (..., J, Di), wi: (..., J) f32, ki: (..., S, Di)
+    with the same leading axes -> (..., S) f32."""
+    s = jnp.einsum("...jd,...sd->...js", qi, ki,
+                   preferred_element_type=jnp.float32,
+                   precision=_prec(qi.dtype))
+    return jnp.sum(jax.nn.relu(s) * wi[..., None], axis=-2)
+
+
+def compact_selected(sel, table, k: int):
+    """Where the selected tokens lie in the pool, without a sort or a
+    scatter. sel: (B, P, page) bool, the selection over a slot's page table;
+    table: (B, P) int32 pool pages. -> (rows (B, k) int32 into the pool seen
+    as (pages * page) token rows, ok (B, k) bool): the j-th selected token of
+    each slot in order of position, ``ok`` false past the slot's count. Two
+    levels: the page of the j-th token from the running count of selected
+    tokens a page, then its row from the running count inside that page;
+    the look-ups are products with 0/1 matrices."""
+    b, p, page = sel.shape
+    hi = lax.Precision.HIGHEST
+    incl = jnp.cumsum(jnp.sum(sel, axis=-1, dtype=jnp.int32), axis=-1)
+    j = jnp.arange(k, dtype=jnp.int32)
+    before = incl[:, None, :] <= j[None, :, None]               # (B, k, P)
+    page_of = jnp.sum(before, axis=-1, dtype=jnp.int32)         # P = none left
+    skipped = jnp.max(jnp.where(before, incl[:, None, :], 0), axis=-1)
+    rank = j[None, :] - skipped + 1                             # within the page
+    onehot = (page_of[:, :, None] == jnp.arange(p)[None, None, :]
+              ).astype(jnp.float32)
+    within = (jnp.cumsum(sel, axis=-1, dtype=jnp.int32) * sel
+              ).astype(jnp.float32)                             # 0 = not chosen
+    ranks = jnp.einsum("bkp,bpr->bkr", onehot, within, precision=hi)
+    row = jnp.argmax(ranks == rank[:, :, None].astype(jnp.float32), axis=-1)
+    pool_page = jnp.einsum("bkp,bp->bk", onehot, table.astype(jnp.float32),
+                           precision=hi)
+    ok = j[None, :] < incl[:, -1:]
+    rows = jnp.round(pool_page).astype(jnp.int32) * page + row.astype(jnp.int32)
+    return jnp.where(ok, rows, 0), ok
+
+
+def selected_attention(q, kpool, vpool, layer, rows, ok, groups: int):
+    """Decode attention over selected rows, read where they lie. q: (B, H,
+    Dh); kpool / vpool: (n_layers, Np, page, G*Dh) with G key-value heads,
+    query head h reading head ``h // (H // G)``; rows / ok: ``compact_
+    selected``. Softmax in float32. -> (B, H, Dh) f32."""
+    b, h, dh = q.shape
+    n_l, n_p, page, gd = kpool.shape
+    k = rows.shape[1]
+    ksel = kpool.reshape(n_l, n_p * page, gd)[layer, rows].reshape(
+        b, k, groups, dh)
+    vsel = vpool.reshape(n_l, n_p * page, gd)[layer, rows].reshape(
+        b, k, groups, dh)
+    qg = (q * (1.0 / dh ** 0.5)).astype(kpool.dtype).reshape(
+        b, groups, h // groups, dh)
+    s = jnp.einsum("bgqx,bkgx->bgqk", qg, ksel,
+                   preferred_element_type=jnp.float32,
+                   precision=_prec(kpool.dtype))
+    s = jnp.where(ok[:, None, None, :], s, NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bgqk,bkgx->bgqx", p.astype(kpool.dtype), vsel,
+                   preferred_element_type=jnp.float32,
+                   precision=_prec(kpool.dtype))
+    return o.reshape(b, h, dh)
+
+
+def masked_context_attention(q, kctx, vctx, allowed, n_keys, block: int):
+    """A prefill chunk's attention over its sequence's context, each query
+    reading the keys ``allowed`` lets it. q: (C, H, Dh); kctx / vctx: (S, G,
+    Dh), the sequence's pages side by side; allowed: (C, S) bool (selection
+    and causality; every query allows at least one key under ``n_keys``);
+    the walk reads ``block`` keys a trip and stops at ``n_keys``, so its
+    work follows the context held, not the longest one. One pass: the
+    exponent is shifted by a bound on the score (|q| * the longest key /
+    sqrt(Dh)) in place of the running maximum, which a softmax does not
+    notice. -> (C, H, Dh) f32."""
+    c, h, dh = q.shape
+    s_max, g, _ = kctx.shape
+    per = h // g
+    scale = 1.0 / dh ** 0.5
+    dt = kctx.dtype
+    prec = _prec(dt)
+    live = (jnp.arange(s_max) < n_keys)[:, None]
+    k_long = jnp.sqrt(jnp.max(jnp.where(
+        live, jnp.sum(jnp.square(kctx.astype(jnp.float32)), axis=-1), 0.0),
+        axis=0))                                                  # (G,)
+    qf = q.astype(jnp.float32).reshape(c, g, per, dh)
+    bound = jnp.sqrt(jnp.sum(jnp.square(qf), axis=-1)) \
+        * k_long[None, :, None] * scale                           # (C, G, per)
+    qg = (qf * scale).astype(dt)
+
+    def walk(i, carry):
+        acc, den = carry
+        at = i * block
+        kb = lax.dynamic_slice_in_dim(kctx, at, block)            # (block, G, Dh)
+        vb = lax.dynamic_slice_in_dim(vctx, at, block)
+        ok = lax.dynamic_slice_in_dim(allowed, at, block, axis=1)  # (C, block)
+        s = jnp.einsum("cgqx,kgx->cgqk", qg, kb, precision=prec,
+                       preferred_element_type=jnp.float32)
+        p = jnp.where(ok[:, None, None, :],
+                      jnp.exp(s - bound[..., None]), 0.0)
+        den = den + jnp.sum(p, axis=-1)
+        acc = acc + jnp.einsum("cgqk,kgx->cgqx", p.astype(dt), vb,
+                               precision=prec,
+                               preferred_element_type=jnp.float32)
+        return acc, den
+
+    acc, den = lax.fori_loop(
+        0, (n_keys + block - 1) // block, walk,
+        (jnp.zeros((c, g, per, dh), jnp.float32),
+         jnp.zeros((c, g, per), jnp.float32)))
+    return (acc / jnp.maximum(den, 1e-37)[..., None]).reshape(c, h, dh)
+
+
+def context_index_scores(qi, wi, kictx, may, n_keys, block: int):
+    """A chunk's index scores over its sequence's context. qi: (C, J, Di),
+    wi: (C, J) f32, kictx: (S, Di), may: (C, S) bool (what a query may read
+    at all). -> (C, S) f32 with -inf where ``may`` is false; the walk reads
+    ``block`` keys a trip and stops at ``n_keys``."""
+    c, s_max = may.shape
+
+    def walk(i, out):
+        at = i * block
+        kb = lax.dynamic_slice_in_dim(kictx, at, block)
+        ok = lax.dynamic_slice_in_dim(may, at, block, axis=1)
+        sc = jnp.where(ok, index_scores(qi, wi, kb[None]), -jnp.inf)
+        return lax.dynamic_update_slice_in_dim(out, sc, at, axis=1)
+
+    return lax.fori_loop(0, (n_keys + block - 1) // block, walk,
+                         jnp.full((c, s_max), -jnp.inf, jnp.float32))
+
+
+def select_in_context(scores, room, n_keys, topk: int):
+    """``exact_top_k_mask`` of a chunk's scores (C, S), at the cost of the
+    context held: nothing to choose while the context is no longer than
+    ``topk`` (every position a query may read is selected), and else the
+    counting passes run over the narrowest of a quarter, a half and the
+    whole of S that holds ``n_keys``."""
+    s_max = scores.shape[1]
+    widths = sorted({min(s_max, -(-s_max // d // 128) * 128)
+                     for d in (4, 2, 1)})
+    widths = [w for w in widths if w > topk] or [s_max]
+
+    def everything(sc):
+        return sc > -jnp.inf
+
+    def within(w):
+        def choose(sc):
+            return jnp.pad(exact_top_k_mask(sc[:, :w], room),
+                           ((0, 0), (0, s_max - w)))
+        return choose
+
+    which = jnp.where(
+        n_keys <= topk, 0,
+        1 + sum((n_keys > w).astype(jnp.int32) for w in widths[:-1]))
+    return lax.switch(which, [everything] + [within(w) for w in widths],
+                      scores)

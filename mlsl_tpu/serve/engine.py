@@ -3,7 +3,8 @@
 One :class:`InferenceEngine` owns a 1 x tp slice of the mesh (dp = sp = 1 —
 serving replicates across engines, not inside one), the sharded parameter
 tree (the HybridTrainer device_put idiom), the paged KV pools as donated
-device arrays, and three compiled smap programs:
+device arrays (K, V, and the index keys of a model with an indexer), and
+three compiled smap programs:
 
 - **prefill** — one padded sequence -> next-token logits + per-layer K/V.
   Padded to the full context length so there is exactly one compiled shape.
@@ -19,6 +20,15 @@ device arrays, and three compiled smap programs:
   compute dtype so the SLA governor's precision shed (bf16) is just a
   different entry in the program cache — KV at rest stays f32/int8 either
   way, which is why recovery is numerically clean.
+
+With ``prefill_chunk`` (a model with grouped-query heads or an indexer
+needs it; any model may ask) the first two give way to one **chunk** program
+(``models.transformer.chunk_local``): an admitted prompt is prefilled that
+many positions a step through the paged cache, each chunk writing its K, V
+(and index keys) and attending to what the cache already holds of the
+sequence plus itself; at most one chunk a step, one sequence prefilling at a
+time, every sequence that has its first token decoding in every step. The
+first token comes from the last chunk.
 
 Scheduling runs entirely on the caller's thread (``step()``/``run()``):
 device dispatch from a worker thread is exactly what lint rule A202
@@ -107,6 +117,15 @@ class _Seq:
     last_token: int
     admitted_at: int    # admission counter: eviction preempts the youngest
     finished: bool = False
+    # chunked prefill: the tokens to prefill, how many the cache holds, and
+    # the number of the next chunk; a sequence decodes once filled
+    prefix: Optional[np.ndarray] = None
+    filled: int = 0
+    chunks: int = 0
+
+    @property
+    def prefilling(self) -> bool:
+        return self.prefix is not None and self.filled < self.prefix.size
 
 
 class InferenceEngine:
@@ -115,7 +134,8 @@ class InferenceEngine:
     def __init__(self, env, cfg, tp: int = 1, params=None, seed: int = 0,
                  devices=None, config=None, max_batch: Optional[int] = None,
                  queue_depth: Optional[int] = None,
-                 tpot_p99_ms: float = 0.0):
+                 tpot_p99_ms: float = 0.0,
+                 prefill_chunk: Optional[int] = None):
         self.env = env
         self.cfg = cfg
         self.tp = int(tp)
@@ -130,12 +150,28 @@ class InferenceEngine:
         self.specs = tfm.param_specs(cfg)
         if params is None:
             params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
-        self.params = jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            params, self.specs, is_leaf=lambda x: isinstance(x, P),
-        )
 
+        def place(x, spec):
+            # weights handed in where they belong stay as they are: a second
+            # set of a model that fills half the chip would not fit
+            sharding = NamedSharding(self.mesh, spec)
+            if isinstance(x, jax.Array) \
+                    and x.sharding.is_equivalent_to(sharding, x.ndim):
+                return x
+            return jax.device_put(x, sharding)
+
+        self.params = jax.tree.map(
+            place, params, self.specs, is_leaf=lambda x: isinstance(x, P))
+
+        self.prefill_chunk = int(prefill_chunk or 0)
+        self.indexed = bool(cfg.index_topk)
+        mlsl_assert(
+            self.prefill_chunk or not (self.indexed or cfg.n_kv_heads),
+            "a grouped-query or indexer model prefills by chunks: "
+            "pass prefill_chunk")
         self.quant = bool(self.config.serve_kv_quant)
+        mlsl_assert(not (self.quant and self.prefill_chunk),
+                    "chunked prefill writes unquantised pools")
         self.cache = kvc.PagedKVCache(
             cfg,
             page_elems=self.config.serve_kv_page_elems,
@@ -161,20 +197,25 @@ class InferenceEngine:
         # whole heads shard over 'model'. Scales: one a token and head, a
         # page's on one row, head-major for the same two reasons.
         npg, page = self.cache.num_pages + 1, self.cache.page_elems
-        pool_shape = (cfg.n_blocks, npg, page, cfg.n_heads * cfg.head_dim)
+        pool_shape = (cfg.n_blocks, npg, page, cfg.kv_heads * cfg.head_dim)
         self._pool_spec = P(None, None, None, MODEL_AXIS)
         self._scale_spec = P(None, None, MODEL_AXIS)
         # the decode program's live-page list: room for every page of the
         # pool, a whole number of the chunks the attention walks
         self._chunk = paged_attention.PAGES_PER_CHUNK
         self._list_cap = -(-self.cache.num_pages // self._chunk) * self._chunk
-        kv_dt = jnp.int8 if self.quant else jnp.float32
+        kv_dt = jnp.int8 if self.quant else jnp.dtype(cfg.kv_dtype)
         self.kpool = jax.device_put(
             jnp.zeros(pool_shape, kv_dt),
             NamedSharding(self.mesh, self._pool_spec))
         self.vpool = jax.device_put(
             jnp.zeros(pool_shape, kv_dt),
             NamedSharding(self.mesh, self._pool_spec))
+        # the index keys of a model with an indexer: a third pool under the
+        # same page tables (and the same budget: kv_cache.page_bytes)
+        self.ipool = jax.device_put(
+            jnp.zeros(pool_shape[:3] + (cfg.index_row,), kv_dt),
+            NamedSharding(self.mesh, P())) if self.indexed else None
         if self.quant:
             sshape = pool_shape[:2] + (cfg.n_heads * page,)
             self.kscale = jax.device_put(
@@ -202,6 +243,23 @@ class InferenceEngine:
     def _build_programs(self) -> None:
         cfg, tp, comm = self.cfg, self.tp, self.comm
         kv_spec = P(None, None, MODEL_AXIS)
+        self._decode_cache: Dict[str, object] = {}
+        if self.prefill_chunk:
+            indexed = self.indexed
+
+            def chunk_body(params, tokens, offset, n_valid, table,
+                           kpool, vpool, *ipool):
+                return tfm.chunk_local(
+                    params, tokens, offset, n_valid, table, kpool, vpool,
+                    ipool[0] if indexed else None, cfg, tp, comm=comm)
+
+            pools = (self._pool_spec,) * 2 + ((P(),) if indexed else ())
+            self._chunk_prog = jax.jit(smap(
+                chunk_body, self.mesh,
+                in_specs=(self.specs, P(), P(), P(), P()) + pools,
+                out_specs=(P(), P()) + pools, check=False,
+            ), donate_argnums=tuple(range(5, 5 + len(pools))))
+            return
 
         def prefill_body(params, tokens, length):
             return tfm.prefill_local(params, tokens, length, cfg, tp,
@@ -257,8 +315,6 @@ class InferenceEngine:
                 check=False,
             ), donate_argnums=(0, 1))
 
-        self._decode_cache: Dict[str, object] = {}
-
     def _decode_prog(self, dtype: str):
         prog = self._decode_cache.get(dtype)
         if prog is not None:
@@ -277,6 +333,16 @@ class InferenceEngine:
             out_specs = (P(), self._pool_spec, self._pool_spec,
                          self._scale_spec, self._scale_spec)
             donate = (3, 4, 5, 6)
+        elif self.indexed:
+            def decode_body(params, slots, tables, kpool, vpool, ipool):
+                return tfm.decode_local(
+                    params, slots, tables, kpool, vpool, cfg, tp,
+                    comm=comm, dtype=dtype, ipool=ipool)
+
+            in_specs = (self.specs, P(), P(),
+                        self._pool_spec, self._pool_spec, P())
+            out_specs = (P(), self._pool_spec, self._pool_spec, P(), P())
+            donate = (3, 4, 5)
         else:
             def decode_body(params, slots, live, kpool, vpool):
                 return tfm.decode_local(
@@ -410,6 +476,9 @@ class InferenceEngine:
     # -- internals ---------------------------------------------------------
 
     def _admit(self) -> None:
+        if self.prefill_chunk:
+            self._admit_chunked()
+            return
         while len(self._active) < self.governor.batch_limit:
             t0 = time.perf_counter_ns()
             with self._lock:
@@ -449,6 +518,133 @@ class InferenceEngine:
                             queue_wait_ns=t0 - req.t_submit,
                             resumed=resumed, error=error)
 
+    def _admit_chunked(self) -> None:
+        """At most one chunk a step, one sequence prefilling at a time: the
+        next chunk of the sequence that is being filled, else the first
+        chunk of the oldest queued request (``serve.admit`` is the step in
+        which a request leaves the queue and runs its first chunk)."""
+        filling = [s for s in self._active.values() if s.prefilling]
+        if filling:
+            seq = min(filling, key=lambda s: s.admitted_at)
+            try:
+                self._run_chunk(seq)
+            except Exception as e:      # fail this one request closed
+                self._drop(seq, e)
+            return
+        if len(self._active) >= self.governor.batch_limit:
+            return
+        t0 = time.perf_counter_ns()
+        with self._lock:
+            if not self._pending:
+                return
+            req = self._pending.popleft()
+        seq_id = self._next_seq_id
+        self._next_seq_id += 1
+        resumed = req._resume is not None
+        prefix = req._resume if resumed else req.prompt
+        seq = error = None
+        try:
+            chaos.inject("serve.admit", req_id=req.id)
+            if not self.cache.admit(seq_id, prefix.size + 1):
+                with self._lock:        # pool backpressure: leave it queued
+                    self._pending.appendleft(req)
+                return
+            seq = _Seq(req=req, seq_id=seq_id, slot=-1,
+                       position=int(prefix.size), last_token=-1,
+                       admitted_at=self._admit_counter, prefix=prefix)
+            self._admit_counter += 1
+            self._active[seq_id] = seq
+            self._run_chunk(seq)
+        except Exception as e:
+            error = type(e).__name__
+            if seq is None:
+                self._fail(req, e)
+            else:
+                self._drop(seq, e)
+        tr = obs_trace._tracer
+        if tr is not None:
+            tr.complete("serve.admit", "serve", t0, step=self._step_no,
+                        req=req.id, seq=seq_id,
+                        prompt_tokens=int(prefix.size),
+                        queue_wait_ns=t0 - req.t_submit,
+                        resumed=resumed, error=error)
+
+    def _drop(self, seq: _Seq, e: BaseException) -> None:
+        """Fail one admitted request closed: its pages back, out of the
+        batch."""
+        self._active.pop(seq.seq_id, None)
+        self.cache.release(seq.seq_id)
+        self._fail(seq.req, e)
+        m = metrics._registry
+        if m is not None:
+            m.inc("mlsl_serve_requests_total", 1.0,
+                  route=seq.req.route, outcome="failed")
+
+    def _run_chunk(self, seq: _Seq) -> None:
+        """Prefill the sequence's next ``prefill_chunk`` positions through
+        the paged cache (one compiled shape: the last chunk is padded and
+        its padding masked). The last chunk yields the first token."""
+        tr = obs_trace._tracer
+        step, req = self._step_no, seq.req
+        t0 = time.perf_counter_ns()
+        size, at = self.prefill_chunk, seq.filled
+        n = min(size, seq.prefix.size - at)
+        last = at + n >= seq.prefix.size
+        tokens = np.zeros((size,), np.int32)
+        tokens[:n] = seq.prefix[at:at + n]
+        table = np.asarray(self.cache.table_padded(seq.seq_id), np.int32)
+        pools = (self.kpool, self.vpool) \
+            + ((self.ipool,) if self.indexed else ())
+        out = self._chunk_prog(self.params, tokens, np.int32(at), np.int32(n),
+                          table, *pools)
+        logits, counts = out[:2]
+        self.kpool, self.vpool = out[2:4]
+        if self.indexed:
+            self.ipool = out[4]
+        # the counts come back with the logits; a chunk that is not the last
+        # has no use for its logits and leaves them on the device
+        if last:
+            logits, counts = jax.device_get((logits, counts))
+        else:
+            counts = np.asarray(counts)
+        seq.filled += n
+        seq.chunks += 1
+        stats.record_serve("prefill_chunks")
+        if tr is not None:
+            t0 = tr.complete(
+                "serve.prefill.chunk", "serve", t0, step=step, req=req.id,
+                chunk=seq.chunks - 1, offset=at, tokens=n, last=last,
+                experts_hit=int(counts[0]), expert_tokens=int(counts[1]))
+        if not last:
+            return
+        tok = int(np.argmax(logits))
+        t_first = tr.complete("serve.first_token", "serve", t0, step=step,
+                              req=req.id) \
+            if tr is not None else time.perf_counter_ns()
+        self._first_token(seq, tok, t_first)
+
+    def _first_token(self, seq: _Seq, tok: int, t_first: int) -> None:
+        """A prefilled sequence has its first token: count it, time it, and
+        let the sequence decode (or finish, if one token was all)."""
+        req = seq.req
+        stats.record_serve("prefills")
+        stats.record_serve("tokens_out")
+        self._tokens_total += 1
+        if req.ttft_ms is None:         # a resumed request keeps its first
+            req.ttft_ms = (t_first - req.t_submit) / 1e6
+            m = metrics._registry
+            if m is not None:
+                m.observe("mlsl_serve_ttft_ms", req.ttft_ms,
+                          route=req.route)
+        req._resume = None
+        req.state = "active"
+        req.tokens.append(tok)
+        seq.last_token = tok
+        if (req.eos_token is not None and tok == req.eos_token) \
+                or len(req.tokens) >= req.max_new_tokens \
+                or seq.position >= self.ctx_len:
+            seq.finished = True
+
     def _prefill_seq(self, req: Request, seq_id: int,
                      prefix: np.ndarray) -> None:
         tr = obs_trace._tracer
@@ -477,25 +673,10 @@ class InferenceEngine:
         t_first = tr.complete("serve.first_token", "serve", t0, step=step,
                               req=rid) \
             if tr is not None else time.perf_counter_ns()
-        stats.record_serve("prefills")
-        stats.record_serve("tokens_out")
-        self._tokens_total += 1
-        if req.ttft_ms is None:         # a resumed request keeps its first
-            req.ttft_ms = (t_first - req.t_submit) / 1e6
-            m = metrics._registry
-            if m is not None:
-                m.observe("mlsl_serve_ttft_ms", req.ttft_ms,
-                          route=req.route)
-        req._resume = None
-        req.state = "active"
-        req.tokens.append(tok)
         seq = _Seq(req=req, seq_id=seq_id, slot=-1, position=n,
                    last_token=tok, admitted_at=self._admit_counter)
         self._admit_counter += 1
-        if (req.eos_token is not None and tok == req.eos_token) \
-                or len(req.tokens) >= req.max_new_tokens \
-                or seq.position >= self.ctx_len:
-            seq.finished = True
+        self._first_token(seq, tok, t_first)
         self._active[seq_id] = seq
 
     def _fail(self, req: Request, e: BaseException) -> None:
@@ -556,7 +737,10 @@ class InferenceEngine:
         if not self._active:
             return
         t_decode = t0 = time.perf_counter_ns()
-        live = sorted(self._active.values(), key=lambda s: s.admitted_at)
+        live = sorted((s for s in self._active.values() if not s.prefilling),
+                      key=lambda s: s.admitted_at)
+        if not live:            # the one sequence in flight is still filling
+            return
         # a column a slot: token, position, the page the position lies in
         # (inactive slots: zeros, so their writes land on the garbage page)
         slots = np.zeros((3, self.max_batch), np.int32)
@@ -564,8 +748,17 @@ class InferenceEngine:
             seq.slot = i
             slots[:, i] = (seq.last_token, seq.position,
                            self.cache.page_of(seq.seq_id, seq.position))
-        pages, held = self.cache.live_list(
-            [seq.seq_id for seq in live], self._list_cap)
+        if self.indexed:
+            # a page table a slot; the pages the index keys are read from
+            width = self.cache.max_pages_per_seq
+            pages = np.zeros((self.max_batch, width), np.int32)
+            for i, seq in enumerate(live):
+                pages[i] = self.cache.table_padded(seq.seq_id)
+            gathered = self.max_batch * width
+        else:
+            pages, held = self.cache.live_list(
+                [seq.seq_id for seq in live], self._list_cap)
+            gathered = -(-held // self._chunk) * self._chunk
         dtype = "bfloat16" if self.governor.precision_shed else None
         prog = self._decode_prog(dtype or self.cfg.dtype)
         args = (jnp.asarray(slots), jnp.asarray(pages))
@@ -580,8 +773,8 @@ class InferenceEngine:
                 # the governor sheds — the degraded-not-down path
                 chaos.inject("serve.decode", inflight=len(live))
                 out = prog(self.params, *args, self.kpool, self.vpool,
-                           *((self.kscale, self.vscale)
-                             if self.quant else ()))
+                           *((self.kscale, self.vscale) if self.quant
+                             else (self.ipool,) if self.indexed else ()))
                 break
             except Exception as e:
                 cls = supervisor.classify(e)
@@ -597,11 +790,15 @@ class InferenceEngine:
         t0 = tr.complete("serve.decode.dispatch", "serve", t_try, step=step,
                          attempt=attempt) \
             if tr is not None else t_try
+        counts = None
         if self.quant:
             logits, self.kpool, self.vpool, self.kscale, self.vscale = out
+        elif self.indexed:
+            logits, self.kpool, self.vpool, self.ipool, counts = out
         else:
             logits, self.kpool, self.vpool = out
-        logits = np.asarray(logits)           # blocks until the step is done
+        # blocks until the step is done; the expert counts ride with it
+        logits, counts = jax.device_get((logits, counts))
         t0 = tr.complete("serve.decode.wait", "serve", t0, step=step,
                          bytes=logits.nbytes) \
             if tr is not None else time.perf_counter_ns()
@@ -630,11 +827,18 @@ class InferenceEngine:
                 seq.finished = True
         if tr is not None:
             tr.complete("serve.decode.sample", "serve", t0, step=step)
+            more = {}
+            if self.indexed:
+                top = self.cfg.index_topk
+                more = dict(
+                    ctx_tokens=tokens_live,
+                    selected_tokens=sum(min(top, s.position) for s in live),
+                    experts_hit=int(counts[0]), expert_tokens=int(counts[1]))
             tr.complete("serve.decode", "serve", t_decode, step=step,
                         inflight=len(live), tokens_live=tokens_live,
                         pages_held=self.cache.held_pages,
-                        pages_gathered=-(-held // self._chunk) * self._chunk,
-                        pool_pages=self.cache.num_pages)
+                        pages_gathered=gathered,
+                        pool_pages=self.cache.num_pages, **more)
 
     def _decode_fault(self, e: BaseException) -> None:
         cls = supervisor.classify(e)

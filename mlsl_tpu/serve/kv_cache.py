@@ -8,7 +8,8 @@ KV side keeps the same :class:`~mlsl_tpu.data.cache.AdmissionBudget`
 admit-or-reject contract underneath, and adds what serving needs on top:
 
 - **fixed-size HBM pages** — the pool is ``(n_blocks, num_pages+1, page,
-  heads * head_dim)`` per K and V, owned by the engine as donated device
+  kv_heads * head_dim)`` per K and V (and ``(..., page, index_row)`` for the
+  index keys of a model with an indexer, under the same tables), owned by the engine as donated device
   arrays; this class is the host-side allocator (free-list + page tables)
   and never touches device memory itself. Page granularity kills the
   fragmentation that per-sequence max-length slabs would cause: a
@@ -38,6 +39,7 @@ from __future__ import annotations
 import itertools
 from typing import Dict, List, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from mlsl_tpu.data.cache import AdmissionBudget
@@ -66,14 +68,17 @@ class PagedKVCache:
             "KV write scatters the padded prefill as whole pages)",
         )
         self.max_pages_per_seq = self.ctx_len // self.page_elems
-        # bytes for ONE page across all layers, K and V: int8 stores
-        # 1 byte/elem plus a f32 scale per (token, head); f32 stores 4.
-        elem = 1 if self.quant else 4
+        # bytes for ONE page across all layers and all pools, counted from
+        # the configuration: K and V a key-value head (int8 stores 1 byte an
+        # element plus a f32 scale per (token, head); else ``kv_dtype``'s
+        # width), and the index key a token where the model has an indexer
+        # (the third pool lives under the same tables and the same budget).
+        elem = 1 if self.quant else jnp.dtype(cfg.kv_dtype).itemsize
         scale = 4 if self.quant else 0
-        self.page_bytes = (
-            cfg.n_blocks * 2 * self.page_elems * cfg.n_heads
-            * (cfg.head_dim * elem + scale)
-        )
+        heads = cfg.kv_heads
+        index = cfg.index_row       # lanes stored, zeros above index_dim
+        self.page_bytes = cfg.n_blocks * self.page_elems * (
+            2 * heads * (cfg.head_dim * elem + scale) + index * elem)
         self.budget = AdmissionBudget(int(budget_mb * (1 << 20)))
         self.num_pages = self.budget.budget_bytes // self.page_bytes
         if self.num_pages < self.max_pages_per_seq:

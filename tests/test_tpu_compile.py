@@ -82,3 +82,80 @@ def test_decode_program_reads_and_writes_the_pool_in_place(
                      for p in pools)
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 16, mem
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_indexer_programs_keep_three_pools_in_place(
+        one_chip, no_compile_cache, program):
+    """The decode step and the prefill chunk of the configuration with an
+    indexer (perf/configs/keye-vl2-30b-a3b-pp8.json: 32 query heads over 4
+    key-value heads, 16 x 64 indexer with topk 2,048, 128 experts, bfloat16
+    weights and pools) at the serving cell's sizes: K, V and the index keys
+    alias their outputs, the temporaries do not grow with the pool (1 and 3
+    GiB compile to the same), and weights, pool and temporaries fit the
+    chip. The first trace of PR 29 showed what a 64-lane index pool costs:
+    the compiler laid the whole pool out anew around every layer's gather."""
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from perf.lib import manifest
+
+    from mlsl_tpu.models import transformer as tfm
+
+    config = manifest.read_json(
+        manifest.PERF / "configs" / "keye-vl2-30b-a3b-pp8.json")
+    traffic = manifest.traffic_file("longctx-open-loop")
+    cfg = manifest.load_module(
+        manifest.PERF / "adapters" / "keye.py").program_config(config, traffic)
+    page, batch = traffic["kv_page_tokens"], traffic["max_batch"]
+    chunk = traffic["prefill_chunk_tokens"]
+    table = cfg.seq_len // page
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: shape(x.shape, x.dtype),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    weights = sum(jnp.dtype(x.dtype).itemsize * math.prod(x.shape)
+                  for x in jax.tree.leaves(params))
+    assert 8.7e9 < weights < 8.8e9          # 4,375 M parameters in bfloat16
+
+    def compiled(pool_mb):
+        token = 2 * cfg.kv_heads * cfg.head_dim + cfg.index_row
+        pages = (pool_mb << 20) // (cfg.n_blocks * page * token * 2) + 1
+        kv = shape((cfg.n_blocks, pages, page, cfg.kv_heads * cfg.head_dim),
+                   jnp.bfloat16)
+        pools = (kv, kv, shape((cfg.n_blocks, pages, page, cfg.index_row),
+                               jnp.bfloat16))
+        if program == "decode":
+            def body(params, slots, tables, kpool, vpool, ipool):
+                return tfm.decode_local(params, slots, tables, kpool, vpool,
+                                        cfg, 1, ipool=ipool)
+
+            args = (shape((3, batch), jnp.int32),
+                    shape((batch, table), jnp.int32))
+        else:
+            def body(params, tokens, offset, n_valid, table_, kpool, vpool,
+                     ipool):
+                return tfm.chunk_local(params, tokens, offset, n_valid,
+                                       table_, kpool, vpool, ipool, cfg, 1)
+
+            args = (shape((chunk,), jnp.int32), shape((), jnp.int32),
+                    shape((), jnp.int32), shape((table,), jnp.int32))
+        n = 1 + len(args)
+        mem = jax.jit(body, donate_argnums=(n, n + 1, n + 2)).lower(
+            params, *args, *pools).compile().memory_analysis()
+        return mem, sum(jnp.dtype(p.dtype).itemsize * math.prod(p.shape)
+                        for p in pools)
+
+    small, small_pool = compiled(1024)
+    large, large_pool = compiled(3072)
+    assert large.alias_size_in_bytes >= large_pool > 2.9 * small_pool
+    assert small.alias_size_in_bytes >= small_pool
+    assert abs(large.temp_size_in_bytes - small.temp_size_in_bytes) < 64 << 20
+    assert large.temp_size_in_bytes < large_pool
+    assert weights + large_pool + large.temp_size_in_bytes < 15.75 * 2**30
