@@ -6,8 +6,9 @@ tree (the HybridTrainer device_put idiom), the paged KV pools as donated
 device arrays (K, V, and the index keys of a model with an indexer), and
 three compiled smap programs:
 
-- **prefill** — one padded sequence -> next-token logits + per-layer K/V.
-  Padded to the full context length so there is exactly one compiled shape.
+- **prefill** — one padded sequence -> the next token, its logits (an
+  output only the unpaged oracle below fetches) + per-layer K/V. Padded to
+  the full context length so there is exactly one compiled shape.
 - **write** — scatter the prefill K/V into the paged pools through the
   sequence's page table (donation-enabled: the pools update in place in
   HBM). The int8 variant quantizes in-graph via ``kv_block_quant``.
@@ -16,10 +17,16 @@ three compiled smap programs:
   one token per call, sequences join and retire between calls. Attention
   reads the pools in place through the flat list of the pages the live
   sequences hold, a chunk of pages a trip, so a step's work follows that
-  list's length and not the batch's or the pool's capacity. Built per
-  compute dtype so the SLA governor's precision shed (bf16) is just a
-  different entry in the program cache — KV at rest stays f32/int8 either
-  way, which is why recovery is numerically clean.
+  list's length and not the batch's or the pool's capacity. It returns the
+  token each slot chose and the pools, never the logits. Built per compute
+  dtype so the SLA governor's precision shed (bf16) is just a different
+  entry in the program cache — KV at rest stays f32/int8 either way, which
+  is why recovery is numerically clean.
+
+The engine is greedy, and the choice is made inside the program that made
+the logits (``_greedy``): a step reads back ``max_batch`` int32 (and, with
+an indexer, the two expert counts), a prefill or a last chunk one, whatever
+the vocabulary. A sampler with a temperature belongs at the same place.
 
 With ``prefill_chunk`` (a model with grouped-query heads or an indexer
 needs it; any model may ask) the first two give way to one **chunk** program
@@ -27,8 +34,9 @@ needs it; any model may ask) the first two give way to one **chunk** program
 many positions a step through the paged cache, each chunk writing its K, V
 (and index keys) and attending to what the cache already holds of the
 sequence plus itself; at most one chunk a step, one sequence prefilling at a
-time, every sequence that has its first token decoding in every step. The
-first token comes from the last chunk.
+time, every sequence that has its first token decoding in every step. A
+chunk returns the token after its last valid position in the logits' place;
+the first token is the last chunk's.
 
 Scheduling runs entirely on the caller's thread (``step()``/``run()``):
 device dispatch from a worker thread is exactly what lint rule A202
@@ -73,6 +81,44 @@ from mlsl_tpu.serve import kv_cache as kvc, sla
 #: consecutive failed decode steps before the in-flight batch is failed
 #: closed (the engine itself survives and keeps admitting)
 _DECODE_FAIL_CAP = 8
+
+
+def _greedy(logits):
+    """The greedy choice over the last axis, as int32: the first index of
+    the maximum, a NaN counting as the maximum (``np.argmax``'s rule; the
+    tests hold the programs to it)."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def decode_body_of(cfg, tp: int, comm, dtype, pools=()):
+    """The decode program's body: ``models.transformer.decode_local`` with
+    the tokens it chose where its logits stood. ``pools`` names the pools
+    beside K and V: ``("kscale", "vscale")`` for int8 pools, ``("ipool",)``
+    under an indexer. The head is replicated in decode mode, so under
+    ``tp`` > 1 every rank makes the same choice."""
+
+    def decode_body(params, slots, live, kpool, vpool, *more):
+        logits, *rest = tfm.decode_local(
+            params, slots, live, kpool, vpool, cfg, tp, comm=comm,
+            dtype=dtype, **dict(zip(pools, more)))
+        return (_greedy(logits), *rest)
+
+    return decode_body
+
+
+def chunk_body_of(cfg, tp: int, comm):
+    """The chunk program's body: ``models.transformer.chunk_local`` with the
+    token after the chunk's last valid position where its logits stood; the
+    index keys' pool is handed in under an indexer and not otherwise."""
+
+    def chunk_body(params, tokens, offset, n_valid, table,
+                   kpool, vpool, *ipool):
+        logits, *rest = tfm.chunk_local(
+            params, tokens, offset, n_valid, table, kpool, vpool,
+            ipool[0] if ipool else None, cfg, tp, comm=comm)
+        return (_greedy(logits), *rest)
+
+    return chunk_body
 
 
 @dataclass
@@ -245,30 +291,23 @@ class InferenceEngine:
         kv_spec = P(None, None, MODEL_AXIS)
         self._decode_cache: Dict[str, object] = {}
         if self.prefill_chunk:
-            indexed = self.indexed
-
-            def chunk_body(params, tokens, offset, n_valid, table,
-                           kpool, vpool, *ipool):
-                return tfm.chunk_local(
-                    params, tokens, offset, n_valid, table, kpool, vpool,
-                    ipool[0] if indexed else None, cfg, tp, comm=comm)
-
-            pools = (self._pool_spec,) * 2 + ((P(),) if indexed else ())
+            pools = (self._pool_spec,) * 2 + ((P(),) if self.indexed else ())
             self._chunk_prog = jax.jit(smap(
-                chunk_body, self.mesh,
+                chunk_body_of(cfg, tp, comm), self.mesh,
                 in_specs=(self.specs, P(), P(), P(), P()) + pools,
                 out_specs=(P(), P()) + pools, check=False,
             ), donate_argnums=tuple(range(5, 5 + len(pools))))
             return
 
         def prefill_body(params, tokens, length):
-            return tfm.prefill_local(params, tokens, length, cfg, tp,
-                                     comm=comm)
+            logits, k, v = tfm.prefill_local(params, tokens, length, cfg, tp,
+                                             comm=comm)
+            return _greedy(logits), logits, k, v
 
         self._prefill = jax.jit(smap(
             prefill_body, self.mesh,
             in_specs=(self.specs, P(), P()),
-            out_specs=(P(), kv_spec, kv_spec),
+            out_specs=(P(), P(), kv_spec, kv_spec),
             check=False,
         ))
 
@@ -319,45 +358,22 @@ class InferenceEngine:
         prog = self._decode_cache.get(dtype)
         if prog is not None:
             return prog
-        cfg, tp, comm = self.cfg, self.tp, self.comm
-
+        pool, scale = self._pool_spec, self._scale_spec
         if self.quant:
-            def decode_body(params, slots, live,
-                            kpool, vpool, kscale, vscale):
-                return tfm.decode_local(
-                    params, slots, live, kpool, vpool, cfg, tp,
-                    comm=comm, dtype=dtype, kscale=kscale, vscale=vscale)
-
-            in_specs = (self.specs, P(), P(), self._pool_spec,
-                        self._pool_spec, self._scale_spec, self._scale_spec)
-            out_specs = (P(), self._pool_spec, self._pool_spec,
-                         self._scale_spec, self._scale_spec)
-            donate = (3, 4, 5, 6)
+            names, more = ("kscale", "vscale"), (scale, scale)
         elif self.indexed:
-            def decode_body(params, slots, tables, kpool, vpool, ipool):
-                return tfm.decode_local(
-                    params, slots, tables, kpool, vpool, cfg, tp,
-                    comm=comm, dtype=dtype, ipool=ipool)
-
-            in_specs = (self.specs, P(), P(),
-                        self._pool_spec, self._pool_spec, P())
-            out_specs = (P(), self._pool_spec, self._pool_spec, P(), P())
-            donate = (3, 4, 5)
+            names, more = ("ipool",), (P(),)
         else:
-            def decode_body(params, slots, live, kpool, vpool):
-                return tfm.decode_local(
-                    params, slots, live, kpool, vpool, cfg, tp,
-                    comm=comm, dtype=dtype)
-
-            in_specs = (self.specs, P(), P(),
-                        self._pool_spec, self._pool_spec)
-            out_specs = (P(), self._pool_spec, self._pool_spec)
-            donate = (3, 4)
-
+            names = more = ()
+        in_specs = (self.specs, P(), P(), pool, pool) + more
+        # the tokens, the pools, and the expert counts of a model that has them
+        out_specs = (P(), pool, pool) + more \
+            + ((P(),) if self.indexed else ())
         prog = jax.jit(
-            smap(decode_body, self.mesh, in_specs=in_specs,
-                 out_specs=out_specs, check=False),
-            donate_argnums=donate,
+            smap(decode_body_of(self.cfg, self.tp, self.comm, dtype, names),
+                 self.mesh, in_specs=in_specs, out_specs=out_specs,
+                 check=False),
+            donate_argnums=tuple(range(3, len(in_specs))),
         )
         self._decode_cache[dtype] = prog
         return prog
@@ -597,16 +613,13 @@ class InferenceEngine:
             + ((self.ipool,) if self.indexed else ())
         out = self._chunk_prog(self.params, tokens, np.int32(at), np.int32(n),
                           table, *pools)
-        logits, counts = out[:2]
         self.kpool, self.vpool = out[2:4]
         if self.indexed:
             self.ipool = out[4]
-        # the counts come back with the logits; a chunk that is not the last
-        # has no use for its logits and leaves them on the device
-        if last:
-            logits, counts = jax.device_get((logits, counts))
-        else:
-            counts = np.asarray(counts)
+        # blocks until the chunk is done: the expert counts come back, and
+        # with them the last chunk's token, which is the first token
+        tok, counts = jax.device_get(out[:2]) if last \
+            else (None, np.asarray(out[1]))
         seq.filled += n
         seq.chunks += 1
         stats.record_serve("prefill_chunks")
@@ -617,11 +630,10 @@ class InferenceEngine:
                 experts_hit=int(counts[0]), expert_tokens=int(counts[1]))
         if not last:
             return
-        tok = int(np.argmax(logits))
         t_first = tr.complete("serve.first_token", "serve", t0, step=step,
                               req=req.id) \
             if tr is not None else time.perf_counter_ns()
-        self._first_token(seq, tok, t_first)
+        self._first_token(seq, int(tok), t_first)
 
     def _first_token(self, seq: _Seq, tok: int, t_first: int) -> None:
         """A prefilled sequence has its first token: count it, time it, and
@@ -653,7 +665,7 @@ class InferenceEngine:
         n = int(prefix.size)
         tokens = np.zeros((self.ctx_len,), np.int32)
         tokens[:n] = prefix
-        logits, k, v = self._prefill(
+        tok, _, k, v = self._prefill(
             self.params, jnp.asarray(tokens), jnp.int32(n))
         if tr is not None:
             t0 = tr.complete("serve.prefill", "serve", t0, step=step, req=rid)
@@ -669,7 +681,7 @@ class InferenceEngine:
         if tr is not None:
             t0 = tr.complete("serve.kv_write", "serve", t0, step=step,
                              req=rid, pages=self.cache.pages_for(n + 1))
-        tok = int(np.argmax(np.asarray(logits)))
+        tok = int(tok)      # blocks until the prefill is done: 4 bytes
         t_first = tr.complete("serve.first_token", "serve", t0, step=step,
                               req=rid) \
             if tr is not None else time.perf_counter_ns()
@@ -792,15 +804,16 @@ class InferenceEngine:
             if tr is not None else t_try
         counts = None
         if self.quant:
-            logits, self.kpool, self.vpool, self.kscale, self.vscale = out
+            tokens, self.kpool, self.vpool, self.kscale, self.vscale = out
         elif self.indexed:
-            logits, self.kpool, self.vpool, self.ipool, counts = out
+            tokens, self.kpool, self.vpool, self.ipool, counts = out
         else:
-            logits, self.kpool, self.vpool = out
-        # blocks until the step is done; the expert counts ride with it
-        logits, counts = jax.device_get((logits, counts))
+            tokens, self.kpool, self.vpool = out
+        # blocks until the step is done: a token a slot comes back, and the
+        # expert counts with it
+        tokens, counts = jax.device_get((tokens, counts))
         t0 = tr.complete("serve.decode.wait", "serve", t0, step=step,
-                         bytes=logits.nbytes) \
+                         bytes=tokens.nbytes) \
             if tr is not None else time.perf_counter_ns()
         step_ms = (t0 - t_try) / 1e6
         self._decode_fails = 0
@@ -815,7 +828,7 @@ class InferenceEngine:
         self._tokens_total += len(live)
         tokens_live = 0
         for seq in live:
-            tok = int(np.argmax(logits[seq.slot]))
+            tok = int(tokens[seq.slot])
             tokens_live += seq.position + 1
             seq.position += 1
             seq.last_token = tok
@@ -898,7 +911,7 @@ def oracle_logits(engine: InferenceEngine, seq) -> np.ndarray:
     seq = np.asarray(seq, np.int32).reshape(-1)
     tokens = np.zeros((engine.ctx_len,), np.int32)
     tokens[:seq.size] = seq
-    logits, _, _ = engine._prefill(
+    _, logits, _, _ = engine._prefill(
         engine.params, jnp.asarray(tokens), jnp.int32(seq.size))
     return np.asarray(logits)
 

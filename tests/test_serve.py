@@ -222,6 +222,116 @@ def test_int8_paged_decode_within_tolerance(env):
     eng.close()
 
 
+# -- the greedy choice, on the device ------------------------------------------
+
+
+def _indexed_cfg():
+    """A small block with grouped-query heads under an indexer and dropless
+    experts: the engine prefills it by chunks and decodes through tables."""
+    return TransformerConfig(
+        vocab=64, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8, n_blocks=2,
+        seq_len=64, dtype="float32", norm="rms", positions="rope",
+        qk_norm=True, mlp="experts", n_experts=4, moe_top_k=2,
+        expert_width=16, index_heads=2, index_dim=8, index_topk=8)
+
+
+@pytest.mark.parametrize("head", ["drawn", "two_equal_maxima", "nan"])
+@pytest.mark.parametrize("pools", ["f32_pool", "int8_pool", "indexed"])
+def test_the_programs_choose_the_token_the_hosts_argmax_chose(
+        env, monkeypatch, pools, head):
+    """The engine's programs return tokens, not logits, and the tokens are
+    those of ``np.argmax``, the oracles' rule: on the slots and pools of
+    every step of a short run the decode program's output is exactly
+    ``np.argmax(decode_local's logits, axis=-1)`` for every slot (live or
+    not), the chunk program's the argmax of ``chunk_local``'s, the prefill's
+    the argmax of the oracle's; among equal maxima the lower index wins, a
+    NaN wins over every number; and what a request is served is that chain."""
+    import jax
+    import jax.numpy as jnp
+
+    from mlsl_tpu.models import transformer as tfm
+    from mlsl_tpu.serve.engine import oracle_logits
+
+    indexed, quant = pools == "indexed", pools == "int8_pool"
+    cfg = _indexed_cfg() if indexed else _cfg()
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    w = params["final"]["head"]
+    if head == "two_equal_maxima":
+        # columns 5 and 9 alike and zeros elsewhere: a row of logits holds
+        # its maximum at 5 and 9, or (their product negative) at the 62 zeros
+        w = jnp.zeros_like(w).at[:, 5].set(w[:, 5]).at[:, 9].set(w[:, 5])
+    elif head == "nan":
+        w = w.at[:, 7].set(jnp.nan)
+    params["final"]["head"] = w
+    eng = serve.InferenceEngine(
+        env, cfg, tp=1, params=params, max_batch=4,
+        config=dataclasses.replace(env.config, serve_kv_quant=quant),
+        prefill_chunk=8 if indexed else None)
+
+    names = ("kscale", "vscale") if quant else ("ipool",) if indexed else ()
+    decode_logits = jax.jit(lambda p, slots, live, k, v, *more: tfm.decode_local(
+        p, slots, live, k, v, cfg, 1, **dict(zip(names, more)))[0])
+    chunk_logits = jax.jit(lambda *args: tfm.chunk_local(*args, cfg, 1)[0])
+    steps, chunks, chain = [], [], {}
+    compiled = eng._decode_prog
+
+    def decode_prog(dtype):
+        prog = compiled(dtype)
+
+        def call(*args):
+            logits = np.asarray(decode_logits(*args))   # the pools are donated
+            out = prog(*args)
+            steps.append((logits, np.asarray(out[0])))
+            for seq in eng._active.values():
+                if not seq.prefilling:
+                    chain.setdefault(seq.req.id, []).append(
+                        int(np.argmax(logits[seq.slot])))
+            return out
+        return call
+
+    def chunk_prog(*args):
+        logits = np.asarray(chunk_logits(*args))
+        out = chunk(*args)
+        chunks.append((int(args[2]) + int(args[3]), logits, out[0]))
+        return out
+
+    monkeypatch.setattr(eng, "_decode_prog", decode_prog)
+    if indexed:
+        chunk = eng._chunk_prog
+        monkeypatch.setattr(eng, "_chunk_prog", chunk_prog)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab, size=n).astype(np.int32)
+               for n in (5, 11, 18)]
+    reqs = [eng.submit(p, 5) for p in prompts]
+    eng.run()
+    assert all(r.state == "done" and len(r.tokens) == 5 for r in reqs)
+
+    assert len(steps) >= 4
+    for logits, tokens in steps:
+        assert tokens.dtype == np.int32 and tokens.shape == (eng.max_batch,)
+        np.testing.assert_array_equal(tokens, np.argmax(logits, axis=-1))
+        for row, tok in zip(logits, tokens):
+            if head == "two_equal_maxima":
+                best = np.flatnonzero(row == row.max())
+                assert len(best) >= 2 and tok == best[0]
+                assert row[5] == row[9]
+            elif head == "nan":
+                assert tok == 7 and np.isnan(row[7])
+    if indexed:
+        for _, logits, tok in chunks:
+            assert tok.dtype == jnp.int32 and tok.shape == ()
+            assert int(tok) == int(np.argmax(logits))
+        firsts = [int(tok) for end, _, tok in chunks if end in (5, 11, 18)]
+    else:
+        firsts = [int(np.argmax(oracle_logits(eng, p))) for p in prompts]
+    assert [r.tokens[0] for r in reqs] == firsts
+    assert [r.tokens[1:] for r in reqs] == [chain[r.id] for r in reqs]
+    if head == "two_equal_maxima":
+        # both kinds of tie were met: 5 before 9, and 0 before the other zeros
+        assert {int(t) for _, toks in steps for t in toks} == {0, 5}
+    eng.close()
+
+
 # -- paged KV cache invariants ------------------------------------------------
 
 
@@ -617,7 +727,8 @@ def test_serve_spans_on_timeline(env, monkeypatch, evict):
         assert a["inflight"] <= a["tokens_live"] <= a["pages_held"] * 16
     waits = [e for e in spans if e[NAME] == "serve.decode.wait"]
     assert len(waits) == len(decodes)
-    assert all(e[ARGS]["bytes"] == eng.max_batch * cfg.vocab * 4 for e in waits)
+    # what comes back a step: an int32 a slot, whatever the vocabulary
+    assert all(e[ARGS]["bytes"] == eng.max_batch * 4 for e in waits)
     evicted = sum(e[ARGS]["evicted"] for e in spans
                   if e[NAME] == "serve.capacity")
     assert evicted == stats.SERVE_COUNTERS["kv_evictions"]

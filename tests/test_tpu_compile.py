@@ -39,16 +39,29 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+def tokens_and_pools_only(lowered, mem, slots, vocab):
+    """What a serving program returns beside the pools it updates in place
+    does not follow the vocabulary: int32 tokens first (``slots`` of them, a
+    scalar for a chunk), no float32 result of the logits' size anywhere: a
+    (32, 50257) one is 6.4 MB a step for the host to read back."""
+    first, *rest = jax.tree.leaves(lowered.out_info)
+    assert first.dtype == jnp.int32 and first.shape in ((slots,), ())
+    assert all(vocab not in leaf.shape for leaf in rest)
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 4096, mem
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["f32_pool", "int8_pool"])
 def test_decode_program_reads_and_writes_the_pool_in_place(
         one_chip, no_compile_cache, quant):
     """GPT-2 medium's decode step over a 2 GiB pool, as the serving cell
-    runs it: the pools alias their outputs, and nothing the program keeps
-    beside them is of the pool's size (the layer's slice made a buffer, the
-    relayout before a gather and the gathered context of the dense form
-    were 6.5 GB here)."""
+    runs it (the engine's own body): the pools alias their outputs, nothing
+    the program keeps beside them is of the pool's size (the layer's slice
+    made a buffer, the relayout before a gather and the gathered context of
+    the dense form were 6.5 GB here), and what it returns beside them is a
+    token a slot, not 6.4 MB of logits for the host to read back."""
     from mlsl_tpu.models import transformer as tfm
     from mlsl_tpu.ops import paged_attention
+    from mlsl_tpu.serve import engine
 
     cfg = tfm.TransformerConfig(
         vocab=50257, d_model=1024, n_heads=16, head_dim=64, n_blocks=24,
@@ -69,32 +82,33 @@ def test_decode_program_reads_and_writes_the_pool_in_place(
     scale = shape((cfg.n_blocks, pages, cfg.n_heads * page), jnp.float32)
     pools = (pool, pool) + ((scale, scale) if quant else ())
 
-    def decode_body(params, slots, live, kpool, vpool, *scales):
-        return tfm.decode_local(params, slots, live, kpool, vpool, cfg, 1,
-                                **dict(zip(("kscale", "vscale"), scales)))
-
-    compiled = jax.jit(
-        decode_body, donate_argnums=tuple(range(3, 3 + len(pools))),
+    lowered = jax.jit(
+        engine.decode_body_of(cfg, 1, None, None,
+                              ("kscale", "vscale") if quant else ()),
+        donate_argnums=tuple(range(3, 3 + len(pools))),
     ).lower(params, shape((3, batch), jnp.int32), shape((3, cap), jnp.int32),
-            *pools).compile()
-    mem = compiled.memory_analysis()
+            *pools)
+    mem = lowered.compile().memory_analysis()
     pool_bytes = sum(jnp.dtype(p.dtype).itemsize * math.prod(p.shape)
                      for p in pools)
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 16, mem
+    tokens_and_pools_only(lowered, mem, batch, cfg.vocab)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_indexer_programs_keep_three_pools_in_place(
         one_chip, no_compile_cache, program):
-    """The decode step and the prefill chunk of the configuration with an
-    indexer (perf/configs/keye-vl2-30b-a3b-pp8.json: 32 query heads over 4
-    key-value heads, 16 x 64 indexer with topk 2,048, 128 experts, bfloat16
-    weights and pools) at the serving cell's sizes: K, V and the index keys
-    alias their outputs, the temporaries do not grow with the pool (1 and 3
-    GiB compile to the same), and weights, pool and temporaries fit the
-    chip. The first trace of PR 29 showed what a 64-lane index pool costs:
-    the compiler laid the whole pool out anew around every layer's gather."""
+    """The decode step and the prefill chunk (the engine's own bodies) of
+    the configuration with an indexer (perf/configs/keye-vl2-30b-a3b-pp8.json:
+    32 query heads over 4 key-value heads, 16 x 64 indexer with topk 2,048,
+    128 experts, bfloat16 weights and pools) at the serving cell's sizes: K,
+    V and the index keys alias their outputs, the temporaries do not grow
+    with the pool (1 and 3 GiB compile to the same), weights, pool and
+    temporaries fit the chip, and beside the pools come tokens and two
+    counts, no logits. The first trace of PR 29 showed what a 64-lane index
+    pool costs: the compiler laid the whole pool out anew around every
+    layer's gather."""
     import pathlib
     import sys
 
@@ -104,6 +118,7 @@ def test_indexer_programs_keep_three_pools_in_place(
     from perf.lib import manifest
 
     from mlsl_tpu.models import transformer as tfm
+    from mlsl_tpu.serve import engine
 
     config = manifest.read_json(
         manifest.PERF / "configs" / "keye-vl2-30b-a3b-pp8.json")
@@ -132,23 +147,18 @@ def test_indexer_programs_keep_three_pools_in_place(
         pools = (kv, kv, shape((cfg.n_blocks, pages, page, cfg.index_row),
                                jnp.bfloat16))
         if program == "decode":
-            def body(params, slots, tables, kpool, vpool, ipool):
-                return tfm.decode_local(params, slots, tables, kpool, vpool,
-                                        cfg, 1, ipool=ipool)
-
+            body = engine.decode_body_of(cfg, 1, None, None, ("ipool",))
             args = (shape((3, batch), jnp.int32),
                     shape((batch, table), jnp.int32))
         else:
-            def body(params, tokens, offset, n_valid, table_, kpool, vpool,
-                     ipool):
-                return tfm.chunk_local(params, tokens, offset, n_valid,
-                                       table_, kpool, vpool, ipool, cfg, 1)
-
+            body = engine.chunk_body_of(cfg, 1, None)
             args = (shape((chunk,), jnp.int32), shape((), jnp.int32),
                     shape((), jnp.int32), shape((table,), jnp.int32))
         n = 1 + len(args)
-        mem = jax.jit(body, donate_argnums=(n, n + 1, n + 2)).lower(
-            params, *args, *pools).compile().memory_analysis()
+        lowered = jax.jit(body, donate_argnums=(n, n + 1, n + 2)).lower(
+            params, *args, *pools)
+        mem = lowered.compile().memory_analysis()
+        tokens_and_pools_only(lowered, mem, batch, cfg.vocab)
         return mem, sum(jnp.dtype(p.dtype).itemsize * math.prod(p.shape)
                         for p in pools)
 
