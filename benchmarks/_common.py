@@ -1,5 +1,5 @@
-"""Shared helpers for the benchmark scripts: one synchronisation primitive,
-two host-clock timers and the analytic FLOP count.
+"""Shared helpers for the measurement tools kept beside the benchmark
+(``perf/``): one synchronisation primitive and two host-clock timers.
 
 A bench process imports JAX itself and fails loudly if the chip cannot be had:
 a chip belongs to one process, so nothing here probes it from a child first.
@@ -45,27 +45,6 @@ def device_sync(tree):
     return float(np.asarray(jax.numpy.ravel(leaves[0])[0]))
 
 
-def model_flops(cfg, batch):
-    """Analytic model FLOPs per train step (fwd + bwd = 3x fwd, the standard
-    MFU denominator): per token per block 8*d*ad qkvo (ad = n_heads*head_dim,
-    which the config does NOT require to equal d_model) + 4*mlp_ratio*d^2 MLP
-    matmul FLOPs + 2*S*ad causal attention (4*S*ad full halved by the mask),
-    plus the 2*d*V head. Unlike the executed-program cost model this does NOT
-    count remat recompute, so remat variants' mfu_model is comparable: a
-    faster wall clock is a higher mfu_model, full stop. Returns None for MoE
-    configs (active FLOPs depend on routing/capacity; the executed-program
-    row is the honest one there)."""
-    if cfg.n_experts > 0:
-        return None
-    t = batch * cfg.seq_len
-    d = cfg.d_model
-    ad = cfg.n_heads * cfg.head_dim
-    per_tok_blk = (8 * d * ad + 4 * cfg.mlp_ratio * d * d
-                   + 2 * cfg.seq_len * ad)
-    fwd = t * (cfg.n_blocks * per_tok_blk + 2 * d * cfg.vocab)
-    return 3.0 * fwd
-
-
 def timed_scan(step, carry0, iters=100, blocks=5):
     """Per-iteration ms for a carry→carry `step`, executed as a lax.scan
     inside ONE device computation, using a PAIRED-length estimate: best time
@@ -74,7 +53,7 @@ def timed_scan(step, carry0, iters=100, blocks=5):
     that is, from a sub-millisecond kernel's time. Blocks alternate
     short/long so slow drift hits both arms equally. The carry dependency
     serializes iterations and defeats CSE; callers must make `step` keep its
-    values bounded. (Whether the pairing survives is ROADMAP S2's call.)"""
+    values bounded."""
     import time
 
     import jax
@@ -105,7 +84,7 @@ def timed(fn, *args, iters=30, warmup=5, blocks=3):
     the reported value is (best_2K - best_K) / K, which removes the fixed
     cost of one sync from the per-call time (it does NOT remove per-call
     dispatch cost: both arms pay it per call). Minima across rounds are
-    taken per arm. (Whether the pairing survives is ROADMAP S2's call.)"""
+    taken per arm."""
     import time
 
     r = fn(*args)  # also covers warmup=0: r must exist for the first sync

@@ -14,9 +14,10 @@ Usage: JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
        python benchmarks/algo_sweep_bench.py [--smoke] [--quant] \\
               [--profile-out PATH]
 
---smoke trims sizes/iters for the tier-1 wiring (tests/test_algos.py, the
-``bench_smoke`` marker). Full sweeps (default sizes up to 8 MiB plus the
-quant-block cell) belong to the standalone/capture run.
+--smoke trims sizes/iters for tests/test_algos.py (which holds the profile's
+round trip and the parity row, never a time) and scripts/run_tune.sh. Full
+sweeps (default sizes up to 8 MiB plus the quant-block cell) are for the
+chip: the per-lowering curve ROADMAP S8 runs on four chips.
 """
 
 import argparse
@@ -27,9 +28,8 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-# smoke stays small on purpose: the tier-1 budget is tight, the non-default
-# selections live at latency-bound sizes, and the bandwidth tail belongs to
-# the full (standalone/capture) run
+# smoke stays small on purpose: the tier-1 budget is tight, and the
+# bandwidth tail belongs to the full run on the chip
 SMOKE_SIZES = (4 * 1024, 64 * 1024)
 FULL_SIZES = (16 * 1024, 128 * 1024, 1024 * 1024, 8 * 1024 * 1024)
 
@@ -55,7 +55,7 @@ def main():
     sizes = SMOKE_SIZES if args.smoke else FULL_SIZES
     iters = args.iters or (3 if args.smoke else 7)
     # an explicit --quant is honored even in smoke mode (run_tune.sh passes
-    # it through); the tier-1 smoke wiring simply doesn't ask for it
+    # it through)
     quant = args.quant
 
     prof = tuner.run_sweep(sizes=sizes, iters=iters, quant=quant)

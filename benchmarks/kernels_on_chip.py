@@ -131,10 +131,16 @@ def main():
     dr = jax.jit(lambda q, s: qk.dequantize_blocks_ref(q, s))
     qv, s = qp(x)
     qv_r, s_r = qr(x)
-    q_ok = (bool(jnp.all(qv == qv_r)) and bool(jnp.all(s == s_r)))
+    # the two quantizers may differ by one ulp in a scale or by one code at a
+    # rounding tie (round and divide are ordered differently): hold what they
+    # decode to within one quantization step (both at once read a hair over
+    # 1.0, hence the 1e-3), and record the measured distance in steps, never
+    # a made-up 0 or 1
+    deq = dr(qv, s)
+    q_err = float(jnp.max(jnp.abs(deq - dr(qv_r, s_r)) / s_r[:, None]))
     # same (qv, s) on both sides: isolates the dequant kernel under test from
     # any one-ulp quantizer divergence
-    err = float(jnp.max(jnp.abs(dp(qv, s) - dr(qv, s))))
+    err = float(jnp.max(jnp.abs(dp(qv, s) - deq)))
 
     def _t(f, *a):
         # iters=1800 -> 200-call arms (~100 ms paired diff on a ~0.5 ms
@@ -147,8 +153,8 @@ def main():
         return None
 
     p_ms, x_ms = _t(qp, x), _t(qr, x)
-    results.append(_row("quant_int8_256MiB", q_ok,
-                              0.0 if q_ok else 1.0, p_ms, x_ms))
+    results.append(_row("quant_int8_256MiB", q_err <= 1.0 + 1e-3,
+                        round(q_err, 6), p_ms, x_ms))
     p_ms = _t(dp, qv, s)
     x_ms = _t(dr, qv, s)
     results.append(_row("dequant_int8_256MiB", err < 1e-6,

@@ -49,7 +49,7 @@ class Sizes:
     rn_batch: int = 256
     rn_hw: int = 224
     rn_classes: int = 1000
-    rn_lr: float = 0.05                      # bench.py's; falls on one batch
+    rn_lr: float = 0.05                      # falls on one repeated batch
     tfm: dict = dataclasses.field(default_factory=lambda: dict(
         vocab=32768, d_model=1024, n_heads=16, head_dim=64, n_blocks=12,
         seq_len=2048))                       # gpt-medium-2k
@@ -209,7 +209,7 @@ class Smoke:
             lr=self.sizes.rn_lr, **kw)
 
     def resnet_feed(self, trainer):
-        """The device feed as bench.py builds it: uint8 wire, HBM cache, and
+        """The device feed as the ResNet cell builds it: uint8 wire, HBM cache, and
         one distinct batch replayed for ever (the repeated batch P1 needs)."""
         from mlsl_tpu.data import synthetic_source
 
@@ -230,7 +230,7 @@ class Smoke:
     def train_resnet(self, trainer, steps, sync_each_step=True):
         """-> per-step mean losses. The loss is read back after the loop
         unless sync_each_step: M1 leaves three steps of per-layer collectives
-        un-awaited on purpose (the wedge bench.py records on the CPU mesh)."""
+        un-awaited on purpose (on the CPU mesh that wedges, KNOWN_FAILURES.md)."""
         import numpy as np
 
         loader = self.resnet_feed(trainer)
@@ -516,7 +516,7 @@ class Smoke:
               f"large_msg_size_mb={cfg.large_msg_size_mb} "
               f"large_msg_chunks={cfg.large_msg_chunks}")
 
-        # the fused oracle: bench.py's single raw-JAX program (loss + grad +
+        # the fused oracle: a single raw-JAX program (loss + grad +
         # SGD, no framework), written per shard because the trainer's batch
         # norm is per device (models/resnet.py) — a GSPMD jit over the global
         # batch would normalise over all 256 images and be another model
@@ -571,7 +571,7 @@ class Smoke:
             o_losses, losses = [], [trainer.step((xb, yb))]
             for _ in range(2):
                 # un-awaited on the chip: three steps of per-layer
-                # collectives in flight is the wedge bench.py met on the CPU
+                # collectives in flight wedge XLA:CPU's rendezvous on the CPU
                 # mesh, where the dry run therefore awaits every step
                 if self.tiny:
                     jax.block_until_ready(losses[-1])

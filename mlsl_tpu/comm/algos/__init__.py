@@ -437,7 +437,7 @@ def inline_plan(kind: str, group: ProcessGroup, algo: str, count: int, *,
     rop = ReductionType(op) if op is not None else ReductionType.SUM
     if group.is_self or group.size <= 1:
         # degenerate group: every reduction is the identity (the compiled
-        # per-layer schedule is still measurable — bench.py's single-chip row)
+        # per-layer schedule still builds and runs on one chip)
         if kind == "reduce_scatter" and recv_count is not None:
             return (lambda x, mypos: (x, mypos), [],
                     lambda carry: carry[0][:recv_count])

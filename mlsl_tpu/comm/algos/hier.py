@@ -479,7 +479,8 @@ def quant_body(
 
 
 # ---------------------------------------------------------------------------
-# Cost model (benchmarks/hier_bench.py DCN bandwidth-delay simulator)
+# Cost model: bytes and round trips one hier allreduce puts on a DCN link
+# (no caller since PR 32 removed the CPU-mesh simulator; ROADMAP D3)
 # ---------------------------------------------------------------------------
 
 
@@ -496,7 +497,7 @@ def dcn_wire_bytes(count: int, tiers: Tuple[int, int], codec: str,
         per = slen * 1 + 4 * (slen // block)  # q + the shared-scale pmax
     elif codec == "topk":
         per = slen * 4  # dense psum carries the masked shard (sim mesh)
-    elif codec not in ("f32", "none"):  # "none" = hier_bench's uncompressed
+    elif codec not in ("f32", "none"):  # "none" = an uncompressed DCN tier
         from mlsl_tpu import codecs as codecs_mod
         per = codecs_mod.configure(codec).wire_len(slen)  # encoded shard
     else:

@@ -1,10 +1,9 @@
 """Compiled overlap engine: in-graph per-layer gradient collectives.
 
 The host per-layer path (models/train.py ``_sync_and_update``) dispatches one
-XLA executable per layer collective and overlaps them with host polling —
-which BENCH_r05 showed gains nothing over the fused monolithic jit on a real
-chip (``per_layer_vs_fused: 1.0``): the comm schedule lives on the host,
-where XLA's latency-hiding scheduler cannot see it. This module moves the
+XLA executable per layer collective and overlaps them with host polling:
+the comm schedule lives on the host, where XLA's latency-hiding scheduler
+cannot see it. This module moves the
 schedule INTO the compiled program (the PyTorch-DDP finding, PAPERS.md:
 overlap only pays when the compiler/scheduler owns the comm stream):
 

@@ -171,8 +171,8 @@ class CommRequest:
         # effective int8 block (desc override > calibration cell > config):
         # the A112 geometry check must model THIS, not the session block
         self._eff_quant_block = 0
-        # per-Start hot-path constants (VERDICT r4 item 3: keep the host
-        # dispatch floor low — no per-dispatch string building / re-derivation)
+        # per-Start hot-path constants: keep the host dispatch floor low —
+        # no per-dispatch string building / re-derivation
         self._trace_name = f"mlsl:{desc.kind}:{name or self.uid}"
         self._payload = desc.payload_bytes()
         # watchdog stamp: monotonic Start time of the current in-flight epoch
@@ -520,7 +520,7 @@ class CommRequest:
             self._degrade_subsys = "algo"
             self._lax_build = (dtype, lax_kw)
         # hot-path precomputation: the per-layer dispatch floor must stay in
-        # single-digit µs (VERDICT r4 item 3), so nothing re-derived per Start
+        # single-digit µs, so nothing re-derived per Start
         self._single_full = (
             len(self._chunk_slices) == 1 and self._chunk_slices[0] == slice(None)
         )

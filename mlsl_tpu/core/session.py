@@ -440,8 +440,8 @@ class Session:
     def _stat_event(self, entity, action: str, is_param: bool = False, is_increment: bool = False):
         # Gate on started, not the env flag: MLSL_STATS drives the default via
         # initialize(), but Statistics.start() must also work programmatically
-        # (reference Statistics::Start, include/mlsl.hpp:662) — bench.py turns
-        # accounting on for a few un-timed steps to emit the overlap fraction.
+        # (reference Statistics::Start, include/mlsl.hpp:662) — a caller may
+        # turn accounting on for a few steps only.
         if self.stats.is_started():
             self.stats.update(entity, action, is_param, is_increment)
 

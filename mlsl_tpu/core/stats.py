@@ -12,8 +12,7 @@ src/mlsl_impl_stats.cpp):
 - Isolation benchmark at Commit: every registered comm request is replayed
   ISOLATION_ITERS times (first ISOLATION_SKIP discarded) with compute off, using zero
   buffers, giving the pure-communication time per iteration (reference
-  CollectIsolationStats :387-562, iters/skip hardcoded :48-49). This doubles as the
-  algbw-vs-size measurement harness used by bench.py.
+  CollectIsolationStats :387-562, iters/skip hardcoded :48-49).
 
 - Table printer to mlsl_stats.log (reference :226-363).
 """

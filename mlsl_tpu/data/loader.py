@@ -12,7 +12,7 @@ transfers/decodes are already dispatched — the worker blocks (backpressure)
 once that many are in flight, so HBM use is bounded at depth x batch bytes.
 Both sides of the queue are accounted: time the CONSUMER blocks on an empty
 queue is input stall (the number the feed pipeline exists to drive to zero,
-surfaced as ``input_stall_ms`` on the bench row), time the WORKER blocks on
+the benchmark's ``input_stall_ms_per_step``), time the WORKER blocks on
 a full queue is healthy backpressure. Both land in ``FEED_COUNTERS``.
 
 Failure contract: a worker that dies mid-epoch surfaces its ORIGINAL

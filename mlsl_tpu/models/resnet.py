@@ -68,9 +68,8 @@ def _bn(x, p, eps=1e-5):
     # Folded BN, one-pass statistics: mean and E[x^2] accumulate in f32 off
     # the bf16 input in a SINGLE read of the activation (XLA fuses both
     # reductions into one convert_reduce pass). The centered two-pass form
-    # read every activation twice — BN-stat traffic dominated the profiled
-    # step (benchmarks/profile_step.py: 19.7 ms of 50.5 at batch 128 on v5e);
-    # one-pass cut the measured train step 58.8 -> 49.2 ms. E[x^2]-E[x]^2 can
+    # read every activation twice (the step's device-op table is
+    # `perf/run.py --workload resnet50-1chip --trace 1`). E[x^2]-E[x]^2 can
     # cancel to a small negative on near-constant channels, so the variance
     # is clamped at 0 — normalization then degrades to rsqrt(eps)-scaling,
     # exactly what true-variance BN does on such channels (flax BatchNorm's

@@ -382,7 +382,7 @@ class DataParallelTrainer:
         self._stall_ms_seen = 0.0   # FEED stall total at the last sample
         # force_graph_path bypasses the fused shortcut so the per-layer
         # Start/Wait machinery can be measured even when no comm is needed
-        # (bench.py times it against the fused program on one chip). An
+        # (chip_smoke.py P1 trains both and compares them on one chip). An
         # armed quality gate does the same: the gate screens at the
         # gradient boundary, which the fused program never exposes.
         use_fused = (

@@ -972,8 +972,8 @@ class HybridTrainer:
     def compiled_step(self, tokens, labels):
         """Lower+compile the fused train step for (tokens, labels) and return
         the jax Compiled object (cost_analysis, memory_analysis, as_text) —
-        the profiling surface for benchmarks. None on the per-layer graph
-        path, where the step is many programs, not one."""
+        what chip_smoke.py reads the step's program text from. None on the
+        per-layer graph path, where the step is many programs, not one."""
         if self._fused_fn is None:
             return None
         if self.optimizer is None:

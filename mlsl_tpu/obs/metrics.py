@@ -29,7 +29,7 @@ series, appended to ``mlsl_metrics.jsonl`` under ``MLSL_STATS_DIR`` on each
 sampler tick; ``scripts/trace_view.py --metrics`` summarizes the file).
 
 Hot-path contract (the tracer/chaos precedent, pinned by tracemalloc in
-tests/test_metrics.py and benchmarks/metrics_overhead_bench.py):
+tests/test_metrics.py):
 instrumented code reads the module global once per operation —
 ``m = metrics._registry`` / ``if m is not None:`` — so the disabled path is
 ONE attribute load and a None test with zero allocations. Series internals

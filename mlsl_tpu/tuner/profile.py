@@ -57,18 +57,18 @@ KNOB_RANGES = {
     # over; an exported MLSL_OVERLAP_STAGES always wins
     "overlap_stages": 1,
     # feed-pipeline prefetch depth (mlsl_tpu.data): profiles may carry the
-    # depth benchmarks/input_pipeline_bench.py measured best for this
-    # machine's h2d link; an exported MLSL_FEED_DEPTH always wins
+    # depth measured best for this machine's h2d link (a training cell's
+    # `input_stall_ms_per_step`, perf/); an exported MLSL_FEED_DEPTH
+    # always wins
     "feed_depth": 1,
     # integrity-sentinel audit interval (mlsl_tpu.sentinel): profiles may
-    # carry the interval benchmarks/sentinel_overhead_bench.py measured to
-    # keep gate+audit overhead under its budget on this machine; an
-    # exported MLSL_SENTINEL_EVERY always wins (0 = audit off)
+    # carry the interval measured to keep gate+audit overhead under its
+    # budget on this machine; an exported MLSL_SENTINEL_EVERY always wins
+    # (0 = audit off)
     "sentinel_every": 0,
     # telemetry sampler cadence (obs/metrics.py): profiles may carry the
-    # cadence benchmarks/metrics_overhead_bench.py measured to keep the
-    # armed-path cost under its 2% budget on this machine; an exported
-    # MLSL_METRICS_EVERY always wins
+    # cadence measured to keep the armed-path cost under its budget on
+    # this machine; an exported MLSL_METRICS_EVERY always wins
     "metrics_every": 1,
     # straggler audit window (obs/straggler.py): an exported
     # MLSL_STRAGGLER_EVERY always wins; floor = the judgeable minimum
@@ -87,9 +87,9 @@ KNOB_RANGES = {
     "vq_codebook": 2,
     "prune_ratio": 1e-4,
     # serving decode-slot ceiling (serve/engine.py): profiles may carry the
-    # batch benchmarks/serving_bench.py measured to maximize tokens/s while
-    # holding p99 TPOT on this chip; an exported MLSL_SERVE_MAX_BATCH
-    # always wins
+    # batch measured to maximize tokens/s while holding the inter-token
+    # latency on this chip (perf/sweep.py walks a serving cell's rate);
+    # an exported MLSL_SERVE_MAX_BATCH always wins
     "serve_max_batch": 1,
     # KV page granularity in tokens (serve/kv_cache.py): profiles may carry
     # the page size measured to balance HBM tail waste against page-table

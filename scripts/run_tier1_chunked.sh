@@ -83,8 +83,8 @@ select_changed_files() {
                 stems="$stems codecs" ;;
             # known-bad analysis fixtures are exercised only by test_analysis
             tests/fixtures/*) printf '%s\n' tests/test_analysis.py ;;
-            # bench scripts are pinned by the --smoke subprocess tests that
-            # name them (latency_bench -> test_pallas_rhd, etc.)
+            # a kept measurement tool is pinned by the test that names it
+            # (algo_sweep_bench -> test_algos)
             benchmarks/*.py) stems="$stems $(basename "$f" .py)" ;;
             mlsl_tpu/*.py|mlsl_tpu/*/*.py|mlsl_tpu/*/*/*.py)
                 s=$(basename "$f" .py)

@@ -156,21 +156,25 @@ def _default_tracer():
         tracer.enable()
 
 
-def skip_if_loaded(detail: str) -> None:
-    """Comparative-timing deflake contract (KNOWN_FAILURES.md "Known
-    flakes"): a bench smoke's LIVE timing comparison gets best-of-N inside
-    the bench plus ONE whole-bench retry from the test; if it still fails
-    on a box under external load the comparison is unjudgeable — skip
-    loudly with the load recorded. On an idle box this returns and the
-    caller's assertion fails: that is a genuine regression, not the flake.
-    Functional assertions never route through here — they stay hard."""
-    load1 = os.getloadavg()[0]
-    ncpu = os.cpu_count() or 1
-    if load1 > 0.5 * ncpu:
-        pytest.skip(
-            f"skipped:loadavg {load1:.1f} on {ncpu} cpus - comparative "
-            f"timing unjudgeable under external load ({detail})"
-        )
+def resnet50_counts(per_layer: bool = False, scale: int = 1, floor: int = 64):
+    """Parameter counts of a ResNet-50's gradient stream, for tests that need
+    a realistically ragged list of tensors: 53 convolutions each with a batch
+    norm's (gamma, beta), and the fc head. Per tensor that is 161 counts; per
+    layer (convolution with its batch norm, fc weight with bias) 54.
+    ``scale`` divides every count without changing how many there are."""
+    convs = [(3, 64, 7)]
+    cin = 64
+    for blocks, mid in [(3, 64), (4, 128), (6, 256), (3, 512)]:
+        for b in range(blocks):
+            convs += [(cin, mid, 1), (mid, mid, 3), (mid, mid * 4, 1)]
+            if b == 0:  # downsample projection
+                convs.append((cin, mid * 4, 1))
+            cin = mid * 4
+    tensors = [[ci * co * k * k, co, co] for ci, co, k in convs]
+    tensors.append([2048 * 1000, 1000])
+    counts = ([sum(t) for t in tensors] if per_layer
+              else [c for t in tensors for c in t])
+    return [max(c // scale, floor) for c in counts]
 
 
 def ref_coords(p, data_parts, model_parts):

@@ -171,7 +171,7 @@ CONTROL_SITES = {"control.heartbeat", "control.notice"}
 # their behaviors (admit fault fails ONE request closed, transient decode
 # errors retried in place, device loss shedding the ladder with the engine
 # surviving, a hang breaching the TPOT window) are pinned by the chaos tests
-# in tests/test_serve.py and the serving_bench chaos row.
+# in tests/test_serve.py.
 SERVE_SITES = {"serve.admit", "serve.decode"}
 
 

@@ -177,16 +177,6 @@ def test_failed_build_beside_a_library_is_an_error(monkeypatch):
         native.load()
 
 
-def test_peaks_table_raises_on_unknown_device():
-    sys.path.insert(0, REPO)
-    import bench
-
-    assert bench._peak_tflops("TPU v5 lite") == 197.0
-    assert bench._peak_tflops("TPU v5p") == 459.0
-    with pytest.raises(ValueError, match="no bf16 peak"):
-        bench._peak_tflops("TPU v5")           # not v5p's 459 by default
-
-
 # -- cross-lowering for the TPU on the CPU ------------------------------------
 
 

@@ -8,9 +8,6 @@ same math done host-side (uint8) or tolerance-pinned against the original
 with the cache on or off.
 """
 
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -263,6 +260,34 @@ def test_cache_epoch_parity_fixed_shuffle(env):
     assert any(
         not np.array_equal(xs[e * 4], batches[0][0]) for e in range(3)
     )
+
+
+@pytest.mark.parametrize("wire,share", [("none", 1.0), ("bf16", 0.55),
+                                        ("uint8", 0.3), ("int8", 0.3)])
+def test_feed_accounts_the_codecs_wire_bytes_per_batch(env, wire, share):
+    """The feed as a training loop holds it (DeviceFeed behind an
+    AsyncLoader, HBM cache on, three epochs): what FEED_COUNTERS says crossed
+    the link is what the wire's codec says one staging of each batch ships,
+    once a batch (epochs two and three come from the cache), and a narrower
+    wire ships no more than its share of the float32 it decodes to."""
+    from mlsl_tpu.data import AsyncLoader, DeviceFeed, FeedCodec
+
+    _, topo = _topo(env)
+    batches = _batches(4, 16, (64, 64, 3), seed=9)
+    shipped = [FeedCodec(topo, wire).stage(b)[1:] for b in batches]
+    full = sum(x.nbytes + y.nbytes for x, y in batches)
+    assert sum(f for _, f in shipped) == full
+    core_stats.reset_feed_counters()
+    loader = AsyncLoader(
+        DeviceFeed(batches, topo, wire=wire, cache_mb=64, epochs=3), depth=2)
+    seen = sum(1 for _ in loader)
+    loader.close()
+    c = dict(core_stats.FEED_COUNTERS)
+    assert seen == 12
+    assert c["batches_staged"] == 4 and c["cache_hits"] == 8
+    assert c["wire_bytes"] == sum(w for w, _ in shipped)
+    assert c["wire_bytes"] <= share * full
+    assert wire != "none" or c["wire_bytes"] == full
 
 
 def test_cache_budget_rejects_but_streams(env):
@@ -687,47 +712,3 @@ def test_feed_line_in_stats_log(env, tmp_path, monkeypatch):
     assert "cache 2h/2m" in text
     with open(tmp_path / "mlsl_stats.log") as f:
         assert "FEED" in f.read()
-
-
-# -- bench wiring ------------------------------------------------------------
-
-
-def test_overlap_probe_records_explicit_skip(monkeypatch):
-    """Satellite: a failed CPU-mesh overlap probe must record WHY
-    (overlap_backend='skipped:<reason>'), never a bare null pair."""
-    import bench
-
-    monkeypatch.setattr(bench, "_OVERLAP_PROBE_SRC", "print('no overlap')")
-    frac, tag = bench._overlap_probe_cpu_mesh(timeout=120, attempts=1)
-    assert frac is None
-    assert tag.startswith("skipped:")
-
-
-@pytest.mark.bench_smoke
-def test_input_pipeline_bench_smoke():
-    """Tier-1 wiring for benchmarks/input_pipeline_bench.py: the smoke grid
-    must run and parse (comparative speedups are asserted on-chip, not on a
-    loaded CI box — the PR 2/3 lesson about comparative smoke tests)."""
-    import json
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env_vars = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    )
-    env_vars.pop("MLSL_CHAOS", None)
-    out = subprocess.run(
-        [sys.executable, "benchmarks/input_pipeline_bench.py", "--smoke"],
-        capture_output=True, text=True, timeout=600, env=env_vars, cwd=repo,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(l) for l in out.stdout.splitlines()
-            if l.startswith("{")]
-    grid = [r for r in rows if r.get("metric") == "input_pipeline"]
-    assert len(grid) >= 4
-    for r in grid:
-        assert r["images_per_s"] > 0
-        assert "wire_mb_per_batch" in r and "h2d_mbps" in r
-    summary = [r for r in rows if r.get("metric") == "input_pipeline_best"]
-    assert summary and summary[0]["feed_depth"] >= 1

@@ -638,36 +638,3 @@ def test_profile_on_trip_off_by_default(env, monkeypatch):
     monkeypatch.delenv("MLSL_PROFILE_ON_TRIP", raising=False)
     _wedged_wait(env, monkeypatch, "wedge2")
     assert "device_profile" not in stats.WATCHDOG_EVENTS[-1]
-
-
-# -- overhead bench wiring (tier-1 smoke) -------------------------------------
-
-
-@pytest.mark.bench_smoke
-def test_metrics_overhead_bench_smoke():
-    """Tier-1 wiring for benchmarks/metrics_overhead_bench.py: the disabled
-    path is zero-alloc and the armed path costs <2% of a representative
-    step at the default cadence (the ISSUE 15 acceptance row) — the bench
-    itself exits nonzero on either violation."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env_vars = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    )
-    for k in list(env_vars):
-        if k.startswith(("MLSL_METRICS", "MLSL_STRAGGLER")):
-            del env_vars[k]
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(repo, "benchmarks", "metrics_overhead_bench.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=540, env=env_vars, cwd=repo,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(l) for l in out.stdout.splitlines()
-            if l.startswith("{")]
-    assert len(rows) == 1
-    row = rows[0]
-    assert row["disabled_zero_alloc"] is True
-    assert row["overhead_frac_default"] < 0.02

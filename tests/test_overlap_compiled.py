@@ -10,11 +10,6 @@ bit-exact on integer payloads against the host algorithm programs across
 {lax, rhd, ring2d} x group shapes {8, (4,2), 6}.
 """
 
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -413,11 +408,10 @@ def test_sentinel_skip_step_lockstep(env):
 
 
 def test_degenerate_group_single_device(env):
-    """force_graph_path + overlap_compiled on a single-device world (the
-    bench.py single-chip row): units have ZERO reduce phases — the compiled
-    per-layer schedule still runs, bit-identical to the host no-comm
-    per-layer path (the IndexError regression this pins was caught by
-    bench --quick)."""
+    """force_graph_path + overlap_compiled on a single-device world (one
+    chip): units have ZERO reduce phases — the compiled per-layer schedule
+    still runs, bit-identical to the host no-comm per-layer path (this pins
+    an IndexError regression)."""
     params = init(jax.random.PRNGKey(0))
 
     def mk(overlap_on):
@@ -534,14 +528,11 @@ def test_stats_and_trace_attribution(env):
 
 @pytest.mark.slow
 def test_large_model_parity(env):
-    """Slow: the full ResNet-50-shaped 54-layer stream twin (the bench
-    model) pinned host-vs-compiled over several steps."""
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks"))
-    from overlap_compiled_bench import resnet50_layer_counts
+    """Slow: a ResNet-50-shaped 54-layer stream pinned host-vs-compiled
+    over several steps."""
+    from conftest import resnet50_counts
 
-    counts = resnet50_layer_counts(scale=16)
+    counts = resnet50_counts(per_layer=True, scale=16)
     layers = [f"l{i}" for i in range(len(counts))]
     rng = np.random.default_rng(0)
     params = {
@@ -577,29 +568,3 @@ def test_large_model_parity(env):
         th.step(bh)
         tc.step(bc)
     assert _max_param_delta(th.params, tc.params) <= 1e-6
-
-
-@pytest.mark.bench_smoke
-def test_overlap_compiled_bench_smoke():
-    """Tier-1 wiring for benchmarks/overlap_compiled_bench.py: the smoke row
-    must parse and the compiled schedule must beat the host per-layer path
-    on the 8-dev CPU proof mesh (the measured acceptance: >= 1.1x)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env_vars = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    )
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(repo, "benchmarks", "overlap_compiled_bench.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=540, env=env_vars, cwd=repo,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(l) for l in out.stdout.splitlines() if l.startswith("{")]
-    stream = [r for r in rows
-              if r["metric"] == "overlap_compiled_resnet50_stream"]
-    assert len(stream) == 1 and stream[0]["layers"] >= 54
-    assert stream[0]["speedup"] >= 1.1, stream[0]
-    assert "compiled_vs_fused" in stream[0]

@@ -16,16 +16,10 @@ Tier-1 runs the kernel under the Pallas interpreter (MLSL_PALLAS_INTERPRET=1
   span + ALGO counter attribution, breaker degradation to the baseline,
   MLSL_PRECOMPILE plan-key variant identity, tuner knob validation, and the
   A130-A132 static-accounting mirror (including the pre/post fold rounds
-  for non-2^k groups the 8-device mesh cannot instantiate live);
-- the latency_bench --smoke wiring (the ``bench_smoke`` marker).
+  for non-2^k groups the 8-device mesh cannot instantiate live).
 
 The compiled Mosaic variant carries the ``tpu`` marker (auto-skip
 off-chip, conftest)."""
-
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -39,8 +33,6 @@ from mlsl_tpu.ops import rhd_kernels as rhd
 from mlsl_tpu.types import (
     CompressionType, DataType, GroupType, ReductionType,
 )
-
-REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 
 
 @pytest.fixture(autouse=True)
@@ -390,59 +382,6 @@ def test_accounting_tamper_detected():
     bad.remove(("free", 0, [e for e in ev if e[0] == "free"][-1][2]))
     rep = plan_mod.verify_hop_trace(bad, slots=2, ndirs=nd, total_hops=th)
     assert any(d.code == "MLSL-A130" for d in rep.diagnostics)
-
-
-# -- bench smoke wiring -------------------------------------------------------
-
-
-@pytest.mark.bench_smoke
-def test_latency_bench_smoke():
-    """Tier-1 wiring for benchmarks/latency_bench.py: rows parse, the parity
-    and wire-ratio acceptance rows are hard; the rhd-beats-ring band is a
-    live-timing comparison and follows the deflake contract (one retry,
-    loud skip on a loaded box — KNOWN_FAILURES.md)."""
-    from conftest import skip_if_loaded
-
-    env_vars = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    )
-    for k in ("MLSL_ALGO", "MLSL_TUNE", "MLSL_TUNE_PROFILE", "MLSL_CHAOS",
-              "MLSL_PALLAS_RING_SLOTS", "MLSL_PALLAS_RHD",
-              "MLSL_PALLAS_RHD_MAX_BYTES", "MLSL_PALLAS_A2A_QUANT"):
-        env_vars.pop(k, None)
-
-    def run():
-        out = subprocess.run(
-            [sys.executable,
-             os.path.join(REPO, "benchmarks", "latency_bench.py"),
-             "--smoke"],
-            capture_output=True, text=True, timeout=540, env=env_vars,
-            cwd=REPO,
-        )
-        assert out.returncode == 0, out.stderr[-2000:]
-        rows = [json.loads(l) for l in out.stdout.splitlines()
-                if l.startswith("{")]
-        curve = [r for r in rows if r["metric"] == "latency_bench"]
-        assert len(curve) >= 2
-        assert all(set(r["us"]) >= {"lax", "rhd", "pallas_ring",
-                                    "pallas_rhd"} for r in curve)
-        parity = next(r for r in rows
-                      if r["metric"] == "latency_bench_parity")
-        assert parity["rhd_int_bitexact_vs_lax"]
-        assert parity["a2a_int_bitexact_vs_lax"]
-        assert parity["a2a_wire_ratio_le_third"]
-        moe = next(r for r in rows if r["metric"] == "latency_bench_moe")
-        assert moe["wire_bytes"]["ratio"] <= 1 / 3
-        return next(r for r in rows if r["metric"] == "latency_crossover")
-
-    cross = run()
-    if not cross["rhd_wins_band"]:
-        cross = run()  # one retry: a fresh best-of-N curve
-    if not cross["rhd_wins_band"]:
-        skip_if_loaded(f"crossover row {cross}")
-    assert cross["rhd_wins_band"], cross
 
 
 # -- on-chip-only variant (auto-skip off TPU) ---------------------------------

@@ -16,17 +16,12 @@ compile), pinning:
 - selection precedence (MLSL_ALGO > tuned profile > default), the off-TPU
   eligibility gate, breaker degradation to the baseline, chunked quantized
   requests, the overlap engine's loud off-chip fallback, plan-cache variant
-  identity, config/knob validation, and the bench --smoke wiring.
+  identity, and config/knob validation.
 
 On-chip-only variants (compiled Mosaic kernels, in-graph overlap emission,
 the capacity-handshake/bidir code paths that the interpreter statically
 elides) carry the ``tpu`` marker and auto-skip off-chip (conftest).
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -41,7 +36,6 @@ from mlsl_tpu.types import (
     CompressionType, DataType, GroupType, ReductionType,
 )
 
-REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 BLOCK = 128  # quant block for the parity suites (any 128-multiple works)
 
 
@@ -639,38 +633,6 @@ def test_plan_key_carries_slot_geometry(env):
     finally:
         env.config.precompile = False
         collectives.clear_cache()
-
-
-# -- bench smoke wiring -------------------------------------------------------
-
-
-@pytest.mark.bench_smoke
-def test_pallas_ring_bench_smoke():
-    """Tier-1 wiring for benchmarks/pallas_ring_bench.py: rows parse and the
-    parity acceptance row is green (interpret backend off-chip)."""
-    env_vars = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    )
-    for k in ("MLSL_ALGO", "MLSL_TUNE", "MLSL_TUNE_PROFILE", "MLSL_CHAOS",
-              "MLSL_PALLAS_RING_SLOTS", "MLSL_PALLAS_RING_BIDIR"):
-        env_vars.pop(k, None)
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "benchmarks", "pallas_ring_bench.py"), "--smoke"],
-        capture_output=True, text=True, timeout=540, env=env_vars, cwd=REPO,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(l) for l in out.stdout.splitlines()
-            if l.startswith("{")]
-    curve = [r for r in rows if r["metric"] == "pallas_ring_bench"]
-    assert len(curve) >= 2
-    assert all("dense/pallas_ring" in r["us"] and "int8/pallas_ring" in r["us"]
-               for r in curve)
-    parity = next(r for r in rows if r["metric"] == "pallas_ring_parity")
-    assert parity["dense_int_bitexact_vs_lax"]
-    assert parity["quant_bitexact_vs_quant_ring"]
 
 
 # -- on-chip-only variants (auto-skip off TPU) --------------------------------

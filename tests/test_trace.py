@@ -4,8 +4,6 @@ Perfetto export validity, and the watchdog flight recorder."""
 
 import json
 import os
-import subprocess
-import sys
 import time
 import tracemalloc
 
@@ -171,6 +169,10 @@ def _import_probe(value):
     import importlib
 
     before = os.environ.pop(tracer_mod.ENV_TRACE, None)
+    # a fresh process has no ring size either: an in-process serving run of
+    # tests/perf leaves its own (perf/adapters' setdefault) in a worker that
+    # --dist loadfile may hand this file next
+    capacity = os.environ.pop(tracer_mod.ENV_CAPACITY, None)
     if value is not None:
         os.environ[tracer_mod.ENV_TRACE] = value
     try:
@@ -180,6 +182,8 @@ def _import_probe(value):
         os.environ.pop(tracer_mod.ENV_TRACE, None)
         if before is not None:
             os.environ[tracer_mod.ENV_TRACE] = before
+        if capacity is not None:
+            os.environ[tracer_mod.ENV_CAPACITY] = capacity
         importlib.reload(tracer_mod)
 
 
@@ -440,32 +444,3 @@ def test_count_backend_compiles_unregisters_on_exception():
     before = captured[0][0]
     monitoring.record_event_duration_secs(BACKEND_COMPILE_EVENT, 0.01)
     assert captured[0][0] == before, "listener leaked past the context"
-
-
-# -- overhead microbench wiring (tier-1 smoke) --------------------------------
-
-
-@pytest.mark.bench_smoke
-def test_trace_overhead_bench_smoke():
-    """Tier-1 wiring for benchmarks/trace_overhead_bench.py: the enabled
-    tracer must add <5% to the windowed CPU-mesh allreduce stream (accounted
-    per-event cost x instrumented events over the measured stream floor — the
-    comparative delta is reported but carries the backend's +-15% noise)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env_vars = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-    )
-    env_vars.pop("MLSL_TRACE", None)  # the bench toggles tracing itself
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(repo, "benchmarks", "trace_overhead_bench.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=540, env=env_vars, cwd=repo,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    rows = [json.loads(l) for l in out.stdout.splitlines() if l.startswith("{")]
-    row = next(r for r in rows if r["metric"] == "trace_overhead")
-    assert row["per_event_us"] < 50  # a ring append is microseconds, not ms
-    assert row["overhead_frac"] < 0.05, row

@@ -299,7 +299,8 @@ def test_remat_replays_forward(env):
     forward (+1/4 to +1/3 of the plain 3x-forward step). The MEMORY win is a
     TPU-backend liveness property — XLA:CPU's temp accounting does not
     reflect it (measured: remat temp slightly LARGER on CPU at d128 x 8blk x
-    s512), so on-chip evidence comes from transformer_bench, not this test."""
+    s512), so on-chip evidence is the training cell's `peak_hbm_gib`, not this
+    test."""
     cfg = dataclasses.replace(
         CFG, n_blocks=8, seq_len=512, d_model=128, n_heads=4, head_dim=32
     )
