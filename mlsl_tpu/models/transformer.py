@@ -656,7 +656,8 @@ def chunk_local(params, tokens, offset, n_valid, table, kpool, vpool, ipool,
     for a configuration without an indexer) are written into the pools,
     then each query attends to what the cache holds of the sequence up to
     itself: every such position, or the ``index_topk`` that its indexer
-    scores highest (paged_attention.exact_top_k_mask). The walks over the
+    scores highest (paged_attention.select_in_context: exactly, searched
+    as far as the context held reaches). The walks over the
     context stop at offset + n_valid. Returns (logits (V,) f32 at the last
     valid position, int32 [experts that got a token, token-expert pairs]
     summed over layers, kpool, vpool[, ipool]) - the engine donates the

@@ -134,28 +134,150 @@ def _ordered_bits(x):
     return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
 
 
+#: the bytes of scores that are searched together, chosen on the chip (PERF.md
+#: section 6, PR 33): with all of 2,048 x 33,280 at once every counting pass
+#: reads the scores from HBM; 34 MB at a time they stay in fast memory across
+#: the passes, at well under half the time (68 MB: half again as slow as 34;
+#: 17 and 8.5 MB: a twentieth and a tenth slower).
+GROUP_BYTES = 1024 * 8320 * 4
+
+
 def exact_top_k_mask(scores, k):
     """The ``k[r]`` highest of each row of ``scores`` (R, S) f32, exactly,
     ties to the lower column: -> (R, S) bool. Positions that may not be
-    chosen hold -inf, and ``k[r]`` is at most the number that may. No sort:
-    the k-th highest value is found bit by bit (32 counting passes over the
-    scores), then the row keeps what lies above it and the first of its
-    equals. ``lax.approx_max_k`` may miss a member and is a different
-    result, not a faster one."""
+    chosen hold -inf, and ``k[r]`` is at most the number that may. No sort
+    (``_top_k_rows``). ``lax.approx_max_k`` may miss a member and is a
+    different result, not a faster one.
+
+    Four rows or more with more than ``GROUP_BYTES`` of scores (a prefill
+    chunk's) are searched one unit of that size after the other, so that a
+    unit stays in fast memory while it is counted, and only as far as the
+    last column that some row may choose: a row is cut into tiles of a
+    quarter of S, a unit is the 1, 2 or 4 tiles that reach that far
+    (``select_width`` is the same table for the host) of as many rows as
+    fit, and its search adds up the counts of a row's tiles. One search in
+    the program whatever the context held, at the cost of the context
+    held."""
+    rows, s = scores.shape
+    if rows * s * 4 <= GROUP_BYTES or rows < 4:
+        return _top_k_rows(scores, k)
+    tile = _tile(s)
+    unit = 4
+    while 2 * unit <= rows and 2 * unit * tile * 4 <= GROUP_BYTES:
+        unit *= 2
+    chosen_from = jnp.any(scores > -jnp.inf, axis=0)
+    live = jnp.max(jnp.where(chosen_from, jnp.arange(1, s + 1), 0))
+    which = _reach(live, tile)
+    parts = 1 << which
+    whole = jnp.pad(scores, ((0, 0), (0, 4 * tile - s)),
+                    constant_values=-jnp.inf)
+
+    # unit g: the first p tiles of unit / p rows (the last unit starts where
+    # it still fits, and searches some rows again)
+    def cut(p):
+        return lambda g: (
+            lax.dynamic_slice(whole, (g * (unit // p), 0),
+                              (unit // p, p * tile)).reshape(unit, tile),
+            jnp.repeat(lax.dynamic_slice(k, (g * (unit // p),),
+                                         (unit // p,)), p))
+
+    def put(p):
+        return lambda out, chosen, g: lax.dynamic_update_slice(
+            out, chosen.reshape(unit // p, p * tile), (g * (unit // p), 0))
+
+    def one_unit(g, out):
+        chosen = _top_k_rows(
+            *lax.switch(which, [cut(p) for p in (1, 2, 4)], g), parts)
+        return lax.switch(which, [put(p) for p in (1, 2, 4)], out, chosen, g)
+
+    out = lax.fori_loop(0, (rows * parts + unit - 1) // unit, one_unit,
+                        jnp.zeros(whole.shape, bool))
+    return out[:, :s]
+
+
+def _tile(s: int) -> int:
+    """A quarter of a row of ``s`` scores, in whole lanes of 128."""
+    return -(-s // 512) * 128
+
+
+def _reach(live, tile: int):
+    """0, 1 or 2: a row's first 1, 2 or 4 tiles hold its ``live`` first
+    columns (a traced value in the program, a plain number on the host; the
+    0 makes it a sum of two traced booleans, not their ``or``)."""
+    return (live > tile) + 0 + (live > 2 * tile)
+
+
+def select_width(n_keys: int, s: int, topk: int) -> int:
+    """The columns of a row of ``s`` scores that the selection of a chunk
+    searches when its context is ``n_keys`` long (``exact_top_k_mask`` under
+    ``select_in_context``): one, two or four quarters, and 0 where the
+    context is no longer than ``topk`` and nothing is searched."""
+    if n_keys <= topk:
+        return 0
+    return min(s, _tile(s) << int(_reach(n_keys, _tile(s))))
+
+
+def _over_runs(x, member):
+    """x (rows,) int32 in fours; member (4, 4) bool: -> (rows,), row j of a
+    four gets the sum of the rows i of its four with ``member[i, j]``."""
+    return jnp.sum(jnp.where(member, x.reshape(-1, 4, 1), 0),
+                   axis=1).reshape(-1)
+
+
+@jax.jit
+def _top_k_rows(scores, k, parts=None):
+    """``exact_top_k_mask`` of rows that are searched together (jitted, so
+    that a program of many layers traces and lowers it once). The k-th
+    highest value is found bit by bit, the highest first: 32 counting passes
+    over the scores. Then the row keeps what lies above that value and the
+    first of its equals, up to the column of the last one there is room for,
+    which the counts of equals a block of 128 columns and one block a row
+    give: no cumulative sum over the scores (10.9 of the 24.3 ms that 2,048
+    x 33,280 took; some row of a wide chunk has a tie at its k-th value in
+    every second layer, so skipping it when no row has one would not do).
+    With ``parts`` (1, 2 or 4, a traced number) every aligned run of that
+    many rows is one row cut into tiles, each with the row's ``k``."""
+    rows, s = scores.shape
     u = _ordered_bits(scores)
     k = k.astype(jnp.int32)
+    if parts is not None:
+        j = jnp.arange(4)
+        same = j[:, None] // parts == j[None, :] // parts
+        whole_row = functools.partial(_over_runs, member=same)
+        tiles_before = functools.partial(
+            _over_runs, member=same & (j[:, None] < j[None, :]))
 
     def one_bit(i, thr):
         cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        enough = jnp.sum(u >= cand[:, None], axis=1, dtype=jnp.int32) >= k
-        return jnp.where(enough, cand, thr)
+        n = jnp.sum(u >= cand[:, None], axis=1, dtype=jnp.int32)
+        if parts is not None:
+            n = whole_row(n)
+        return jnp.where(n >= k, cand, thr)
 
-    thr = lax.fori_loop(0, 32, one_bit, jnp.zeros(u.shape[:1], jnp.uint32))
-    above = u > thr[:, None]
-    equal = u == thr[:, None]
-    room = k - jnp.sum(above, axis=1, dtype=jnp.int32)
-    return above | (equal & (jnp.cumsum(equal, axis=1, dtype=jnp.int32)
-                             <= room[:, None]))
+    thr = lax.fori_loop(0, 32, one_bit, jnp.zeros((rows,), jnp.uint32))
+    # the column of the last equal that is kept: its block from the running
+    # count of equals a block, its place from that block alone
+    lanes = 128 if s % 128 == 0 else s
+    blocks = u.reshape(rows, s // lanes, lanes)
+    n_above = jnp.sum(blocks > thr[:, None, None], axis=2, dtype=jnp.int32)
+    n_equal = jnp.sum(blocks == thr[:, None, None], axis=2, dtype=jnp.int32)
+    room = k - jnp.sum(n_above, axis=1)
+    if parts is not None:       # of the whole row, after its tiles before
+        room = k - whole_row(jnp.sum(n_above, axis=1)) \
+            - tiles_before(jnp.sum(n_equal, axis=1))
+    upto = jnp.cumsum(n_equal, axis=1)
+    at = jnp.minimum(jnp.sum(upto < room[:, None], axis=1, dtype=jnp.int32),
+                     s // lanes - 1)
+    before = jnp.take_along_axis(upto - n_equal, at[:, None], axis=1)
+    block = jnp.take_along_axis(blocks, at[:, None, None], axis=1)[:, 0]
+    within = before + jnp.cumsum(block == thr[:, None], axis=1,
+                                 dtype=jnp.int32)
+    last = at * lanes + jnp.sum(within < room[:, None], axis=1,
+                                dtype=jnp.int32)
+    last = jnp.where(room > 0, last, -1)
+    column = jnp.arange(s, dtype=jnp.int32)
+    return (u > thr[:, None]) | (
+        (u == thr[:, None]) & (column[None, :] <= last[:, None]))
 
 
 def index_scores(qi, wi, ki):
@@ -290,27 +412,8 @@ def context_index_scores(qi, wi, kictx, may, n_keys, block: int):
 
 
 def select_in_context(scores, room, n_keys, topk: int):
-    """``exact_top_k_mask`` of a chunk's scores (C, S), at the cost of the
-    context held: nothing to choose while the context is no longer than
-    ``topk`` (every position a query may read is selected), and else the
-    counting passes run over the narrowest of a quarter, a half and the
-    whole of S that holds ``n_keys``."""
-    s_max = scores.shape[1]
-    widths = sorted({min(s_max, -(-s_max // d // 128) * 128)
-                     for d in (4, 2, 1)})
-    widths = [w for w in widths if w > topk] or [s_max]
-
-    def everything(sc):
-        return sc > -jnp.inf
-
-    def within(w):
-        def choose(sc):
-            return jnp.pad(exact_top_k_mask(sc[:, :w], room),
-                           ((0, 0), (0, s_max - w)))
-        return choose
-
-    which = jnp.where(
-        n_keys <= topk, 0,
-        1 + sum((n_keys > w).astype(jnp.int32) for w in widths[:-1]))
-    return lax.switch(which, [everything] + [within(w) for w in widths],
-                      scores)
+    """``exact_top_k_mask`` of a chunk's scores (C, S): nothing to choose
+    while the context is no longer than ``topk`` (every position a query
+    may read is selected)."""
+    return lax.cond(n_keys > topk, lambda sc: exact_top_k_mask(sc, room),
+                    lambda sc: sc > -jnp.inf, scores)

@@ -616,6 +616,11 @@ class InferenceEngine:
         self.kpool, self.vpool = out[2:4]
         if self.indexed:
             self.ipool = out[4]
+        # the width this chunk's selection searches, for its span: host
+        # arithmetic while the device runs the chunk
+        width = paged_attention.select_width(
+            at + n, table.size * self.cache.page_elems,
+            self.cfg.index_topk) if tr is not None and self.indexed else 0
         # blocks until the chunk is done: the expert counts come back, and
         # with them the last chunk's token, which is the first token
         tok, counts = jax.device_get(out[:2]) if last \
@@ -627,7 +632,8 @@ class InferenceEngine:
             t0 = tr.complete(
                 "serve.prefill.chunk", "serve", t0, step=step, req=req.id,
                 chunk=seq.chunks - 1, offset=at, tokens=n, last=last,
-                experts_hit=int(counts[0]), expert_tokens=int(counts[1]))
+                experts_hit=int(counts[0]), expert_tokens=int(counts[1]),
+                select_width=width)
         if not last:
             return
         t_first = tr.complete("serve.first_token", "serve", t0, step=step,

@@ -197,6 +197,150 @@ def test_exact_top_k_keeps_the_lower_position_among_equals(k):
         assert (got[row] == want).all()
 
 
+def _top_k_oracle(scores, k):
+    """Stable descending order, ties to the lower column, both zeros as +0."""
+    want = np.zeros(scores.shape, bool)
+    for r, row in enumerate(scores):
+        want[r, np.argsort(-(row + 0.0), kind="stable")[:k[r]]] = True
+    return want
+
+
+def _top_k_case(name):
+    """-> (scores (R, S) f32, k (R,)): what ``exact_top_k_mask`` has to get
+    right, at widths of one block of columns and of several."""
+    rng = np.random.default_rng(len(name))
+    if name == "random":
+        s = rng.normal(size=(8, 384)).astype(np.float32)
+        return s, rng.integers(1, 384, size=8)
+    if name == "three_values":          # ties at the k-th value in every row
+        s = rng.integers(0, 3, size=(8, 384)).astype(np.float32)
+        return s, np.array([1, 5, 100, 128, 129, 200, 300, 383])
+    if name == "all_equal":
+        return np.full((6, 256), 0.25, np.float32), \
+            np.array([1, 2, 127, 128, 129, 255])
+    if name == "inf_tail_k_is_all_allowed":
+        s = rng.normal(size=(6, 200)).astype(np.float32)
+        allowed = np.array([1, 7, 64, 150, 199, 200])
+        s[np.arange(200)[None, :] >= allowed[:, None]] = -np.inf
+        return s, allowed
+    if name == "k_zero":
+        return rng.normal(size=(4, 256)).astype(np.float32), \
+            np.zeros(4, np.int64)
+    if name == "k_is_the_row":
+        s = rng.integers(-2, 3, size=(4, 256)).astype(np.float32)
+        return s, np.full(4, 256)
+    if name == "both_zeros":            # -0.0 ties with +0.0: the lower column
+        s = rng.integers(-1, 2, size=(6, 256)).astype(np.float32)
+        s[:, ::3] = -0.0
+        s[:, 1::3] = 0.0
+        return s, np.array([1, 40, 100, 171, 200, 250])
+    if name == "a_k_a_row":
+        s = np.round(rng.normal(size=(16, 640)) * 4).astype(np.float32) / 4
+        s[:, 600:] = -np.inf
+        return s, np.arange(16) * 40
+    if name in ("a_quarter_live", "a_half_live"):   # 1 and 2 tiles of a row
+        live = 100 if name == "a_quarter_live" else 250
+        s = np.round(rng.normal(size=(8, 512)) * 2).astype(np.float32) / 2
+        s[:, live:] = -np.inf
+        return s, np.array([0, 1, 17, 64, 65, 99, live - 1, live])
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("unit_rows", [None, 2, 4],
+                         ids=["together", "units_of_4", "units_of_8"])
+@pytest.mark.parametrize("case", [
+    "random", "three_values", "all_equal", "inf_tail_k_is_all_allowed",
+    "k_zero", "k_is_the_row", "both_zeros", "a_k_a_row", "a_quarter_live",
+    "a_half_live"])
+def test_exact_top_k_is_the_stable_sorts(case, unit_rows, monkeypatch):
+    """Searched together (the decode step's few rows) and cut into tiles
+    that are searched a unit at a time (a chunk's: more bytes than
+    ``GROUP_BYTES``, here that many rows' scores)."""
+    scores, k = _top_k_case(case)
+    if unit_rows:
+        monkeypatch.setattr(paged_attention, "GROUP_BYTES",
+                            unit_rows * scores.shape[1] * 4)
+    got = np.asarray(jax.jit(paged_attention.exact_top_k_mask)(
+        jnp.asarray(scores), jnp.asarray(k, jnp.int32)))
+    assert (got == _top_k_oracle(scores, k)).all()
+    assert (got.sum(axis=1) == k).all()
+
+
+@pytest.mark.parametrize("n_keys", [64, 65, 256, 257, 512, 513, 1024])
+def test_select_in_context_is_the_full_width_selection(n_keys, monkeypatch):
+    """At the edges of every reach (``topk`` 64 under a table of 1,024:
+    nothing, one tile of 256, two, the whole), with ties and a chunk of more
+    bytes than are searched together: the search as far as the context gives
+    what the search of whole rows gives, and the host's table names the
+    width."""
+    topk, s_max, c = 64, 1024, 8
+    monkeypatch.setattr(paged_attention, "GROUP_BYTES", 4 * 256 * 4)
+    rng = np.random.default_rng(n_keys)
+    qpos = n_keys - c + np.arange(c)
+    kpos = np.arange(s_max)
+    scores = np.round(rng.normal(size=(c, s_max)) * 8).astype(np.float32) / 8
+    scores[(kpos[None, :] > qpos[:, None]) | (kpos[None, :] >= n_keys)] = -np.inf
+    room = jnp.asarray(np.minimum(qpos + 1, topk), jnp.int32)
+    got = jax.jit(paged_attention.select_in_context, static_argnums=3)(
+        jnp.asarray(scores), room, jnp.int32(n_keys), topk)
+    want = paged_attention._top_k_rows(jnp.asarray(scores), room)
+    assert (np.asarray(got) == np.asarray(want)).all()
+    assert (np.asarray(got).sum(axis=1) == np.asarray(room)).all()
+    assert paged_attention.select_width(n_keys, s_max, topk) == (
+        0 if n_keys <= topk else min(
+            w for w in (256, 512, 1024) if n_keys <= w))
+
+
+def _loops(jaxpr, around=()):
+    """Every loop of a jaxpr, outermost first: -> [(the trip counts of the
+    loops around it, its own (None where the program computes it), its body's
+    jaxpr)]."""
+    found = []
+    for eqn in jaxpr.eqns:
+        inner = around
+        if eqn.primitive.name in ("while", "scan"):
+            scan = eqn.primitive.name == "scan"
+            trips = eqn.params["length"] if scan else None
+            body = eqn.params["jaxpr" if scan else "body_jaxpr"].jaxpr
+            found.append((around, trips, body))
+            inner = around + (trips,)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _loops(sub, inner)
+    return found
+
+
+def test_the_chunk_program_holds_one_search_and_a_pass_reads_a_unit_once():
+    """The serving cell's chunk (2,048 queries under a table of 33,280,
+    top 2,048), traced and not run: one search whatever the context held,
+    over units of 1,024 tile rows of 8,320 (as many as the context asks
+    for: a trip count the program computes), 32 passes a unit, and a pass
+    holds one reduction over the unit's scores and nothing else of their
+    size but the compare."""
+    rows, s_max, topk = 2048, 33280, 2048
+    jaxpr = jax.make_jaxpr(
+        lambda sc, room, n: paged_attention.select_in_context(
+            sc, room, n, topk))(
+        jax.ShapeDtypeStruct((rows, s_max), jnp.float32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)).jaxpr
+    cond, = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(cond.params["branches"]) == 2
+    loops = _loops(jaxpr)
+    assert [(around, trips) for around, trips, _ in loops] == [
+        ((), None), ((None,), 32)]
+    unit = (paged_attention.GROUP_BYTES // (s_max // 4 * 4), s_max // 4)
+    assert unit == (1024, 8320)
+    body = loops[1][2]
+    over_scores = [e.primitive.name for e in body.eqns if any(
+        getattr(v.aval, "shape", None) == unit for v in e.invars)]
+    assert over_scores == ["ge", "convert_element_type", "reduce_sum"]
+    assert not [e for e in body.eqns
+                if e.primitive.name in ("cumsum", "sort", "gather")]
+    assert [paged_attention.select_width(n, s_max, topk)
+            for n in (2048, 2049, 8320, 8321, 16640, 16641, 33280)] \
+        == [0, 8320, 8320, 16640, 16640, 33280, 33280]
+
+
 def test_compact_selected_lists_the_chosen_rows_in_order():
     rng = np.random.default_rng(1)
     sel = rng.random((3, 7, 8)) < 0.4
@@ -321,6 +465,11 @@ def test_chunked_prefill_leaves_one_span_a_chunk_and_the_counters(env):
             for c in chunks] == [(0, 0, 0, 24, False), (0, 1, 24, 24, False),
                                  (0, 2, 48, 2, True), (1, 0, 0, 24, True)]
     assert all(c["expert_tokens"] == c["tokens"] * 2 * 2 for c in chunks)
+    # how far the selection searched, as the exported table has it: every
+    # chunk's context is longer than the toy's topk and its table is one tile
+    assert [c["select_width"] for c in chunks] \
+        == [paged_attention.select_width(c["offset"] + c["tokens"], CTX, TOPK)
+            for c in chunks] == [CTX] * 4
     # one chunk a step at most, and an admission is the step of the first
     assert len({c["step"] for c in chunks}) == len(chunks)
     admits = [e[7] for e in events if e[1] == "serve.admit"]
