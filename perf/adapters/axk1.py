@@ -18,12 +18,8 @@ _transformer = manifest.load_module(
 
 
 def environment(ring_events=None):
-    """The program's environment, its span ring holding ``ring_events``
-    events. The ring is armed when the program is imported; it is made anew
-    at that size here, and nothing is left in the process's environment. A
-    program that cannot serve this configuration (an older commit) fails
-    here, before any weight is made."""
-    from mlsl_tpu import obs
+    """As the transformer adapter's; and a program that cannot serve this
+    configuration (an older commit) fails here, before any weight is made."""
     from mlsl_tpu.models import transformer as tfm
 
     if not hasattr(tfm, "latent_scale"):
@@ -31,13 +27,7 @@ def environment(ring_events=None):
             "this program has no latent attention "
             "(mlsl_tpu.models.transformer.latent_scale): it cannot serve "
             "a configuration with a latent cache")
-    if ring_events and obs.enabled() \
-            and obs.get_tracer().capacity < ring_events:
-        obs.disable()
-        obs.enable(ring_events)
-    import mlsl_tpu as mlsl
-
-    return mlsl.Environment.get_env().init()
+    return _transformer.environment(ring_events)
 
 
 def program_config(config, traffic):

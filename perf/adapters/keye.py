@@ -6,7 +6,6 @@ the benchmark that imports the program; the faults that prove the cell's
 limit (``plant``) are here for that reason."""
 
 import copy
-import os
 import pathlib
 
 import jax
@@ -20,9 +19,6 @@ _transformer = manifest.load_module(
 def environment(ring_events=None):
     """As the transformer adapter's; and a program that cannot serve this
     configuration (an older commit) fails here, before any weight is made."""
-    if ring_events:
-        os.environ.setdefault("MLSL_TRACE_CAPACITY", str(ring_events))
-    import mlsl_tpu as mlsl
     from mlsl_tpu.models import transformer as tfm
 
     if not hasattr(tfm, "chunk_local"):
@@ -30,7 +26,7 @@ def environment(ring_events=None):
             "this program has no chunked prefill over the paged cache "
             "(mlsl_tpu.models.transformer.chunk_local): it cannot serve "
             "a configuration with an indexer")
-    return mlsl.Environment.get_env().init()
+    return _transformer.environment(ring_events)
 
 
 def program_config(config, traffic):

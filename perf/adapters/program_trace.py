@@ -7,9 +7,9 @@ default before the ring became the flight recorder), gives ``(False, [])``:
 the readers then find nothing to read and leave their metrics out.
 
 The ring holds ``MLSL_TRACE_CAPACITY`` events (65,536 unless set) and a
-serving window at the knee leaves more: the serving runner asks
-``transformer.environment`` for a larger one before the program is imported
-(a training cell does not), and ``capacity`` says what the
+serving window at the knee leaves more: the serving runner asks the
+adapter's ``environment`` for a larger one, made anew once the program is
+imported (a training cell does not), and ``capacity`` says what the
 ring got, so that a reader can tell a whole window from one whose beginning
 was overwritten."""
 

@@ -6,7 +6,6 @@ the benchmark that import the program."""
 import copy
 import functools
 import itertools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -14,12 +13,17 @@ import numpy as np
 
 
 def environment(ring_events=None):
-    """``ring_events``: the serving runner's size for the program's span
-    ring, the operator's ``MLSL_TRACE_CAPACITY``. The ring is sized when the
-    program is imported; a training cell asks for nothing and runs the
-    program's own 65,536."""
-    if ring_events:
-        os.environ.setdefault("MLSL_TRACE_CAPACITY", str(ring_events))
+    """The program's environment, its span ring holding at least
+    ``ring_events`` events (the serving runner's size; a training cell asks
+    for nothing and runs the program's own 65,536). The ring is armed when
+    the program is imported; a smaller one is made anew at that size here,
+    and nothing is left in the process's environment."""
+    from mlsl_tpu import obs
+
+    if ring_events and obs.enabled() \
+            and obs.get_tracer().capacity < ring_events:
+        obs.disable()
+        obs.enable(ring_events)
     import mlsl_tpu as mlsl
 
     return mlsl.Environment.get_env().init()

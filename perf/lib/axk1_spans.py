@@ -3,15 +3,19 @@ the program's counters of the traced steps (``serve.decode``'s and
 ``serve.prefill.chunk``'s ``latent_rows``, ``experts_hit``,
 ``expert_tokens``, ``shared_tokens``), the device time of the absorbed
 decode kernel (found by its name, ``latent_decode_attention``), and the
-device time of the chunk program's walks over the context.
+device time of the prefill kernel that walks a chunk over its context
+(``latent_prefill_attention``).
 
 A program without these spans, counters or kernel (an older commit) gives
 ``None`` or an empty list everywhere, and nothing raises."""
 
-from perf.lib import keye_spans, program_spans, trace as trace_lib
+import re
+
+from perf.lib import keye_spans, program_spans
 
 DECODE_PROGRAM, CHUNK_PROGRAM = keye_spans.DECODE_PROGRAM, keye_spans.CHUNK_PROGRAM
-KERNEL = r"^latent_decode_attention"
+KERNEL = re.compile(r"^latent_decode_attention")
+PREFILL_KERNEL = re.compile(r"^latent_prefill_attention")
 
 
 def _spans(run, name, traced):
@@ -49,35 +53,22 @@ def paired(run, program, spans):
     return list(zip(runs, spans[:len(runs)]))
 
 
+def _kernel_events(run, rx, lo, hi):
+    dev = run.trace.devices[0]
+    return [e for e in run.trace.data["devices"][dev]["ops"]
+            if lo <= e[1] < hi and rx.search(e[0])]
+
+
 def kernel_ns(run, lo, hi):
     """Device time of the absorbed decode kernel inside [lo, hi)."""
-    import re
-
-    rx = re.compile(KERNEL)
-    dev = run.trace.devices[0]
-    return sum(e[2] for e in run.trace.data["devices"][dev]["ops"]
-               if lo <= e[1] < hi and rx.search(e[0]))
+    return sum(e[2] for e in _kernel_events(run, KERNEL, lo, hi))
 
 
-def attention_walks_ns(run, lo, hi):
-    """Device time of the walks over the context in one run of the chunk
-    program: the first top-level loop of each layer (an expert layer's
-    second is the experts' walk). None where the run does not show one loop
-    a dense layer and two an expert layer."""
-    c = run.config
-    dev = run.trace.devices[0]
-    ops = run.trace.data["devices"][dev]["ops"]
-    outer = sorted((e for e in ops if lo <= e[1] < hi
-                    and trace_lib.op_family(e[0]) in ("while", "conditional")),
-                   key=lambda e: e[1])
-    top, end = [], lo
-    for e in outer:
-        if e[1] >= end:
-            top.append(e)
-            end = e[1] + e[2]
-    loops = [e for e in top if trace_lib.op_family(e[0]) == "while"]
-    dense = c["first_k_dense_replace"]
-    if len(loops) != 2 * c["num_hidden_layers"] - dense:
+def prefill_kernel_ns(run, lo, hi):
+    """Device time of the prefill kernel in one run of the chunk program,
+    [lo, hi): the sum of its events, one a layer. None where the run does
+    not show exactly ``num_hidden_layers`` of them."""
+    events = _kernel_events(run, PREFILL_KERNEL, lo, hi)
+    if len(events) != run.config["num_hidden_layers"]:
         return None
-    firsts = loops[:dense] + loops[dense::2]
-    return sum(e[2] for e in firsts)
+    return sum(e[2] for e in events)

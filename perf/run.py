@@ -26,8 +26,9 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from perf.lib import (compare, device, manifest, peaks, record as record_lib,  # noqa: E402
-                      spans as spans_lib, trace as trace_lib)
+from perf.lib import (compare, device, manifest, peaks, program_spans,  # noqa: E402
+                      record as record_lib, spans as spans_lib,
+                      trace as trace_lib)
 
 
 class Context:
@@ -106,6 +107,19 @@ def measure(ctx):
             raise RuntimeError("the trace holds no operation of a TPU")
 
 
+def idle_gaps(ctx, n=10):
+    """The traced window's idle device time, by what the host was doing: in
+    a serving cell whose clock join holds, by the program's innermost span
+    (``program_spans.idle_table``, which ``serve_idle_attributed_share``
+    has read already); otherwise by the benchmark's ``perf.*`` annotations,
+    which in a serving cell wrap the whole of ``engine.step()``."""
+    table = (program_spans.idle_table(ctx)
+             if ctx.traffic["kind"] == "serve" else None)
+    if table is None:
+        return ctx.trace.idle_gaps(n)
+    return table["by_span"][:n]
+
+
 def result(ctx):
     """The last line. With --trace 0 the cell's end-to-end metrics, with
     --trace 1 its per-layer metrics, each read by the reader of its name; a
@@ -129,7 +143,7 @@ def result(ctx):
         dev["busy_s"] = ctx.trace.mean_busy_s()
         dev["window_s"] = ctx.trace.window_s
         out["breakdown"] = {"device_ops": ctx.trace.top_ops(10),
-                            "idle_gaps": ctx.trace.idle_gaps(10)}
+                            "idle_gaps": idle_gaps(ctx)}
     if ctx.args.tiny:
         # a CPU run names no device metric: the numbers stand apart
         out["dry_run"] = {k: v["value"] for k, v in metrics.items()}

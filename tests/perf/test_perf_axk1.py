@@ -146,14 +146,15 @@ def _doc():
              pages_held=3, pages_gathered=8, pool_pages=10, ctx_tokens=12,
              experts_hit=2, expert_tokens=3, shared_tokens=2, latent_rows=36),
     ]
-    # the programs' device time: a chunk run has a loop a dense layer and
-    # two an expert layer (attention, experts), one nested loop inside the
-    # first; a decode run a kernel a layer and an experts' loop an expert
-    # layer
+    # the programs' device time: a chunk run has the prefill kernel a layer
+    # and an experts' loop an expert layer; a decode run the decode kernel
+    # a layer and an experts' loop an expert layer
     chunk_ops = lambda t: [  # noqa: E731
-        ["while.1", t, 5 * ms], ["while.9", t + 1 * ms, 1 * ms],
-        ["fusion.1", t + 5 * ms, 1 * ms], ["while.2", t + 6 * ms, 6 * ms],
-        ["while.3", t + 12 * ms, 3 * ms], ["while.4", t + 15 * ms, 7 * ms],
+        ["latent_prefill_attention.1", t, 5 * ms],
+        ["fusion.1", t + 5 * ms, 1 * ms],
+        ["latent_prefill_attention.2", t + 6 * ms, 6 * ms],
+        ["while.3", t + 12 * ms, 3 * ms],
+        ["latent_prefill_attention.3", t + 15 * ms, 7 * ms],
         ["while.5", t + 22 * ms, 3 * ms], ["fusion.2", t + 25 * ms, 5 * ms]]
     decode_ops = lambda t: [  # noqa: E731
         ["latent_decode_attention.1", t, 2 * ms],
@@ -206,9 +207,9 @@ HAND = {
     "latent_decode_roofline": 100 * (3.840 + 9.216) / 16,
     "latent_decode_share_of_step": 100 * 16 / 80,
     # the chunks' causal operations (3 x 10 x 24 and 3 x 11 x 24) against
-    # their walks over the context, 5 + 6 + 7 ms a run (the nested loop and
-    # the experts' walks left out); the latent rows' bytes (3 x 4 x 256,
-    # 3 x 6 x 256) are longer
+    # the prefill kernel's three events, 5 + 6 + 7 ms a run (the experts'
+    # walks left out); the latent rows' bytes (3 x 4 x 256, 3 x 6 x 256)
+    # are longer
     "latent_prefill_roofline": 100 * (3.072 + 4.608) / 36,
     # two decodes (1 and 2 tokens) and two chunks in 0.3 s at 1e6 a second
     "serve_mfu_latent_active": 100 * (
@@ -237,10 +238,35 @@ def test_a_reader_finds_nothing_in_a_program_without_latent_attention(name):
 
 
 def test_a_chunk_run_with_other_loops_gives_no_prefill_roofline():
+    """The chunk program as it was before the prefill kernel: its walks over
+    the context are top-level loops, and no event carries the kernel's
+    name."""
+    doc = _doc()
+    for e in doc["trace"]["devices"]["0"]["ops"]:
+        if e[0].startswith("latent_prefill_attention"):
+            e[0] = "while.1" + e[0][-1]
+    run = Run(doc)
+    lo, hi = axk1_spans.paired(run, axk1_spans.CHUNK_PROGRAM,
+                               axk1_spans.chunks(run))[0][0]
+    assert axk1_spans.prefill_kernel_ns(run, lo, hi) is None
+    assert manifest.metric_reader("latent_prefill_roofline")(run) is None
+
+
+def test_a_chunk_run_short_of_a_kernel_a_layer_gives_no_prefill_roofline():
+    """One event of the prefill kernel fewer than the toy has layers, in
+    one of the two chunk runs: no guess from the others."""
     doc = _doc()
     ops = doc["trace"]["devices"]["0"]["ops"]
-    doc["trace"]["devices"]["0"]["ops"] = [e for e in ops if e[0] != "while.5"]
-    assert manifest.metric_reader("latent_prefill_roofline")(Run(doc)) is None
+    last = max(e[1] for e in ops if e[0] == "latent_prefill_attention.3")
+    doc["trace"]["devices"]["0"]["ops"] = [
+        e for e in ops
+        if not (e[0] == "latent_prefill_attention.3" and e[1] == last)]
+    run = Run(doc)
+    (first, _), (second, _) = axk1_spans.paired(
+        run, axk1_spans.CHUNK_PROGRAM, axk1_spans.chunks(run))
+    assert axk1_spans.prefill_kernel_ns(run, *first) == 18_000_000
+    assert axk1_spans.prefill_kernel_ns(run, *second) is None
+    assert manifest.metric_reader("latent_prefill_roofline")(run) is None
 
 
 def test_runs_and_spans_pair_at_the_windows_edges():
@@ -317,7 +343,9 @@ def test_tiny_traced_run_reports_the_counter_and_span_metrics(capsys):
     named = {m["name"] for m in manifest.metrics_of(MANIFEST, CELL, "per_layer")}
     assert set(last["dry_run"]) <= named and last["metrics"] == {}
     for name in ("decode_batch_occupancy", "serve_kv_pool_peak_share",
-                 "serve_ttft_queue_wait_ms", "serve_ttft_p90_ms"):
+                 "serve_ttft_queue_wait_ms", "serve_ttft_p90_ms",
+                 "prefill_chunk_ms", "prefill_chunk_p90_ms",
+                 "serve_ttft_prefill_ms"):
         assert last["dry_run"][name] > 0, name
     # no device trace on the CPU: no device metric, no share of a peak
     assert not set(NEW) & set(last["dry_run"])

@@ -158,7 +158,7 @@ HAND = {
 def test_a_reader_gives_the_hand_sum(doc, name):
     assert _read(name, Run(doc)) == pytest.approx(HAND[name], rel=1e-9)
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
 
 
 @pytest.mark.parametrize("name", sorted(HAND))

@@ -69,9 +69,12 @@ def test_the_reader_finds_nothing_in_a_program_without_the_spans(doc):
     assert read(Run(doc, events=old)) is None
 
 
-def test_the_entry_is_the_last_and_lists_the_long_context_cell():
+def test_the_p90_entry_matches_the_median_entry():
+    """Found by name, wherever it stands among the entries: the same layer,
+    source, unit, cells and end-to-end metric as the median's."""
     man = manifest.load()
-    entry = man["per_layer"][-1]
+    entry = next(m for m in man["per_layer"] if m["name"] == NAME)
     median = next(m for m in man["per_layer"] if m["name"] == "prefill_chunk_ms")
     assert entry == {**median, "name": NAME}
-    assert entry["workloads"] == [CELL] and entry["moves"] == "ttft_p50_ms"
+    assert CELL in entry["workloads"] and CELL in median["workloads"]
+    assert entry["moves"] == "ttft_p50_ms"

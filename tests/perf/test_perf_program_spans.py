@@ -120,6 +120,50 @@ def test_idle_time_goes_to_the_innermost_span_and_sums_to_the_traces(doc, capsys
     assert "idle serve.decode.sample: 0.014000 s (8.8%)" in capsys.readouterr().err
 
 
+def _result(run, kind):
+    """perf/run.py's last line of a traced run, for the hand-made run in a
+    cell of traffic ``kind`` that lists no metric."""
+    import argparse
+
+    from perf import run as run_lib
+
+    run.args = argparse.Namespace(trace=1, tiny=False, workload="cell")
+    run.manifest = {"per_layer": [], "end_to_end": []}
+    run.traffic = {"kind": kind}
+    run.device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    run.checks, run.attempted, run.failed = [], 0, 0
+    return run_lib.result(run)
+
+
+def test_the_breakdown_books_serving_idle_time_to_the_programs_spans(doc):
+    """Where the clock join holds, ``idle_gaps`` is the top ten of the idle
+    table by innermost span, the same one the reader of
+    ``serve_idle_attributed_share`` made; it sums to no more than the
+    table's idle time."""
+    run = Run(doc)
+    assert _read("serve_idle_attributed_share", run) == pytest.approx(31.25)
+    gaps = _result(run, "serve")["breakdown"]["idle_gaps"]
+    table = program_spans.idle_table(run)
+    assert gaps == table["by_span"][:10] and len(table["by_span"]) == 14
+    assert gaps[:2] == [["(no span)", pytest.approx(0.110)],
+                        ["serve.decode.sample", pytest.approx(0.014)]]
+    assert sum(v for _, v in gaps) <= table["idle_s"]
+
+
+def test_the_breakdown_keeps_the_benchmarks_spans_without_the_join(doc):
+    """A residual over the limit (nothing mapped), or a training cell: the
+    trace's own host spans, the benchmark's ``perf.*``."""
+    for span, jitter in zip(doc["trace"]["host"][1:], (-250_000, 250_000)):
+        span[1] += jitter
+    run = Run(doc)
+    gaps = _result(run, "serve")["breakdown"]["idle_gaps"]
+    assert gaps == run.trace.idle_gaps(10)
+    assert {name for name, _ in gaps} <= {"perf.engine.step", "(no span)"}
+    run = Run(json.loads((HERE / "small_program_spans.json").read_text()))
+    gaps = _result(run, "train")["breakdown"]["idle_gaps"]
+    assert gaps == run.trace.idle_gaps(10) and not hasattr(run, "_idle_table")
+
+
 def test_flatten_and_book():
     segs = program_spans.flatten([["a", 0, 10], ["b", 2, 5], ["c", 3, 4],
                                   ["d", 12, 14], ["e", 6, 11]])

@@ -181,3 +181,29 @@ def test_the_four_chip_cell_waits_for_its_entries_alone(tmp_path):
     last = json.loads(p.stdout.strip().splitlines()[-1])
     assert last["correct"] is True and last["device"]["count"] == 4
     assert last["dry_run"]["train_rate"] > 0
+
+
+@pytest.mark.parametrize("family", ["transformer", "keye"])
+def test_a_serving_adapter_sizes_the_ring_without_the_processs_environment(
+        monkeypatch, family):
+    """As the A.X-K1 adapter's test: the serving runner's ring size reaches
+    the program's ring, and ``MLSL_TRACE_CAPACITY`` is not left in a test
+    worker's environment, where a later test of the ring's default size
+    would find it."""
+    from mlsl_tpu import obs
+
+    from perf.lib import manifest
+
+    adapter = manifest.load_module(ROOT / "perf" / "adapters" / f"{family}.py")
+    monkeypatch.delenv("MLSL_TRACE_CAPACITY", raising=False)
+    was = obs.get_tracer()
+    try:
+        env = adapter.environment(ring_events=1 << 19)
+        assert "MLSL_TRACE_CAPACITY" not in os.environ
+        if was is not None:
+            assert obs.get_tracer().capacity >= 1 << 19
+        env.finalize()
+    finally:
+        if was is not None:
+            obs.disable()
+            obs.enable(was.capacity)

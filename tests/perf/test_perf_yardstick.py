@@ -126,6 +126,47 @@ def test_a_cell_a_configuration_and_a_metric_are_added_by_files_alone(tmp_path):
     assert {k: v for k, v in after.items() if k in before} == before
 
 
+def test_an_entry_appended_to_per_layer_breaks_no_test_of_the_entries(tmp_path):
+    """A later PR appends its per-layer metric at the end of ``per_layer``
+    (one put in the middle reads as a change to the benchmark): in a copy
+    with a toy entry appended, every test here that reads the entries, the
+    cells they list and their readers still passes. The tests that drive
+    the program are left out: they read no entry by its place."""
+    import os
+    import subprocess
+
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    for sub in ("perf", "tests/perf"):
+        shutil.copytree(ROOT / sub, tmp_path / sub, ignore=shutil.ignore_patterns(
+            "out", ".jax_cache", "__pycache__"))
+    (tmp_path / "perf" / "metrics" / "toy_appended.py").write_text(
+        "def read(run):\n    return None\n")
+    man = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    man["per_layer"].append({
+        "name": "toy_appended", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "whole step", "moves": "itl_p95_ms",
+        "workloads": ["ax-k1-ep16-pp10-serve-docreason"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(man, indent=1))
+    tests = tmp_path / "tests" / "perf"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [os.environ.get("PYTHONPATH", ""), str(ROOT)]).strip(os.pathsep))
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-o", "addopts=",
+         "-k", "not tiny and not planted and not float8 and not breaks_no_test",
+         *(str(tests / f) for f in (
+             "test_perf_yardstick.py", "test_perf_prefill_chunk_p90.py",
+             "test_perf_keye.py", "test_perf_axk1.py",
+             "test_perf_program_spans.py"))],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+    assert "PASSED" in p.stdout and "FAILED" not in p.stdout
+    # the copy's manifest was the one read: the toy's own case ran
+    assert "test_metric_names_units_and_readers[toy_appended]" in p.stdout
+    assert "test_the_p90_entry_matches_the_median_entry" in p.stdout
+
+
 def test_validate_names_the_faults():
     bad = json.loads(json.dumps(MANIFEST))
     bad["per_layer"][0]["unit"] = "ms per step"
