@@ -412,6 +412,7 @@ class FeedCodec:
         sm = smap(body, mesh, in_specs=(in_specs,), out_specs=out_specs,
                   check=False)
 
+        @jax.named_scope("mlsl_feed")
         def fn(wire):
             decoded = sm(wire)
             batch = jax.tree_util.tree_unflatten(treedef, list(decoded))

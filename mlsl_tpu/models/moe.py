@@ -99,6 +99,7 @@ def _expert_ffn(buf, w1, w2, compute_dtype=jnp.float32):
     return mxu_einsum("...ecf,efd->...ecd", h.astype(cdt), w2.astype(cdt))
 
 
+@jax.named_scope("mlsl_experts")
 def moe_ffn(
     x: jax.Array,
     params: Dict,
@@ -259,6 +260,7 @@ def expert_tile(n_tokens: int) -> int:
     return max(8, min(256, -(-n_tokens // 8) * 8))
 
 
+@jax.named_scope("mlsl_experts")
 def dropless_experts(y, params: Dict, top_k: int, valid=None,
                      compute_dtype=jnp.float32, route=None):
     """Gated-SiLU experts, every routed pair computed.

@@ -64,6 +64,7 @@ def init_resnet50(key, num_classes: int = 1000) -> Params:
     return params
 
 
+@jax.named_scope("mlsl_norm")
 def _bn(x, p, eps=1e-5):
     # Folded BN, one-pass statistics: mean and E[x^2] accumulate in f32 off
     # the bf16 input in a SINGLE read of the activation (XLA fuses both
@@ -85,6 +86,7 @@ def _bn(x, p, eps=1e-5):
     return (x * a + b).astype(x.dtype)
 
 
+@jax.named_scope("mlsl_conv")
 def _conv(x, w, stride=1):
     return lax.conv_general_dilated(
         x,
@@ -95,6 +97,7 @@ def _conv(x, w, stride=1):
     )
 
 
+@jax.named_scope("mlsl_conv")
 def _stem_conv(x, w):
     """7x7-stride-2 'SAME' stem conv, optionally in space-to-depth form.
 
@@ -147,6 +150,9 @@ def _use_s2d_stem() -> bool:
     return on_tpu()
 
 
+# the activations and the residual add go with batch norm: a bottleneck's
+# elementwise work is mlsl_norm's, its convolutions (innermost) mlsl_conv's
+@jax.named_scope("mlsl_norm")
 def _bottleneck(x, block, stride):
     y = jax.nn.relu(_bn(_conv(x, block["conv1"]), block["bn1"]))
     y = jax.nn.relu(_bn(_conv(y, block["conv2"], stride), block["bn2"]))
@@ -160,7 +166,8 @@ def apply_resnet50(params: Params, x: jax.Array) -> jax.Array:
     """x: (N, H, W, 3) -> logits (N, num_classes). Compute in bf16, params f32."""
     x = x.astype(jnp.bfloat16)
     x = _stem_conv(x, params["stem"]["conv"])
-    x = jax.nn.relu(_bn(x, params["stem"]["bn"]))
+    with jax.named_scope("mlsl_norm"):
+        x = jax.nn.relu(_bn(x, params["stem"]["bn"]))
     x = lax.reduce_window(
         x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
     )
@@ -169,15 +176,17 @@ def apply_resnet50(params: Params, x: jax.Array) -> jax.Array:
         for bi in range(blocks):
             stride = 2 if (bi == 0 and si > 0) else 1
             x = _bottleneck(x, stage[bi], stride)
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))  # pool accumulates in f32
-    return x @ params["fc"]["w"] + params["fc"]["b"]
+    with jax.named_scope("mlsl_head"):
+        x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))  # pool accumulates in f32
+        return x @ params["fc"]["w"] + params["fc"]["b"]
 
 
 def loss_fn(params: Params, batch: Tuple[jax.Array, jax.Array]) -> jax.Array:
     x, labels = batch
     logits = apply_resnet50(params, x)
-    logp = jax.nn.log_softmax(logits)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
+    with jax.named_scope("mlsl_head"):
+        logp = jax.nn.log_softmax(logits)
+        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
 
 
 def layer_names(params: Params) -> List[str]:

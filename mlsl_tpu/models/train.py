@@ -55,6 +55,7 @@ def build_owned_increment_fn(mesh, lr: float, norm: float, with_scale: bool = Fa
     with_scale the fn takes an extra replicated scalar multiplied into the
     gradient (global-norm clipping)."""
 
+    @jax.named_scope("mlsl_update")
     def body(g, s):
         return (-lr * s * g.reshape(g.shape[NUM_GRID_AXES:]) / norm)[
             None, None, None, None
@@ -136,6 +137,7 @@ def build_owned_opt_increment_fn(mesh, optimizer, norm: float,
     The transform sees each rank's flat (owned,) shard, so only elementwise/
     shard-local transforms are correct here (see DataParallelTrainer)."""
 
+    @jax.named_scope("mlsl_update")
     def body(g, state, s):
         gl = s * g.reshape(g.shape[NUM_GRID_AXES:]) / norm
         local = jax.tree.map(
@@ -667,6 +669,7 @@ class DataParallelTrainer:
         layers, get_layer = self.layers, self.get_layer
 
         def apply(params, incs: Dict[str, jax.Array]):
+            @jax.named_scope("mlsl_update")
             def body(params, *flat_incs):
                 new = params
                 for name, inc in zip(layers, flat_incs):
@@ -696,6 +699,7 @@ class DataParallelTrainer:
         count = self.layer_counts[name]
 
         def update_layer(sub, g):
+            @jax.named_scope("mlsl_update")
             def body(sub, g):
                 g = g.reshape(-1)[:count] / data_size
                 return jax.tree.map(
@@ -734,8 +738,9 @@ class DataParallelTrainer:
                 x = x.reshape(x.shape[NUM_GRID_AXES:])
                 y = y.reshape(y.shape[NUM_GRID_AXES:])
                 loss, grads = jax.value_and_grad(loss_fn)(params, (x, y))
-                grads = _clipped(grads)
-                return loss, jax.tree.map(lambda p, g: p - lr * g, params, grads)
+                with jax.named_scope("mlsl_update"):
+                    grads = _clipped(grads)
+                    return loss, jax.tree.map(lambda p, g: p - lr * g, params, grads)
 
             return fused
 
@@ -747,8 +752,9 @@ class DataParallelTrainer:
             x = x.reshape(x.shape[NUM_GRID_AXES:])
             y = y.reshape(y.shape[NUM_GRID_AXES:])
             loss, grads = jax.value_and_grad(loss_fn)(params, (x, y))
-            updates, new_state = optimizer.update(_clipped(grads), opt_state, params)
-            return loss, optax.apply_updates(params, updates), new_state
+            with jax.named_scope("mlsl_update"):
+                updates, new_state = optimizer.update(_clipped(grads), opt_state, params)
+                return loss, optax.apply_updates(params, updates), new_state
 
         return fused_opt
 

@@ -360,6 +360,7 @@ def get_layer(params, name):
     return params[name]
 
 
+@jax.named_scope("mlsl_norm")
 def _ln(x, scale, bias, eps=1e-5):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
@@ -401,7 +402,8 @@ def forward_local(params, tokens, cfg: TransformerConfig, sp: int, tp: int,
         pos = emb["pos"][idx]
     else:
         pos = lax.dynamic_slice_in_dim(emb["pos"], s_idx * sl, sl, axis=0)
-    h = (emb["tok"][tokens] + pos[None]).astype(cdt)
+    with jax.named_scope("mlsl_embed"):
+        h = (emb["tok"][tokens] + pos[None]).astype(cdt)
 
     if cfg.attention == "zigzag":
         def attn_fn(q, k, v, ax, n, causal=True):
@@ -414,15 +416,21 @@ def forward_local(params, tokens, cfg: TransformerConfig, sp: int, tp: int,
         attn_fn = ring_attention if cfg.attention == "ring" else ulysses_attention
     def block_body(h, lnp, ap, mp):
         a = _ln(h.astype(jnp.float32), lnp["ln1_scale"], lnp["ln1_bias"]).astype(cdt)
-        qkv = jnp.einsum("bsd,dchx->bcshx", a, ap["wqkv"].astype(cdt))
-        q, k, v = (
-            jnp.moveaxis(qkv[:, c], 2, 1) for c in range(3)
-        )  # (Bl, Hl, Sl, Dh)
+        with jax.named_scope("mlsl_attn"):
+            qkv = jnp.einsum("bsd,dchx->bcshx", a, ap["wqkv"].astype(cdt))
+            q, k, v = (
+                jnp.moveaxis(qkv[:, c], 2, 1) for c in range(3)
+            )  # (Bl, Hl, Sl, Dh)
+        # outside the scope: XLA names an instruction by the innermost part of
+        # its path, and the flash kernels are found by the names they have
+        # bare (jvp_jit__flash_fwd__, transpose_jvp_jit__flash_bwd__);
+        # _dense_attention scopes what it computes without them
         attn = attn_fn(q, k, v, SEQ_AXIS, sp, causal=True)
-        # bf16 operands, f32 accumulate/output: keeps the projection on the MXU's
-        # native path while the residual add and TP psum stay f32.
-        o = mxu_einsum("bhsx,hxd->bsd", attn.astype(cdt), ap["wo"].astype(cdt))
-        o = lax.psum(o, MODEL_AXIS) if tp > 1 else o      # TP reduction (case-2 analog)
+        with jax.named_scope("mlsl_attn"):
+            # bf16 operands, f32 accumulate/output: keeps the projection on the
+            # MXU's native path while the residual add and TP psum stay f32.
+            o = mxu_einsum("bhsx,hxd->bsd", attn.astype(cdt), ap["wo"].astype(cdt))
+            o = lax.psum(o, MODEL_AXIS) if tp > 1 else o  # TP reduction (case-2 analog)
         h = (h.astype(jnp.float32) + o).astype(cdt)
 
         a = _ln(h.astype(jnp.float32), lnp["ln2_scale"], lnp["ln2_bias"]).astype(cdt)
@@ -438,12 +446,13 @@ def forward_local(params, tokens, cfg: TransformerConfig, sp: int, tp: int,
             h = (h.astype(jnp.float32) + o2d.reshape(bl, sl_, dm)).astype(cdt)
         else:
             aux = jnp.float32(0.0)
-            f = jax.nn.gelu(
-                jnp.einsum("bsd,df->bsf", a, mp["w1"].astype(cdt))
-                + mp["b1"].astype(cdt)
-            )
-            o = mxu_einsum("bsf,fd->bsd", f, mp["w2"].astype(cdt))
-            o = lax.psum(o, MODEL_AXIS) if tp > 1 else o
+            with jax.named_scope("mlsl_mlp"):
+                f = jax.nn.gelu(
+                    jnp.einsum("bsd,df->bsf", a, mp["w1"].astype(cdt))
+                    + mp["b1"].astype(cdt)
+                )
+                o = mxu_einsum("bsf,fd->bsd", f, mp["w2"].astype(cdt))
+                o = lax.psum(o, MODEL_AXIS) if tp > 1 else o
             h = (h.astype(jnp.float32) + o + mp["b2"]).astype(cdt)
         return h, aux
 
@@ -501,12 +510,13 @@ def local_loss(params, tokens, labels, cfg, sp, tp, comm=None):
     replicated (dense softmax) or model-axis vocab-sharded (pmax/psum CE, full-V
     logits never materialize). Returns (ce_sum, aux)."""
     h, aux = forward_local(params, tokens, cfg, sp, tp, comm=comm)
-    head = params["final"]["head"].astype(jnp.float32)
-    if cfg.sharded_vocab and tp > 1:
-        return _sharded_vocab_ce(h, head, labels, head.shape[-1]), aux
-    logp = jax.nn.log_softmax(h @ head)
-    ce = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return jnp.sum(ce), aux
+    with jax.named_scope("mlsl_head"):
+        head = params["final"]["head"].astype(jnp.float32)
+        if cfg.sharded_vocab and tp > 1:
+            return _sharded_vocab_ce(h, head, labels, head.shape[-1]), aux
+        logp = jax.nn.log_softmax(h @ head)
+        ce = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        return jnp.sum(ce), aux
 
 
 # -- decode mode (mlsl_tpu.serve): prefill + paged single-token steps ---------
@@ -577,6 +587,7 @@ def kv_block_quant(x):
 # are instances.
 
 
+@jax.named_scope("mlsl_norm")
 def _norm(x, p, name: str, cfg: TransformerConfig):
     """Float32 norm of the rows of x by the scale (and bias) ``name`` of p."""
     x = x.astype(jnp.float32)
@@ -639,6 +650,7 @@ def _project(a, ap, cfg: TransformerConfig, positions):
     return q, k, v, index
 
 
+@jax.named_scope("mlsl_embed")
 def _embed(params, tokens, positions, cfg: TransformerConfig, cdt):
     emb = params["embed"]
     h = emb["tok"][tokens]
@@ -647,6 +659,7 @@ def _embed(params, tokens, positions, cfg: TransformerConfig, cdt):
     return h.astype(jnp.dtype(cfg.residual_dtype or cdt))
 
 
+@jax.named_scope("mlsl_head")
 def _logits(params, h, cfg: TransformerConfig, cdt):
     """Final norm and the output head on rows h -> float32 logits. Float32
     weights multiply in float32; weights at rest in bfloat16 go to the MXU as
@@ -669,9 +682,10 @@ def _block(h, params, i: int, cfg: TransformerConfig, cdt, tp: int, comm,
     mp = params[f"blk{i}.mlp"]
     rdt = jnp.dtype(cfg.residual_dtype or cdt)
     a = _norm(h, lnp, "ln1", cfg).astype(cdt)
-    attn = attend(i, a, ap)
-    o = mxu_einsum("thx,hxd->td", attn.astype(cdt), ap["wo"].astype(cdt))
-    o = _decode_reduce(o, tp, comm)
+    with jax.named_scope("mlsl_attn"):
+        attn = attend(i, a, ap)
+        o = mxu_einsum("thx,hxd->td", attn.astype(cdt), ap["wo"].astype(cdt))
+        o = _decode_reduce(o, tp, comm)
     h = (h.astype(jnp.float32) + o).astype(rdt)
 
     a = _norm(h, lnp, "ln2", cfg)
@@ -681,13 +695,14 @@ def _block(h, params, i: int, cfg: TransformerConfig, cdt, tp: int, comm,
     if cfg.mlp == "experts":
         o, hit, pairs = dropless_experts(a, mp, cfg.moe_top_k, valid, cdt)
         return (h.astype(jnp.float32) + o).astype(rdt), jnp.stack([hit, pairs])
-    a = a.astype(cdt)
-    f = jax.nn.gelu(
-        jnp.einsum("td,df->tf", a, mp["w1"].astype(cdt))
-        + mp["b1"].astype(cdt)
-    )
-    o = mxu_einsum("tf,fd->td", f, mp["w2"].astype(cdt))
-    o = _decode_reduce(o, tp, comm)
+    with jax.named_scope("mlsl_mlp"):
+        a = a.astype(cdt)
+        f = jax.nn.gelu(
+            jnp.einsum("td,df->tf", a, mp["w1"].astype(cdt))
+            + mp["b1"].astype(cdt)
+        )
+        o = mxu_einsum("tf,fd->td", f, mp["w2"].astype(cdt))
+        o = _decode_reduce(o, tp, comm)
     h = (h.astype(jnp.float32) + o + mp["b2"]).astype(rdt)
     return h, jnp.zeros((2,), jnp.int32)
 
@@ -769,6 +784,7 @@ def _latent_project(a, ap, cfg: TransformerConfig, positions):
     return q[..., :cfg.qk_nope_dim], q_pe, row
 
 
+@jax.named_scope("mlsl_mlp")
 def _swiglu(a, wgu, wd, cdt):
     """Gated SiLU on rows a: (silu(a Wg) * (a Wu)) Wd, gate and up side by
     side in ``wgu``."""
@@ -933,7 +949,9 @@ def prefill_local(params, tokens, length, cfg: TransformerConfig, tp: int,
     for i in range(cfg.n_blocks):
         h, _ = _block(h, params, i, cfg, cdt, tp, comm, attend, valid)
     last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
-    return _logits(params, last, cfg, cdt)[0], jnp.stack(ks), jnp.stack(vs)
+    logits = _logits(params, last, cfg, cdt)[0]
+    with jax.named_scope("mlsl_attn"):
+        return logits, jnp.stack(ks), jnp.stack(vs)
 
 
 def _keys_per_trip(pages: int, page: int, want: int = 512) -> int:
@@ -1394,6 +1412,7 @@ class HybridTrainer:
         norm = self.batch * self.cfg.seq_len
 
         def update(params, reduced):
+            @jax.named_scope("mlsl_update")
             def body(params, *flat_grads):
                 new = dict(params)
                 for name, g in zip(layers, flat_grads):
@@ -1450,14 +1469,15 @@ class HybridTrainer:
                 (_, loss), grads = jax.value_and_grad(
                     scaled_loss, has_aux=True
                 )(params, tokens, labels)
-                new = dict(params)
-                for name in layers:
-                    subl, treedef = jax.tree.flatten(params[name])
-                    gl = synced_layer_grads(params, grads, name)
-                    new[name] = jax.tree.unflatten(
-                        treedef,
-                        [(p - lr * g).astype(p.dtype) for p, g in zip(subl, gl)],
-                    )
+                with jax.named_scope("mlsl_update"):
+                    new = dict(params)
+                    for name in layers:
+                        subl, treedef = jax.tree.flatten(params[name])
+                        gl = synced_layer_grads(params, grads, name)
+                        new[name] = jax.tree.unflatten(
+                            treedef,
+                            [(p - lr * g).astype(p.dtype) for p, g in zip(subl, gl)],
+                        )
                 return loss[None, None, None, None, None], new
 
             sm = smap(
@@ -1474,21 +1494,22 @@ class HybridTrainer:
             (_, loss), grads = jax.value_and_grad(scaled_loss, has_aux=True)(
                 params, tokens, labels
             )
-            new, new_states = dict(params), {}
-            grid1 = (1,) * NUM_GRID_AXES
-            for name in layers:
-                gl = jnp.concatenate(
-                    [g.reshape(-1) for g in synced_layer_grads(params, grads, name)]
-                )
-                local = jax.tree.map(
-                    lambda l: l.reshape(l.shape[NUM_GRID_AXES:]), states[name]
-                )
-                new[name], ns = self._flat_opt_layer_update(
-                    params[name], local, gl
-                )
-                new_states[name] = jax.tree.map(
-                    lambda l: l.reshape(grid1 + l.shape), ns
-                )
+            with jax.named_scope("mlsl_update"):
+                new, new_states = dict(params), {}
+                grid1 = (1,) * NUM_GRID_AXES
+                for name in layers:
+                    gl = jnp.concatenate(
+                        [g.reshape(-1) for g in synced_layer_grads(params, grads, name)]
+                    )
+                    local = jax.tree.map(
+                        lambda l: l.reshape(l.shape[NUM_GRID_AXES:]), states[name]
+                    )
+                    new[name], ns = self._flat_opt_layer_update(
+                        params[name], local, gl
+                    )
+                    new_states[name] = jax.tree.map(
+                        lambda l: l.reshape(grid1 + l.shape), ns
+                    )
             return loss[None, None, None, None, None], new, new_states
 
         state_specs = {
@@ -1516,6 +1537,7 @@ class HybridTrainer:
                 n: jax.tree.map(_leaf_buf_spec, states[n]) for n in layers
             }
 
+            @jax.named_scope("mlsl_update")
             def body(params, states, *flat_grads):
                 new, new_states = dict(params), {}
                 grid1 = (1,) * NUM_GRID_AXES
@@ -1560,6 +1582,7 @@ class HybridTrainer:
         """Apply all-gathered increments: params += inc (per model shard)."""
         layers, counts = self.layers, self.local_counts
 
+        @jax.named_scope("mlsl_update")
         def body(params, *flat_incs):
             new = dict(params)
             for name, inc in zip(layers, flat_incs):

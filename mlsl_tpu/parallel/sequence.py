@@ -410,15 +410,16 @@ def _dense_attention(q, k, v, causal: bool, pos_offset: int) -> jax.Array:
             off, off, causal, pallas_interpret(),
         )
         return out.reshape(b, h, s, d)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    s_mat = jnp.einsum(
-        "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) * scale
-    if causal:
-        pos = jnp.arange(s) + pos_offset
-        s_mat = jnp.where((pos[None, :] <= pos[:, None])[None, None], s_mat, _NEG)
-    p = jax.nn.softmax(s_mat, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+    with jax.named_scope("mlsl_attn"):
+        scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+        s_mat = jnp.einsum(
+            "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
+        ) * scale
+        if causal:
+            pos = jnp.arange(s) + pos_offset
+            s_mat = jnp.where((pos[None, :] <= pos[:, None])[None, None], s_mat, _NEG)
+        p = jax.nn.softmax(s_mat, axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
 def _use_flash(sq: int, sk: int, d: int) -> bool:

@@ -85,6 +85,7 @@ from mlsl_tpu.serve import kv_cache as kvc, sla
 _DECODE_FAIL_CAP = 8
 
 
+@jax.named_scope("mlsl_head")
 def _greedy(logits):
     """The greedy choice over the last axis, as int32: the first index of
     the maximum, a NaN counting as the maximum (``np.argmax``'s rule; the
@@ -356,6 +357,7 @@ class InferenceEngine:
         page = self.cache.page_elems
 
         if self.quant:
+            @jax.named_scope("mlsl_attn")
             def write_body(kpool, vpool, kscale, vscale, k, v, page_ids):
                 m = page_ids.shape[0]
                 heads = k.shape[:2] + (-1, cfg.head_dim)   # one scale a head
@@ -381,6 +383,7 @@ class InferenceEngine:
                 check=False,
             ), donate_argnums=(0, 1, 2, 3))
         else:
+            @jax.named_scope("mlsl_attn")
             def write_body(kpool, vpool, k, v, page_ids):
                 m = page_ids.shape[0]
                 shp = (cfg.n_blocks, m, page, -1)

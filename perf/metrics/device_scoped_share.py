@@ -1,0 +1,10 @@
+"""Busy time under any ``mlsl_`` scope over all busy time, traced window
+(``perf/lib/scopes.py``): 0 for an executable built without scopes (or one
+served from a compile cache filled by such a build), None where the trace
+carries no scope path."""
+
+from perf.lib import scopes
+
+
+def read(run):
+    return scopes.scoped_share(scopes.by_scope(run.trace))

@@ -399,3 +399,50 @@ def test_latent_programs_hold_what_the_readers_find(
                          exe.as_text())
     experts = cfg.n_blocks - cfg.first_k_dense
     assert kernels == [name] * cfg.n_blocks and len(top) == experts
+
+
+def test_flash_kernels_keep_their_names_inside_the_scoped_step(
+        one_chip, no_compile_cache, monkeypatch):
+    """GPT-2 medium's loss gradient as the training step computes it (the
+    cell's widths and sequence, two blocks, the flash kernels on): the
+    model's ``mlsl_*`` scopes name its parts, the kernels keep the names the
+    benchmark's flash readers find them by (XLA names an instruction by the
+    innermost part of its path, so no scope may stand between the gradient
+    and a kernel's call), and the backward kernels' path carries
+    ``transpose(``."""
+    import pathlib
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from perf.lib import manifest
+
+    from mlsl_tpu.models import transformer as tfm
+    from mlsl_tpu.parallel import sequence
+
+    monkeypatch.setattr(sequence, "on_tpu", lambda: True)
+    monkeypatch.setenv("MLSL_PALLAS_INTERPRET", "0")
+    config = {**manifest.read_json(
+        manifest.PERF / "configs" / "gpt2-medium.json"), "n_layer": 2}
+    cfg = manifest.load_module(
+        manifest.PERF / "adapters" / "transformer.py").program_config(config)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    tokens = jax.ShapeDtypeStruct((8, cfg.seq_len), jnp.int32, sharding=one_chip)
+
+    def grad(params, tokens, labels):
+        return jax.grad(lambda p: tfm.local_loss(
+            p, tokens, labels, cfg, 1, 1)[0])(params)
+
+    text = jax.jit(grad).lower(params, tokens, tokens).compile().as_text()
+    kernels = re.findall(
+        r'%(\w+?)[.\d]* = .*tpu_custom_call.*op_name="([^"]*)"', text)
+    assert sorted({name for name, _ in kernels}) == [
+        "jvp_jit__flash_fwd__", "transpose_jvp_jit__flash_bwd___"]
+    assert all(("transpose(" in path) == name.startswith("transpose")
+               for name, path in kernels)
+    scoped = set(re.findall(r'op_name="[^"]*(mlsl_\w+)', text))
+    assert scoped == {"mlsl_embed", "mlsl_norm", "mlsl_attn", "mlsl_mlp",
+                      "mlsl_head"}

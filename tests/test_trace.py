@@ -169,9 +169,9 @@ def _import_probe(value):
     import importlib
 
     before = os.environ.pop(tracer_mod.ENV_TRACE, None)
-    # a fresh process has no ring size either: an in-process serving run of
-    # tests/perf leaves its own (perf/adapters' setdefault) in a worker that
-    # --dist loadfile may hand this file next
+    # a fresh process has no ring size either: whatever another test left in
+    # this worker's environment (--dist loadfile may hand this file to a
+    # worker after any other) is not this probe's
     capacity = os.environ.pop(tracer_mod.ENV_CAPACITY, None)
     if value is not None:
         os.environ[tracer_mod.ENV_TRACE] = value
